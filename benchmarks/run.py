@@ -449,6 +449,7 @@ from benchmarks.bench_backends import bench_backends  # noqa: E402
 from benchmarks.bench_pipeline import bench_pipeline  # noqa: E402
 from benchmarks.bench_serve import bench_serve  # noqa: E402
 from benchmarks.bench_supervisor import bench_supervisor  # noqa: E402
+from repro.compile_cache import use_compile_cache  # noqa: E402
 
 BENCHES = {
     "grad_error": bench_grad_error,
@@ -468,6 +469,7 @@ BENCHES = {
 
 
 def main() -> None:
+    use_compile_cache()
     import jax
 
     ap = argparse.ArgumentParser()

@@ -9,8 +9,11 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 
 import argparse  # noqa: E402
 
+from repro.compile_cache import use_compile_cache  # noqa: E402
+
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama3.2-1b")
     ap.add_argument("--shape", default="train_4k")

@@ -8,6 +8,7 @@ Figure 2.
 """
 import argparse
 
+from repro.compile_cache import use_compile_cache
 from repro.core import METHODS
 from repro.graph import ClusterSampler, make_sbm_dataset, partition_graph
 from repro.models import make_gnn
@@ -16,6 +17,7 @@ from repro.train import GNNTrainer
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=300)
     ap.add_argument("--preset", default="arxiv-cpu")

@@ -12,11 +12,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import use_compile_cache
 from repro.configs import ARCH_NAMES, reduced_config
 from repro.models.lm import LM
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama3.2-1b", choices=ARCH_NAMES)
     ap.add_argument("--batch", type=int, default=4)

@@ -16,6 +16,7 @@ from collections import Counter
 
 import numpy as np
 
+from repro.compile_cache import use_compile_cache
 from repro.core import LMC
 from repro.graph import ClusterSampler, make_sbm_dataset, partition_graph
 from repro.models import make_gnn
@@ -26,6 +27,7 @@ from repro.train.health import FaultPlan
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--preset", default="ppi-cpu")
     ap.add_argument("--train-steps", type=int, default=100)

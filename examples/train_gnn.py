@@ -21,6 +21,7 @@ onto.
 import argparse
 import time
 
+from repro.compile_cache import use_compile_cache
 from repro.core import METHODS
 from repro.graph import ClusterSampler, make_sbm_dataset, partition_graph
 from repro.models import make_gnn
@@ -29,6 +30,7 @@ from repro.train import GNNTrainer, HealthConfig
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser(
         description="End-to-end LMC GNN training on a synthetic full-scale "
                     "dataset (checkpointing, fault tolerance, Pallas kernel "

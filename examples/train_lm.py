@@ -11,6 +11,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import use_compile_cache
 from repro.configs import ARCH_NAMES, reduced_config
 from repro.data import TokenStream
 from repro.launch.steps import make_lm_train_step
@@ -19,6 +20,7 @@ from repro.optim import make_optimizer
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama3.2-1b", choices=ARCH_NAMES)
     ap.add_argument("--steps", type=int, default=60)

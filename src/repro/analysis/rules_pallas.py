@@ -276,7 +276,7 @@ class VmemBudgetRule(Rule):
                     "the resident block is not statically bounded and Mosaic "
                     "rejects it past ~12 MiB at compile time (TPU-only — "
                     "interpret mode runs any size). Stream the operand "
-                    "(`pltpu.ANY` + async-copy gather) or annotate with "
+                    "(`pl.ANY` + async-copy gather) or annotate with "
                     "`# lint: ok(R003) <static bound argument>`")
                 continue
             if nbytes > VMEM_BUDGET_BYTES:
@@ -309,7 +309,12 @@ class VmemBudgetRule(Rule):
                 continue
             if not node.args or not isinstance(node.args[0],
                                                (ast.Tuple, ast.List)):
-                continue   # e.g. BlockSpec(memory_space=pltpu.ANY): HBM, fine
+                continue   # e.g. BlockSpec(memory_space=pl.ANY): HBM, fine
+            if is_blockspec and any(
+                    kw.arg == "memory_space"
+                    and (astutils.qualname(kw.value, mod.aliases)
+                         or "").endswith(".SMEM") for kw in node.keywords):
+                continue   # SMEM block: not VMEM residency
             env = dict(mod_env)
             for func in reversed(
                     astutils.enclosing_functions(node, mod.parents)):

@@ -1,42 +1,23 @@
-"""Version-compatible mesh construction.
+"""Mesh construction.
 
-`jax.make_mesh` + `jax.sharding.AxisType` only exist in newer jax; the pinned
-0.4.37 has `make_mesh` but no `AxisType`. Construction feature-detects, in
-order: `jax.make_mesh(..., axis_types=...)`, `jax.make_mesh(...)`, and finally
-`mesh_utils.create_device_mesh` + `Mesh` — so the same call sites run on every
-supported jax without touching device state at import time (meshes are built
-by FUNCTIONS: smoke tests must see 1 device while the dry-run sees 512
-placeholder devices via XLA_FLAGS).
+Meshes are built by FUNCTIONS, never at import time: smoke tests must see 1
+device while the dry-run sees 512 placeholder devices via XLA_FLAGS.
 """
 from __future__ import annotations
 
 from typing import Optional, Sequence
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 
 def make_mesh(shape: Sequence[int], axes: Sequence[str], *,
               devices: Optional[Sequence] = None) -> Mesh:
     """Arbitrary mesh (tests, elastic re-scale, production grids)."""
-    shape, axes = tuple(int(s) for s in shape), tuple(axes)
-    kwargs = {} if devices is None else {"devices": devices}
-    make = getattr(jax, "make_mesh", None)
-    if make is not None:
-        axis_type = getattr(jax.sharding, "AxisType", None)
-        if axis_type is not None:
-            try:
-                return make(shape, axes,
-                            axis_types=(axis_type.Auto,) * len(axes), **kwargs)
-            except TypeError:
-                pass  # make_mesh predates the axis_types kwarg
-        try:
-            return make(shape, axes, **kwargs)
-        except TypeError:
-            pass  # very old make_mesh signature — fall through
-    from jax.experimental import mesh_utils
-    devs = mesh_utils.create_device_mesh(shape, devices=devices)
-    return Mesh(devs, axes)
+    axes = tuple(axes)
+    return jax.make_mesh(tuple(int(s) for s in shape), axes,
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:

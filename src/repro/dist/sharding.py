@@ -5,9 +5,9 @@ Axis naming convention (DESIGN.md §4): meshes use up to three named axes —
 sequence parallel). Model code never names mesh axes directly; it labels array
 dims with the *logical* tags ``"dp"`` (rows: the pod+data product axis),
 ``"model"`` or ``None`` and calls :func:`shard_act`. The labels resolve
-against the mesh registered with :func:`activation_sharding` (or the ambient
-``with mesh:`` context), so the same model source traces to a no-op on one
-device and to `with_sharding_constraint`s on a pod.
+against the mesh registered with :func:`activation_sharding`, so the same
+model source traces to a no-op on one device and to
+`with_sharding_constraint`s on a pod.
 
 Resolution drops any label whose dim is not divisible by the target axes'
 size — tiny smoke configs (e.g. 2-row batches on a 4-way data axis) fall back
@@ -38,22 +38,9 @@ _CTX = _MeshStack()
 
 
 def current_mesh() -> Optional[Mesh]:
-    """The mesh activations shard against, or None off-mesh.
-
-    Priority: innermost :func:`activation_sharding` context, then the legacy
-    ambient ``with mesh:`` context manager (so hand-rolled jit calls in tests
-    still resolve), else None.
-    """
-    if _CTX.stack:
-        return _CTX.stack[-1]
-    try:
-        from jax.interpreters import pxla
-        env_mesh = pxla.thread_resources.env.physical_mesh
-        if env_mesh is not None and not env_mesh.empty:
-            return env_mesh
-    except Exception:  # moved/removed in newer jax — registry still works
-        pass
-    return None
+    """The mesh of the innermost :func:`activation_sharding` context, or
+    None off-mesh."""
+    return _CTX.stack[-1] if _CTX.stack else None
 
 
 @contextlib.contextmanager

@@ -2,7 +2,7 @@
 
   ell_spmm.py    — blocked-ELL SpMM (the GNN aggregation the paper's CUDA
                    backend implements with scatter/gather); vectorized tile
-                   kernel with scalar-prefetched index tiles; ref:
+                   kernel with per-row-tile SMEM index tiles; ref:
                    ref.ell_spmm_ref
   compensate.py  — fused gather + convex-combination for LMC Eq. (9)/(12)
   ops.py         — differentiable jit wrappers: degree-bucketed production
@@ -10,12 +10,13 @@
                    backward), bulk-numpy ELL builders, AggregateFn
   ref.py         — pure-jnp oracles
 
-Kernels are written for TPU (pl.pallas_call + PrefetchScalarGridSpec VMEM
-tiling, 8x128 aligned). ``interpret`` autodetects per backend: compiled Mosaic
-on TPU, interpreter fallback on CPU containers. ``stream`` autodetects to the
-HBM→VMEM double-buffered DMA gather (per-row ``pltpu.make_async_copy`` into a
-2-slot VMEM scratch), which removes any VMEM bound on the gather source —
-full-graph historical stores compile (DESIGN.md §3).
+Kernels are written for TPU (pl.pallas_call with SMEM index tiles and
+8x128-aligned VMEM tiles). ``interpret`` autodetects per backend: compiled
+Mosaic on TPU, interpreter fallback on CPU containers. ``stream``
+autodetects to the HBM→VMEM double-buffered DMA gather (per-row
+``pltpu.make_async_copy`` into a 2-slot VMEM scratch), which removes any VMEM
+bound on the gather source — full-graph historical stores compile
+(DESIGN.md §3).
 """
 from repro.kernels.ops import (ELLCapacityError, ELLGraph, build_ell,
                                bucketed_spmm, default_interpret,

@@ -4,12 +4,13 @@ The per-halo-node update  ĥ_i = m_i·[(1-β_i)·H̄[gid_i] + β_i·h̃_i]  is a
 from the (node-sharded) historical store fused with the lerp and validity
 mask, so the historical row never round-trips through HBM twice.
 
-Kernel layout mirrors ell_spmm.py: the gather ids ride in as a scalar-prefetch
-operand (SMEM) and the lerp+mask runs as one broadcast multiply-add over the
-whole tile (β and mask arrive as (N, 1) lane-broadcast columns). The gather
-itself has two strategies:
+Kernel layout mirrors ell_spmm.py: each grid step's gather ids ride into SMEM
+as one (block_rows,) block, so SMEM use does not grow with N (a whole-array
+SMEM operand overflowed v5e's 1 MiB near 262k rows), and the lerp+mask runs
+as one broadcast multiply-add over the whole tile (β and mask arrive as
+(N, 1) lane-broadcast columns). The gather itself has two strategies:
 
-  * ``stream=True`` (default): the store stays in **HBM** (``pltpu.ANY``) and
+  * ``stream=True`` (default): the store stays in **HBM** (``pl.ANY``) and
     each grid step's (block_rows, block_d) gather arrives via per-row
     HBM→VMEM ``pltpu.make_async_copy`` into a 2-slot VMEM scratch. The
     pipeline runs across grid steps: step t's compute overlaps step t+1's
@@ -31,18 +32,51 @@ from __future__ import annotations
 import functools
 
 import jax
+import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.ell_spmm import default_interpret, default_stream
 
+# XLA tiles a 1-D int32 array in 1024-word chunks, and Mosaic accepts a 1-D
+# block only if it matches: an SMEM id block is a whole number of chunks
+_SMEM_CHUNK = 1024
+
+
+def _smem_id_blocks(ids: jax.Array, tile: int):
+    """Lay 1-D ids out for one `tile`-id SMEM slice per row tile.
+
+    Returns ``(ids, block, spec)``: ``ids`` zero-padded to whole 1024-word
+    blocks, each holding one or more whole tiles, and ``spec(shift)`` — the
+    SMEM BlockSpec whose grid step (i, j) holds row tile ``i + shift``'s ids
+    (clamped to the last block) at offset ``(i + shift) · tile % block``.
+    """
+    n = ids.shape[0]
+    if n == tile:          # one row tile, of any height
+        block = _SMEM_CHUNK * -(-tile // _SMEM_CHUNK)
+    else:
+        assert _SMEM_CHUNK % tile == 0, (tile, n)
+        block = _SMEM_CHUNK
+    padded = block * -(-n // block)
+    if padded != n:
+        ids = jnp.pad(ids, (0, padded - n))
+    last = padded // block - 1
+
+    def spec(shift: int = 0) -> pl.BlockSpec:
+        return pl.BlockSpec(
+            (block,), lambda i, j: (jnp.minimum((i + shift) * tile // block,
+                                                last),),
+            memory_space=pltpu.SMEM)
+
+    return ids, block, spec
+
 
 def _comp_resident_kernel(gid_ref, beta_ref, mask_ref, fresh_ref, store_ref,
-                          o_ref, gath_ref, *, block_rows: int):
-    row0 = pl.program_id(0) * block_rows
+                          o_ref, gath_ref, *, block_rows: int, gid_block: int):
+    base = pl.program_id(0) * block_rows % gid_block
 
     def gather_row(r, _):
-        g = gid_ref[row0 + r]
+        g = gid_ref[base + r]
         gath_ref[pl.ds(r, 1), :] = store_ref[pl.ds(g, 1), :]
         return 0
 
@@ -51,28 +85,33 @@ def _comp_resident_kernel(gid_ref, beta_ref, mask_ref, fresh_ref, store_ref,
     o_ref[:] = mask_ref[:] * ((1.0 - b) * gath_ref[:] + b * fresh_ref[:])
 
 
-def _comp_stream_kernel(gid_ref, beta_ref, mask_ref, fresh_ref, store_ref,
-                        o_ref, gath_ref, sem_ref, *, block_rows: int,
-                        block_d: int, grid_j: int):
+def _comp_stream_kernel(gid_ref, gid_next_ref, beta_ref, mask_ref, fresh_ref,
+                        store_ref, o_ref, gath_ref, sem_ref, *,
+                        block_rows: int, block_d: int, grid_j: int,
+                        gid_block: int):
     """Streaming body, pipelined across row/feature tiles.
 
-    store_ref lives in HBM (``pltpu.ANY``); gath_ref is a (2, bn, bd) VMEM
+    store_ref lives in HBM (``pl.ANY``); gath_ref is a (2, bn, bd) VMEM
     double buffer; sem_ref a (2,) DMA-semaphore array. Grid steps run
     sequentially on a TPU core and scratch persists across them, so tile
     t = i·J + j computes out of slot t % 2 while tile t+1's row copies fill
     slot (t+1) % 2 — the gather DMA for the next tile overlaps this tile's
-    lerp. Tile 0 pays the only un-overlapped gather (warm-up).
+    lerp. Tile 0 pays the only un-overlapped gather (warm-up). gid_ref holds
+    row tile i's ids in SMEM and gid_next_ref row tile i+1's, which tile t+1
+    reads when it starts a new row tile (``_smem_id_blocks``).
     """
-    t = pl.program_id(0) * grid_j + pl.program_id(1)
+    i, j = pl.program_id(0), pl.program_id(1)
+    t = i * grid_j + j
     num_t = pl.num_programs(0) * grid_j
+    base = i * block_rows % gid_block
+    next_base = (i + 1) * block_rows % gid_block
 
-    def tile(t_, slot, op):
-        """start()/wait() the bn row-copies of grid tile t_ into slot."""
-        i = jax.lax.div(t_, grid_j)
-        col0 = jax.lax.rem(t_, grid_j) * block_d
+    def tile(col_tile, slot, op, ids_ref, ids_base):
+        """start()/wait() the bn row-copies of one grid tile into slot."""
+        col0 = col_tile * block_d
 
         def row(r, _):
-            g = gid_ref[i * block_rows + r]
+            g = ids_ref[ids_base + r]
             op(pltpu.make_async_copy(
                 store_ref.at[pl.ds(g, 1), pl.ds(col0, block_d)],
                 gath_ref.at[slot, pl.ds(r, 1), :],
@@ -83,14 +122,21 @@ def _comp_stream_kernel(gid_ref, beta_ref, mask_ref, fresh_ref, store_ref,
 
     @pl.when(t == 0)
     def _():  # warm-up: the first tile's gather cannot overlap anything
-        tile(0, 0, lambda dma: dma.start())
+        tile(0, 0, lambda dma: dma.start(), gid_ref, base)
 
-    @pl.when(t + 1 < num_t)
-    def _():  # overlap: next tile's DMA flies during this tile's lerp
-        tile(t + 1, jax.lax.rem(t + 1, 2), lambda dma: dma.start())
+    # overlap: next tile's DMA flies during this tile's lerp
+    next_slot = jax.lax.rem(t + 1, 2)
+
+    @pl.when(j + 1 < grid_j)
+    def _():  # next tile: same row tile, next feature tile
+        tile(j + 1, next_slot, lambda dma: dma.start(), gid_ref, base)
+
+    @pl.when(jnp.logical_and(j + 1 == grid_j, t + 1 < num_t))
+    def _():  # next tile: first feature tile of the next row tile
+        tile(0, next_slot, lambda dma: dma.start(), gid_next_ref, next_base)
 
     slot = jax.lax.rem(t, 2)
-    tile(t, slot, lambda dma: dma.wait())
+    tile(j, slot, lambda dma: dma.wait(), gid_ref, base)
     b = beta_ref[:]          # (bn, 1) broadcast over lanes
     hist = gath_ref[slot].astype(fresh_ref.dtype)
     o_ref[:] = mask_ref[:] * ((1.0 - b) * hist + b * fresh_ref[:])
@@ -120,36 +166,39 @@ def lmc_compensate_kernel(store: jax.Array, gids: jax.Array, beta: jax.Array,
     grid = (n // block_rows, d // block_d)
     beta2 = beta.reshape(n, 1).astype(fresh.dtype)
     mask2 = mask.reshape(n, 1).astype(fresh.dtype)
+    # each grid step's row tile of gather ids rides in an SMEM block
+    gids, gid_block, gid_spec = _smem_id_blocks(gids, block_rows)
+    tile_specs = [
+        pl.BlockSpec((block_rows, 1), lambda i, j: (i, 0)),
+        pl.BlockSpec((block_rows, 1), lambda i, j: (i, 0)),
+        pl.BlockSpec((block_rows, block_d), lambda i, j: (i, j)),
+    ]
     if stream:
         kernel = functools.partial(_comp_stream_kernel, block_rows=block_rows,
-                                   block_d=block_d, grid_j=grid[1])
-        store_spec = pl.BlockSpec(memory_space=pltpu.ANY)  # stays in HBM
+                                   block_d=block_d, grid_j=grid[1],
+                                   gid_block=gid_block)
+        in_specs = [gid_spec(), gid_spec(1), *tile_specs,
+                    pl.BlockSpec(memory_space=pl.ANY)]  # store stays in HBM
+        operands = (gids, gids, beta2, mask2, fresh, store)
         # DMA is byte-exact: the double buffer must carry the store dtype
         scratch = [pltpu.VMEM((2, block_rows, block_d), store.dtype),
                    pltpu.SemaphoreType.DMA((2,))]
     else:
         kernel = functools.partial(_comp_resident_kernel,
-                                   block_rows=block_rows)
+                                   block_rows=block_rows, gid_block=gid_block)
         # lint: ok(R003) legacy resident path: stream=True is the default and
         # Mosaic rejects >12 MiB blocks at compile time; kept for small
         # stores + streamed-vs-resident benchmarking (module docstring)
-        store_spec = pl.BlockSpec((m, block_d), lambda i, j, gid: (0, j))
+        store_spec = pl.BlockSpec((m, block_d), lambda i, j: (0, j))
+        in_specs = [gid_spec(), *tile_specs, store_spec]
+        operands = (gids, beta2, mask2, fresh, store)
         scratch = [pltpu.VMEM((block_rows, block_d), fresh.dtype)]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,  # gids -> SMEM
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_rows, 1), lambda i, j, gid: (i, 0)),
-            pl.BlockSpec((block_rows, 1), lambda i, j, gid: (i, 0)),
-            pl.BlockSpec((block_rows, block_d), lambda i, j, gid: (i, j)),
-            store_spec,
-        ],
-        out_specs=pl.BlockSpec((block_rows, block_d), lambda i, j, gid: (i, j)),
-        scratch_shapes=scratch,
-    )
     return pl.pallas_call(
         kernel,
-        grid_spec=grid_spec,
+        grid=grid,
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((block_rows, block_d), lambda i, j: (i, j)),
+        scratch_shapes=scratch,
         out_shape=jax.ShapeDtypeStruct((n, d), fresh.dtype),
         interpret=interpret,
-    )(gids, beta2, mask2, fresh, store)
+    )(*operands)

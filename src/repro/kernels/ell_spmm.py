@@ -12,14 +12,14 @@ scalar accumulation; broadcast multiply-add over the whole tile, f32
 accumulator, full VPU lanes):
 
   * ``stream=True`` (default): the feature operand stays in **HBM**
-    (``pltpu.ANY`` memory space) and the kernel body issues per-row
-    HBM→VMEM ``pltpu.make_async_copy`` gathers, driven by the
-    scalar-prefetched SMEM indices, into a 2-slot ``(block_rows, block_d)``
-    VMEM scratch. Neighbor plane k+1's copies start before plane k's wait, so
-    the DMA for k+1 overlaps the multiply-add for k (double buffering). No
-    VMEM bound on the gather source M — this is what lets the compiled path
-    gather from full-graph stores (the old resident block capped M at ~24k
-    f32 rows/device).
+    (``pl.ANY`` memory space) and the kernel body issues per-row
+    HBM→VMEM ``pltpu.make_async_copy`` gathers, driven by the row tile's
+    SMEM indices, into a 2-slot ``(block_rows, block_d)`` VMEM scratch.
+    Neighbor plane k+1's copies start before plane k's wait, so the DMA for
+    k+1 overlaps the multiply-add for k (double buffering). No VMEM bound on
+    the gather source M — this is what lets the compiled path gather from
+    full-graph stores (the old resident block capped M at ~24k f32
+    rows/device).
   * ``stream=False``: the legacy resident block — the whole ``(M, block_d)``
     feature slab rides in as one VMEM block and rows are copied out of it with
     dynamic slices. Cheaper for small sources revisited by many rows (one
@@ -33,12 +33,18 @@ streaming everywhere (the interpreter emulates the DMA/semaphore protocol
 exactly, so CPU CI verifies the streamed path — including at M well past the
 old cap). All tile dims are multiples of (8, 128) for VREG/MXU layout.
 
-VMEM budget per grid step (defaults, streamed): 2-slot gather scratch
+Memory per grid step (defaults, streamed): 2-slot gather scratch
 (2, 256, 128) f32 = 256 KiB, w tile (256, K≤128) = 128 KiB, out tile + f32
-accumulator (256, 128) ×2 = 256 KiB — independent of M. The full (N, K≤128)
-int32 index array lives in SMEM (scalar prefetch), which bounds practical N·K
-for the compiled path — the bucketed wrapper (ops.py) keeps per-call index
-arrays at mini-batch scale.
+accumulator (256, 128) ×2 = 256 KiB of VMEM, and the row tile's
+(block_rows, K) indices in SMEM, whose minor dim pads to 128 words: 128 KiB
+per buffer. None of it grows with N or M: the index array reaches SMEM one
+row tile at a time, where a whole (N, K) operand would overflow v5e's 1 MiB
+of SMEM near N = 2k rows.
+
+Mosaic cannot slice a lane at a dynamic offset, so weight column k of the
+(block_rows, K) tile is read as a masked lane reduction (``_weight_column``)
+rather than as ``w_ref[:, k]``; the reduction adds one nonzero to zeros, so
+it is exact.
 """
 from __future__ import annotations
 
@@ -68,25 +74,32 @@ def default_stream() -> bool:
     return True
 
 
+def _weight_column(w_ref, k) -> jax.Array:
+    """Column k of the (bn, K) weight tile as a (bn, 1) f32 lane-broadcast."""
+    w = w_ref[:].astype(jnp.float32)
+    lane = jax.lax.broadcasted_iota(jnp.int32, w.shape, 1)
+    return jnp.sum(jnp.where(lane == k, w, 0.0), axis=1, keepdims=True)
+
+
 def _spmm_resident_kernel(idx_ref, w_ref, h_ref, o_ref, gath_ref, acc_ref, *,
                           K: int, block_rows: int):
     """Resident-block body: gather rows out of a full (M, block_d) VMEM slab.
 
-    idx_ref: full (N, K) int32 in SMEM (scalar prefetch); w_ref: (bn, K) VMEM
-    tile; h_ref: (M, bd) VMEM feature block; gath_ref: (bn, bd) VMEM scratch;
-    acc_ref: (bn, bd) f32 accumulator (full precision even for bf16 inputs).
+    idx_ref: this row tile's (bn, K) int32 indices in SMEM; w_ref: (bn, K)
+    VMEM tile; h_ref: (M, bd) VMEM feature block; gath_ref: (bn, bd) VMEM
+    scratch; acc_ref: (bn, bd) f32 accumulator (full precision even for bf16
+    inputs).
     """
-    row0 = pl.program_id(0) * block_rows
     acc_ref[:] = jnp.zeros_like(acc_ref)
 
     def k_step(k, _):
         def gather_row(r, _):
-            j = idx_ref[row0 + r, k]
+            j = idx_ref[r, k]
             gath_ref[pl.ds(r, 1), :] = h_ref[pl.ds(j, 1), :]
             return 0
 
         jax.lax.fori_loop(0, block_rows, gather_row, 0)
-        acc_ref[:] += (w_ref[:, pl.ds(k, 1)].astype(jnp.float32)
+        acc_ref[:] += (_weight_column(w_ref, k)
                        * gath_ref[:].astype(jnp.float32))
         return 0
 
@@ -98,20 +111,19 @@ def _spmm_stream_kernel(idx_ref, w_ref, h_ref, o_ref, gath_ref, acc_ref,
                         sem_ref, *, K: int, block_rows: int, block_d: int):
     """Streaming body: per-row HBM→VMEM DMA gathers, double-buffered over k.
 
-    h_ref lives in HBM (``pltpu.ANY``); gath_ref is a (2, bn, bd) VMEM
+    h_ref lives in HBM (``pl.ANY``); gath_ref is a (2, bn, bd) VMEM
     double buffer; sem_ref a (2,) DMA-semaphore array, one per slot. Neighbor
     plane k lands in slot k % 2: its copies are started one plane ahead
     (while plane k-1's multiply-add runs) and waited right before use. Every
     started copy is waited in the same grid step, so no DMA crosses grid-step
     boundaries.
     """
-    row0 = pl.program_id(0) * block_rows
     col0 = pl.program_id(1) * block_d
 
     def plane(k, slot, op):
         """start()/wait() the bn row-copies of neighbor plane k into slot."""
         def row(r, _):
-            j = idx_ref[row0 + r, k]
+            j = idx_ref[r, k]
             op(pltpu.make_async_copy(
                 h_ref.at[pl.ds(j, 1), pl.ds(col0, block_d)],
                 gath_ref.at[slot, pl.ds(r, 1), :],
@@ -131,7 +143,7 @@ def _spmm_stream_kernel(idx_ref, w_ref, h_ref, o_ref, gath_ref, acc_ref,
             plane(k + 1, jax.lax.rem(k + 1, 2), lambda dma: dma.start())
 
         plane(k, slot, lambda dma: dma.wait())
-        acc_ref[:] += (w_ref[:, pl.ds(k, 1)].astype(jnp.float32)
+        acc_ref[:] += (_weight_column(w_ref, k)
                        * gath_ref[slot].astype(jnp.float32))
         return 0
 
@@ -164,7 +176,7 @@ def ell_spmm(nbr_idx: jax.Array, nbr_w: jax.Array, h: jax.Array, *,
     if stream:
         kernel = functools.partial(_spmm_stream_kernel, K=k,
                                    block_rows=block_rows, block_d=block_d)
-        h_spec = pl.BlockSpec(memory_space=pltpu.ANY)  # stays in HBM
+        h_spec = pl.BlockSpec(memory_space=pl.ANY)  # stays in HBM
         scratch = [pltpu.VMEM((2, block_rows, block_d), h.dtype),
                    pltpu.VMEM((block_rows, block_d), jnp.float32),
                    pltpu.SemaphoreType.DMA((2,))]
@@ -174,25 +186,25 @@ def ell_spmm(nbr_idx: jax.Array, nbr_w: jax.Array, h: jax.Array, *,
         # lint: ok(R003) legacy resident path: stream=True is the default and
         # Mosaic rejects >12 MiB blocks at compile time; kept for small
         # sources + streamed-vs-resident benchmarking (module docstring)
-        h_spec = pl.BlockSpec((m, block_d), lambda i, j, idx: (0, j))
+        h_spec = pl.BlockSpec((m, block_d), lambda i, j: (0, j))
         scratch = [pltpu.VMEM((block_rows, block_d), h.dtype),
                    pltpu.VMEM((block_rows, block_d), jnp.float32)]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,  # nbr_idx -> SMEM, readable before DMA
+    return pl.pallas_call(
+        kernel,
         grid=grid,
         in_specs=[
+            # SMEM: one (block_rows, K) index tile, its minor dim padded to
+            # 128 words, so (256, 128) int32 = 128 KiB per buffer
+            pl.BlockSpec((block_rows, k), lambda i, j: (i, 0),
+                         memory_space=pltpu.SMEM),
             # lint: ok(R003) K <= 128 by bucket construction: build_ell caps
             # bucket widths at powers of two <= 128, so this w tile is at
             # most (256, 128) f32 = 128 KiB
-            pl.BlockSpec((block_rows, k), lambda i, j, idx: (i, 0)),
+            pl.BlockSpec((block_rows, k), lambda i, j: (i, 0)),
             h_spec,
         ],
-        out_specs=pl.BlockSpec((block_rows, block_d), lambda i, j, idx: (i, j)),
+        out_specs=pl.BlockSpec((block_rows, block_d), lambda i, j: (i, j)),
         scratch_shapes=scratch,
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n, d), h.dtype),
         interpret=interpret,
     )(nbr_idx, nbr_w, h)

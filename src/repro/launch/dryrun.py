@@ -155,7 +155,7 @@ def run_gnn_cell(*, multi_pod: bool, verbose: bool = True) -> dict:
     from repro.models import make_gnn
     import jax.numpy as jnp
 
-    from repro.dist.sharding import dp_axis_size
+    from repro.dist.sharding import activation_sharding, dp_axis_size
 
     mesh = make_production_mesh(multi_pod=multi_pod)
     ndp = dp_axis_size(mesh)
@@ -187,7 +187,7 @@ def run_gnn_cell(*, multi_pod: bool, verbose: bool = True) -> dict:
     store_sh_t = HistoricalState(h=store_sh["h"], v=store_sh["v"])
 
     t0 = time.time()
-    with mesh:
+    with activation_sharding(mesh):
         # donate the historical stores: the production trainer updates them
         # in place (H̄/V̄ are step-local state, §Perf GNN iteration)
         lowered = jax.jit(step, in_shardings=(params_sh, store_sh_t, batch_sh,

@@ -266,7 +266,22 @@ def test_r003_oversized_blockspec():
 
 def test_r003_unbounded_resident_block():
     (f,) = live(VMEM_UNBOUNDED, "R003")
-    assert "runtime-valued" in f.message and "`pltpu.ANY`" in f.message
+    assert "runtime-valued" in f.message and "`pl.ANY`" in f.message
+
+SMEM_BLOCK = """
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    def f(n, k):
+        return pl.BlockSpec((n, k), lambda i: (i, 0),
+                            memory_space=pltpu.SMEM)
+"""
+
+
+def test_r003_ignores_smem_blocks():
+    """An SMEM BlockSpec is not VMEM residency, whatever its shape."""
+    assert live(SMEM_BLOCK, "R003") == []
+    (f,) = live(SMEM_BLOCK.replace("pltpu.SMEM", "pltpu.VMEM"), "R003")
+    assert "runtime-valued" in f.message
 
 
 def test_r003_resolves_param_defaults_and_dtypes():

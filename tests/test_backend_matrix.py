@@ -15,6 +15,7 @@ interchangeable gradient estimators:
     ``store_writes=False`` methods never write it.
 """
 import jax
+import jax.extend.core
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -215,7 +216,7 @@ def test_ti_step_never_reads_the_store(tiny_graph):
                   if any(a is sl for sl in store_leaves)}
     assert len(store_vars) == len(store_leaves)
     used = {id(v) for eqn in closed.jaxpr.eqns for v in eqn.invars
-            if not isinstance(v, jax.core.Literal)}
+            if not isinstance(v, jax.extend.core.Literal)}
     assert not (store_vars & used), "ti step consumed a store input"
 
 

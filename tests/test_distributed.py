@@ -16,6 +16,7 @@ def test_distributed_lmc_step_matches_single_device():
         from repro.core import make_train_step, init_history, from_graph, LMC
         from repro.core.distributed import stack_batches, spmd_shardings
         from repro.core.history import HistoricalState
+        from repro.dist.sharding import activation_sharding
         from repro.launch.mesh import make_mesh
         from repro.models import make_gnn
 
@@ -38,7 +39,7 @@ def test_distributed_lmc_step_matches_single_device():
         bsh, ssh, xsh, swsh, psh = spmd_shardings(mesh)
         store_sh = HistoricalState(h=ssh["h"], v=ssh["v"])
         params_sh = jax.tree.map(lambda _: psh, params)
-        with mesh:
+        with activation_sharding(mesh):
             jstep = jax.jit(step, in_shardings=(params_sh, store_sh, bsh, xsh, swsh))
             l_spmd, g_spmd, _, _ = jstep(params, store, flat, data.x, data.self_w)
         assert abs(float(l_ref) - float(l_spmd)) < 1e-4, (l_ref, l_spmd)
@@ -60,6 +61,7 @@ def test_multipod_lmc_step_matches_single_device():
         from repro.core import make_train_step, init_history, from_graph, LMC
         from repro.core.distributed import stack_batches, spmd_shardings
         from repro.core.history import HistoricalState
+        from repro.dist.sharding import activation_sharding
         from repro.launch.mesh import make_mesh
         from repro.models import make_gnn
 
@@ -82,7 +84,7 @@ def test_multipod_lmc_step_matches_single_device():
         bsh, ssh, xsh, swsh, psh = spmd_shardings(mesh)
         store_sh = HistoricalState(h=ssh["h"], v=ssh["v"])
         params_sh = jax.tree.map(lambda _: psh, params)
-        with mesh:
+        with activation_sharding(mesh):
             jstep = jax.jit(step, in_shardings=(params_sh, store_sh, bsh,
                                                 xsh, swsh))
             l_3ax, g_3ax, st_3ax, _ = jstep(params, store, flat,

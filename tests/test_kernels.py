@@ -74,6 +74,24 @@ def test_lmc_compensate_matches_ref(seed, beta_max):
                                rtol=1e-6, atol=1e-6)
 
 
+@pytest.mark.parametrize("stream", [True, False], ids=["streamed", "resident"])
+def test_lmc_compensate_across_id_blocks_and_feature_tiles(stream):
+    """Six row tiles whose ids span two 1024-word SMEM blocks, times two
+    feature tiles: the streamed kernel's next-tile DMA crosses both a
+    feature-tile and an id-block boundary."""
+    rng = np.random.default_rng(0)
+    n, m, d = 1300, 700, 256
+    args = [jnp.asarray(a) for a in (
+        rng.normal(size=(m, d)).astype(np.float32),
+        rng.integers(0, m, n).astype(np.int32),
+        rng.random(n).astype(np.float32),
+        rng.normal(size=(n, d)).astype(np.float32),
+        (rng.random(n) > 0.2).astype(np.float32))]
+    np.testing.assert_allclose(
+        np.asarray(lmc_compensate(*args, stream=stream)),
+        np.asarray(lmc_compensate_ref(*args)), rtol=1e-6, atol=1e-6)
+
+
 def test_bucketed_spmm_on_real_graph(small_graph):
     g = small_graph
     rng = np.random.default_rng(0)
@@ -314,31 +332,6 @@ def test_interpret_autodetect():
     np.testing.assert_allclose(np.asarray(out),
                                np.asarray(ell_spmm_ref(idx, w, h)),
                                rtol=2e-5, atol=2e-5)
-
-
-def test_compiled_path_lowers_and_compiles():
-    """interpret=False must lower + compile (TPU-only: Mosaic cannot lower on
-    CPU — the autodetect covers that case, asserted above). ``stream=False``
-    pins the legacy resident-block path here; the streamed (default) compile
-    check lives in tests/test_streaming.py."""
-    if jax.default_backend() != "tpu":
-        pytest.skip("no TPU in this container; compiled Mosaic lowering "
-                    "requires a TPU backend")
-    rng = np.random.default_rng(0)
-    idx = jnp.asarray(rng.integers(0, 512, (256, 8)).astype(np.int32))
-    w = jnp.asarray(rng.random((256, 8)).astype(np.float32))
-    h = jnp.asarray(rng.normal(size=(512, 128)).astype(np.float32))
-    jax.jit(lambda a, b, c: ell_spmm(a, b, c, interpret=False,
-                                     stream=False)).lower(
-        idx, w, h).compile()
-    store = jnp.asarray(rng.normal(size=(512, 128)).astype(np.float32))
-    gids = jnp.asarray(rng.integers(0, 512, 256).astype(np.int32))
-    beta = jnp.asarray(rng.random(256).astype(np.float32))
-    mask = jnp.asarray((rng.random(256) > 0.5).astype(np.float32))
-    fresh = jnp.asarray(rng.normal(size=(256, 128)).astype(np.float32))
-    jax.jit(lambda *a: lmc_compensate(*a, interpret=False,
-                                      stream=False)).lower(
-        store, gids, beta, fresh, mask).compile()
 
 
 @pytest.mark.slow

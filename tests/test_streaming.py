@@ -2,12 +2,11 @@
 (fwd + custom-VJP bwd) must match the jnp oracles at gather-source sizes well
 past the old ~24k-row resident-block VMEM cap, and streamed/resident must be
 bit-compatible where both run. CPU CI exercises the exact DMA/semaphore
-protocol through the Pallas interpreter; the compiled Mosaic lowering is
-asserted by the TPU-gated compile check."""
+protocol through the Pallas interpreter; tests/test_tpu_compile.py compiles
+the same kernels for a described TPU v5e."""
 import jax
 import jax.numpy as jnp
 import numpy as np
-import pytest
 from _prop import given, settings, strategies as st
 
 from repro.kernels import (build_ell, bucketed_spmm, default_stream,
@@ -133,27 +132,3 @@ def test_stream_default_is_streaming():
     np.testing.assert_allclose(np.asarray(out),
                                np.asarray(ell_spmm_ref(idx, w, h)),
                                rtol=2e-5, atol=2e-5)
-
-
-def test_streamed_path_lowers_and_compiles():
-    """interpret=False + stream=True must lower + compile with gather sources
-    beyond the old cap (TPU-only: Mosaic cannot lower on CPU) — mirrors
-    test_compiled_path_lowers_and_compiles for the streamed kernels."""
-    if jax.default_backend() != "tpu":
-        pytest.skip("no TPU in this container; compiled Mosaic lowering "
-                    "requires a TPU backend")
-    rng = np.random.default_rng(0)
-    m = BIG_M
-    idx = jnp.asarray(rng.integers(0, m, (256, 8)).astype(np.int32))
-    w = jnp.asarray(rng.random((256, 8)).astype(np.float32))
-    h = jnp.asarray(rng.normal(size=(m, 128)).astype(np.float32))
-    jax.jit(lambda a, b, c: ell_spmm(a, b, c, interpret=False,
-                                     stream=True)).lower(idx, w, h).compile()
-    store = jnp.asarray(rng.normal(size=(m, 128)).astype(np.float32))
-    gids = jnp.asarray(rng.integers(0, m, 256).astype(np.int32))
-    beta = jnp.asarray(rng.random(256).astype(np.float32))
-    mask = jnp.asarray((rng.random(256) > 0.5).astype(np.float32))
-    fresh = jnp.asarray(rng.normal(size=(256, 128)).astype(np.float32))
-    jax.jit(lambda *a: lmc_compensate(*a, interpret=False,
-                                      stream=True)).lower(
-        store, gids, beta, fresh, mask).compile()
