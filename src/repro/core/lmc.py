@@ -20,6 +20,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import tracing
 from repro.core.history import HistoricalState, gather_rows, scatter_rows
 from repro.core.methods import MBMethod
 from repro.dist.sharding import concat_rows
@@ -118,12 +119,14 @@ def _combine(mode: str, beta: jax.Array, hist: jax.Array, fresh: jax.Array,
     return out * mask
 
 
-def _compensate(mode: str, backend: str, store_l: Optional[jax.Array],
+def _compensate(mode: str, backend: str, store: Optional[jax.Array], l: int,
                 halo_gids: jax.Array, beta1d: jax.Array, fresh: jax.Array,
                 mask1d: jax.Array, stream: Optional[bool] = None,
                 ti_scale: Optional[jax.Array] = None) -> jax.Array:
-    """Halo compensation ĥ/V̂ (Eq. 9/12): gather the historical rows and
-    convex-combine with the incomplete fresh values.
+    """Halo compensation ĥ/V̂ (Eq. 9/12): gather the historical rows of
+    layer ``l`` of ``store`` and convex-combine with the incomplete fresh
+    values, under the ``lmc.halo`` scope. ``store`` may be None where no
+    mode reads it (``backend="ti"``).
 
     backend="segment": jnp gather + lerp. backend="ell": one fused Pallas
     ``lmc_compensate`` call — every mode is the same kernel with an effective
@@ -139,22 +142,32 @@ def _compensate(mode: str, backend: str, store_l: Optional[jax.Array],
     effective-β trick. No store read, no gather, no kernel: strictly less
     memory traffic than either store-reading backend.
     """
-    if mode == "none":
-        return jnp.zeros_like(fresh)
-    if backend == "ti":
-        beta_eff = {"lmc": beta1d,
-                    "historical": jnp.zeros_like(beta1d),
-                    "fresh": jnp.ones_like(beta1d)}[mode]
-        coeff = (1.0 - beta_eff) * ti_scale + beta_eff
-        return fresh * (coeff * mask1d)[:, None]
-    if backend == "ell":
-        beta_eff = {"lmc": beta1d,
-                    "historical": jnp.zeros_like(beta1d),
-                    "fresh": jnp.ones_like(beta1d)}[mode]
-        return lmc_compensate(store_l, halo_gids, beta_eff, fresh, mask1d,
-                              stream=stream)
-    hist = gather_rows(store_l, halo_gids)
-    return _combine(mode, beta1d[:, None], hist, fresh, mask1d[:, None])
+    with jax.named_scope(tracing.HALO):
+        if mode == "none":
+            return jnp.zeros_like(fresh)
+        if backend == "ti":
+            beta_eff = {"lmc": beta1d,
+                        "historical": jnp.zeros_like(beta1d),
+                        "fresh": jnp.ones_like(beta1d)}[mode]
+            coeff = (1.0 - beta_eff) * ti_scale + beta_eff
+            return fresh * (coeff * mask1d)[:, None]
+        if backend == "ell":
+            beta_eff = {"lmc": beta1d,
+                        "historical": jnp.zeros_like(beta1d),
+                        "fresh": jnp.ones_like(beta1d)}[mode]
+            return lmc_compensate(store[l], halo_gids, beta_eff, fresh,
+                                  mask1d, stream=stream)
+        hist = gather_rows(store[l], halo_gids)
+        return _combine(mode, beta1d[:, None], hist, fresh, mask1d[:, None])
+
+
+def _refresh(store: jax.Array, l: int, gids: jax.Array, mask: jax.Array,
+             rows: jax.Array, num_nodes: int) -> jax.Array:
+    """Store refresh: write the batch rows into layer ``l`` of ``store``,
+    under the ``lmc.store`` scope."""
+    with jax.named_scope(tracing.STORE):
+        return store.at[l].set(scatter_rows(store[l], gids, mask, rows,
+                                            num_nodes))
 
 
 def make_infer_step(gnn: GNN, num_nodes: int, *, backend: str = "segment",
@@ -210,34 +223,37 @@ def make_infer_step(gnn: GNN, num_nodes: int, *, backend: str = "segment",
                 'compensation="ti" needs batch.ti_scale; attach the '
                 "subgraph's α scales (host_batch(sg, backend=\"ti\") or "
                 "Batch._replace)")
-        ext_gids = concat_rows([batch.batch_gids, batch.halo_gids])
-        x_ext = jnp.take(x_full, ext_gids, axis=0, mode="clip")
-        self_w_ext = jnp.take(self_w_full, ext_gids, axis=0, mode="clip")
+        with jax.named_scope(tracing.DENSE):
+            ext_gids = concat_rows([batch.batch_gids, batch.halo_gids])
+            x_ext = jnp.take(x_full, ext_gids, axis=0, mode="clip")
+            self_w_ext = jnp.take(self_w_full, ext_gids, axis=0, mode="clip")
+            h0_ext = gnn.embed_apply(params["embed"], x_ext)
+            bmask = batch.batch_mask[:, None]
         edges = EdgeList(batch.edge_src, batch.edge_dst, batch.edge_w)
-        h0_ext = gnn.embed_apply(params["embed"], x_ext)
         aux = LayerAux(edges=edges, x=x_ext, h0=h0_ext, self_w=self_w_ext,
                        ell=batch.ell if backend == "ell" else None,
                        stream=stream)
-        bmask = batch.batch_mask[:, None]
         comp_backend = "ti" if compensation == "ti" else backend
 
         h_in = h0_ext
         new_h = store.h
         for l in range(L):
             h_out = gnn.layer_apply(gnn.layer_params(params, l), l, h_in, aux)
-            h_bar_batch = h_out[:nb] * bmask
+            with jax.named_scope(tracing.DENSE):
+                h_bar_batch = h_out[:nb] * bmask
             h_hat_halo = _compensate(
                 fwd_mode, comp_backend,
-                None if compensation == "ti" else new_h[l],
+                None if compensation == "ti" else new_h, l,
                 batch.halo_gids, batch.beta, h_out[nb:], batch.halo_mask,
                 stream, batch.ti_scale)
             if refresh:
-                new_h = new_h.at[l].set(scatter_rows(
-                    new_h[l], batch.batch_gids, batch.batch_mask, h_bar_batch,
-                    num_nodes))
-            h_in = concat_rows([h_bar_batch, h_hat_halo], axis=0)
+                new_h = _refresh(new_h, l, batch.batch_gids,
+                                 batch.batch_mask, h_bar_batch, num_nodes)
+            with jax.named_scope(tracing.DENSE):
+                h_in = concat_rows([h_bar_batch, h_hat_halo], axis=0)
 
-        logits = gnn.head_apply(params["head"], h_in[:nb])
+        with jax.named_scope(tracing.DENSE):
+            logits = gnn.head_apply(params["head"], h_in[:nb])
         return logits, HistoricalState(h=new_h, v=store.v)
 
     return infer
@@ -285,19 +301,22 @@ def make_train_step(gnn: GNN, method: MBMethod, num_nodes: int, *,
             raise ValueError(
                 'backend="ti" needs batch.ti_scale; build the batch with '
                 'to_device_batch(sg, backend="ti")')
+        # every op runs under one of the scopes of repro.tracing; the layers'
+        # vjp calls stay outside them, so a transposed op's path reads
+        # transpose(jvp(<its forward scope>)) and no op carries two scopes.
         # concat_rows (not jnp.concatenate): [batch | halo] row blocks must
         # keep explicit shardings under SPMD — see repro.dist.sharding
-        ext_gids = concat_rows([batch.batch_gids, batch.halo_gids])
-        x_ext = jnp.take(x_full, ext_gids, axis=0, mode="clip")
-        self_w_ext = jnp.take(self_w_full, ext_gids, axis=0, mode="clip")
+        with jax.named_scope(tracing.DENSE):
+            ext_gids = concat_rows([batch.batch_gids, batch.halo_gids])
+            x_ext = jnp.take(x_full, ext_gids, axis=0, mode="clip")
+            self_w_ext = jnp.take(self_w_full, ext_gids, axis=0, mode="clip")
+            h0_ext = gnn.embed_apply(params["embed"], x_ext)
+            bmask = batch.batch_mask[:, None]
+            hmask = batch.halo_mask[:, None]
         edges = EdgeList(batch.edge_src, batch.edge_dst, batch.edge_w)
-        h0_ext = gnn.embed_apply(params["embed"], x_ext)
         aux = LayerAux(edges=edges, x=x_ext, h0=h0_ext, self_w=self_w_ext,
                        ell=batch.ell if backend in ("ell", "ti") else None,
                        stream=stream)
-
-        bmask = batch.batch_mask[:, None]
-        hmask = batch.halo_mask[:, None]
 
         # ---------------- forward (Eqs. 8-10) --------------------------------
         h_in = h0_ext
@@ -306,46 +325,49 @@ def make_train_step(gnn: GNN, method: MBMethod, num_nodes: int, *,
         for l in range(L):
             residuals.append(h_in)
             h_out = gnn.layer_apply(gnn.layer_params(params, l), l, h_in, aux)
-            h_bar_batch = h_out[:nb] * bmask
+            with jax.named_scope(tracing.DENSE):
+                h_bar_batch = h_out[:nb] * bmask
             # ti never touches the store — don't even slice it (keeps the
             # store inputs provably dead in the step's jaxpr)
             h_hat_halo = _compensate(method.fwd_mode, backend,
-                                     None if backend == "ti" else new_h[l],
+                                     None if backend == "ti" else new_h, l,
                                      batch.halo_gids, batch.beta, h_out[nb:],
                                      batch.halo_mask, stream, batch.ti_scale)
             if method.store_writes:
-                new_h = new_h.at[l].set(scatter_rows(
-                    new_h[l], batch.batch_gids, batch.batch_mask, h_bar_batch,
-                    num_nodes))
-            h_in = concat_rows([h_bar_batch, h_hat_halo], axis=0)
+                new_h = _refresh(new_h, l, batch.batch_gids, batch.batch_mask,
+                                 h_bar_batch, num_nodes)
+            with jax.named_scope(tracing.DENSE):
+                h_in = concat_rows([h_bar_batch, h_hat_halo], axis=0)
 
         # ---------------- loss & top-layer adjoints (Eq. 6/14 + V^L init) ----
-        inv_vl = batch.loss_scale / batch.grad_scale  # = 1/|V_L|
-        mask_b = batch.labeled_mask.at[nb:].set(0.0)
-        mask_h = batch.labeled_mask.at[:nb].set(0.0)
+        with jax.named_scope(tracing.DENSE):
+            inv_vl = batch.loss_scale / batch.grad_scale  # = 1/|V_L|
+            mask_b = batch.labeled_mask.at[nb:].set(0.0)
+            mask_h = batch.labeled_mask.at[:nb].set(0.0)
 
-        def unit_loss(head, h_rows, m):
-            logits = gnn.head_apply(head, h_rows)
-            logp = jax.nn.log_softmax(logits)
-            ll = jnp.take_along_axis(logp, batch.labels[:, None], axis=-1)[:, 0]
-            return -jnp.sum(ll * m) * inv_vl, logits
+            def unit_loss(head, h_rows, m):
+                logits = gnn.head_apply(head, h_rows)
+                logp = jax.nn.log_softmax(logits)
+                ll = jnp.take_along_axis(logp, batch.labels[:, None], axis=-1)[:, 0]
+                return -jnp.sum(ll * m) * inv_vl, logits
 
-        (f1, logits_ext), vjp1 = jax.vjp(
-            lambda hd, h: unit_loss(hd, h, mask_b), params["head"], h_in, has_aux=False)
-        g_head_unit, V1 = vjp1((jnp.asarray(1.0, f1.dtype), jnp.zeros_like(logits_ext)))
-        V_bar = V1[:nb] * bmask
+            (f1, logits_ext), vjp1 = jax.vjp(
+                lambda hd, h: unit_loss(hd, h, mask_b), params["head"], h_in, has_aux=False)
+            g_head_unit, V1 = vjp1((jnp.asarray(1.0, f1.dtype), jnp.zeros_like(logits_ext)))
+            V_bar = V1[:nb] * bmask
 
-        if method.bwd_mode == "none":
-            V_hat = jnp.zeros_like(V1[nb:])
-        else:
-            (f2, _), vjp2 = jax.vjp(
-                lambda h: unit_loss(params["head"], h, mask_h), h_in)
-            (V2,) = vjp2((jnp.asarray(1.0, f1.dtype), jnp.zeros_like(logits_ext)))
-            V_hat = V2[nb:] * hmask
+            if method.bwd_mode == "none":
+                V_hat = jnp.zeros_like(V1[nb:])
+            else:
+                (f2, _), vjp2 = jax.vjp(
+                    lambda h: unit_loss(params["head"], h, mask_h), h_in)
+                (V2,) = vjp2((jnp.asarray(1.0, f1.dtype), jnp.zeros_like(logits_ext)))
+                V_hat = V2[nb:] * hmask
 
         # ---------------- backward message passing (Eqs. 11-13, 7/15) --------
         grads_layers = [None] * L
-        v0_acc = jnp.zeros_like(h0_ext)
+        with jax.named_scope(tracing.DENSE):
+            v0_acc = jnp.zeros_like(h0_ext)
         new_v = store.v
         for l in reversed(range(L)):
             lp = gnn.layer_params(params, l)
@@ -354,51 +376,56 @@ def make_train_step(gnn: GNN, method: MBMethod, num_nodes: int, *,
                 return gnn.layer_apply(lp_, _l, hin_, aux._replace(h0=h0_))
 
             _, vjp_fn = jax.vjp(f, lp, residuals[l], h0_ext)
-            ct_batch = concat_rows([V_bar, jnp.zeros_like(V_hat)], axis=0)
+            with jax.named_scope(tracing.DENSE):
+                ct_batch = concat_rows([V_bar, jnp.zeros_like(V_hat)], axis=0)
             g_lp, hgrad_b, h0grad_b = vjp_fn(ct_batch)
             grads_layers[l] = g_lp
             if method.bwd_mode == "none":
                 hgrad, h0grad = hgrad_b, h0grad_b
             else:
-                ct_full = concat_rows([V_bar, V_hat], axis=0)
+                with jax.named_scope(tracing.DENSE):
+                    ct_full = concat_rows([V_bar, V_hat], axis=0)
                 _, hgrad, h0grad = vjp_fn(ct_full)
-            v0_acc = v0_acc + h0grad
+            with jax.named_scope(tracing.DENSE):
+                v0_acc = v0_acc + h0grad
             if l >= 1:
-                V_bar_next = hgrad[:nb] * bmask
+                with jax.named_scope(tracing.DENSE):
+                    V_bar_next = hgrad[:nb] * bmask
                 V_hat = _compensate(method.bwd_mode, backend,
-                                    None if backend == "ti" else new_v[l - 1],
+                                    None if backend == "ti" else new_v, l - 1,
                                     batch.halo_gids, batch.beta, hgrad[nb:],
                                     batch.halo_mask, stream, batch.ti_scale)
                 if method.store_writes:
-                    new_v = new_v.at[l - 1].set(scatter_rows(
-                        new_v[l - 1], batch.batch_gids, batch.batch_mask,
-                        V_bar_next, num_nodes))
+                    new_v = _refresh(new_v, l - 1, batch.batch_gids,
+                                     batch.batch_mask, V_bar_next, num_nodes)
                 V_bar = V_bar_next
             elif layer0_input_is_h0:
-                v0_acc = v0_acc + hgrad
+                with jax.named_scope(tracing.DENSE):
+                    v0_acc = v0_acc + hgrad
 
         # ---------------- parameter gradients (Eq. 7 with A.3.1 scaling) -----
-        scale = batch.grad_scale
-        grads = {
-            "layers": jax.tree.map(lambda *xs: [scale * x for x in xs],
-                                   *grads_layers),
-            "head": jax.tree.map(lambda x: scale * x, g_head_unit),
-        }
-        if params["embed"]:
-            _, vjp_emb = jax.vjp(lambda e: gnn.embed_apply(e, x_ext), params["embed"])
-            (g_emb,) = vjp_emb(v0_acc * concat_rows(
-                [bmask, jnp.zeros_like(hmask)], axis=0))
-            grads["embed"] = jax.tree.map(lambda x: scale * x, g_emb)
-        else:
-            grads["embed"] = {}
+        with jax.named_scope(tracing.DENSE):
+            scale = batch.grad_scale
+            grads = {
+                "layers": jax.tree.map(lambda *xs: [scale * x for x in xs],
+                                       *grads_layers),
+                "head": jax.tree.map(lambda x: scale * x, g_head_unit),
+            }
+            if params["embed"]:
+                _, vjp_emb = jax.vjp(lambda e: gnn.embed_apply(e, x_ext), params["embed"])
+                (g_emb,) = vjp_emb(v0_acc * concat_rows(
+                    [bmask, jnp.zeros_like(hmask)], axis=0))
+                grads["embed"] = jax.tree.map(lambda x: scale * x, g_emb)
+            else:
+                grads["embed"] = {}
 
-        # ---------------- metrics -------------------------------------------
-        loss = f1 * scale
-        pred = jnp.argmax(logits_ext[:nb], axis=-1)
-        lab_b = mask_b[:nb]
-        acc = jnp.sum((pred == batch.labels[:nb]) * lab_b) / jnp.maximum(
-            jnp.sum(lab_b), 1.0)
-        metrics = {"loss": loss, "train_acc": acc}
+            # ---------------- metrics ---------------------------------------
+            loss = f1 * scale
+            pred = jnp.argmax(logits_ext[:nb], axis=-1)
+            lab_b = mask_b[:nb]
+            acc = jnp.sum((pred == batch.labels[:nb]) * lab_b) / jnp.maximum(
+                jnp.sum(lab_b), 1.0)
+            metrics = {"loss": loss, "train_acc": acc}
         return loss, grads, HistoricalState(h=new_h, v=new_v), metrics
 
     return step

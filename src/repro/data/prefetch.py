@@ -16,6 +16,12 @@ Two layers live here (DESIGN.md §9):
   recycling) before resampling; LMC's bounded-staleness historical stores
   keep this within the Thm 2 staleness budget because the store-refresh path
   is unchanged — every recycled step still rewrites its store rows.
+
+The pipeline's host work runs under the spans of ``repro.tracing``:
+``pipeline.build`` on the building thread, ``pipeline.wait`` where the
+consumer takes its next batch (blocking on the queue unless one is staged),
+``pipeline.h2d`` around every ``jax.device_put``. :attr:`SubgraphPipeline.last_fetch` holds their seconds
+for the step last yielded.
 """
 from __future__ import annotations
 
@@ -25,6 +31,11 @@ import threading
 from collections import deque
 from concurrent.futures import ThreadPoolExecutor
 from typing import Callable, Iterator, Optional
+
+from repro.tracing import span
+
+# SubgraphPipeline.last_fetch of a step that fetched nothing
+NO_FETCH = {"wait_s": 0.0, "h2d_s": 0.0, "staged": False, "build_s": 0.0}
 
 
 class _Done:
@@ -204,6 +215,13 @@ class SubgraphPipeline:
     Thread-safety: single consumer thread; the sampler's schedule API
     (``clusters_at``/``build_batch``) is called concurrently from workers
     and must stay read-only (``ClusterSampler``'s is).
+
+    Counters: after each ``next``, :attr:`last_fetch` holds ``wait_s``
+    (taking the batch: blocked on the queue, or ~0 when staged), ``h2d_s`` (in ``jax.device_put``, the staged
+    next batch's included), ``staged`` (the batch came from the device-side
+    double buffer) and ``build_s`` (the seconds that built the yielded
+    slot, on its worker). A recycled step fetches nothing: its seconds are
+    0 and ``staged`` is False.
     """
 
     def __init__(self, sampler, *, backend: str = "segment", depth: int = 2,
@@ -259,7 +277,8 @@ class SubgraphPipeline:
         self._end_step = None if num_steps is None else self._step + int(num_steps)
         self._cur_slot = -1
         self._cur_batch = None
-        self._staged = None          # device batch for the next slot
+        self._staged = None          # (device batch, build_s), next slot
+        self.last_fetch = dict(NO_FETCH)
         self._closed = False
         self._pool: Optional[ThreadPoolExecutor] = None
         self._pf: Optional[Prefetcher] = None
@@ -284,8 +303,17 @@ class SubgraphPipeline:
         return host_batch(sg, backend=self.backend,
                           ell_buckets=self.ell_buckets)
 
+    def _timed_build(self, slot: int):
+        """``_build_host`` under the ``pipeline.build`` span; returns
+        (host Batch, seconds it took)."""
+        took = {}
+        with span("pipeline.build", took):
+            hb = self._build_host(slot)
+        return hb, took["build_s"]
+
     def _built_stream(self, first_slot: int, end_slot: Optional[int]):
-        """Generator the Prefetcher drives: in-order built host batches.
+        """Generator the Prefetcher drives: in-order (host batch, build
+        seconds) pairs.
 
         Keeps up to ``workers`` build futures in flight; ``.result()``
         re-raises worker exceptions in slot order so the Prefetcher's
@@ -301,7 +329,7 @@ class SubgraphPipeline:
                         s = next(slots)
                     except StopIteration:
                         break
-                    pending.append(self._pool.submit(self._build_host, s))
+                    pending.append(self._pool.submit(self._timed_build, s))
                 if not pending:
                     return
                 yield pending.popleft().result()
@@ -316,19 +344,30 @@ class SubgraphPipeline:
         With prefetch: take the staged transfer if one exists, else block on
         the queue + ``device_put``; then opportunistically stage the transfer
         for the following slot (this is the device-side double buffer).
-        Without prefetch (``depth=0``): build + transfer inline.
+        Without prefetch (``depth=0``): build + transfer inline. Fills
+        :attr:`last_fetch`.
         """
         import jax
+        rec = self.last_fetch
         if self._pf is None:
-            slot = self._step // self.recycle
-            return jax.device_put(self._build_host(slot))
-        if self._staged is not None:
-            batch, self._staged = self._staged, None
-        else:
-            batch = jax.device_put(next(self._pf))   # may raise StopIteration
+            hb, rec["build_s"] = self._timed_build(self._step // self.recycle)
+            with span("pipeline.h2d", rec):
+                return jax.device_put(hb)
+        rec["staged"] = self._staged is not None
+        # the wait span opens on every fetch, a staged one too (where it
+        # reads ~0 s), so a trace tells "never waited" from "no such span"
+        with span("pipeline.wait", rec):
+            # may raise StopIteration
+            batch, rec["build_s"] = self._staged or next(self._pf)
+        self._staged = None
+        if not rec["staged"]:
+            with span("pipeline.h2d", rec):
+                batch = jax.device_put(batch)
         nxt = self._pf.poll()
         if nxt is not None:
-            self._staged = jax.device_put(nxt)
+            hb, took = nxt
+            with span("pipeline.h2d", rec):
+                self._staged = (jax.device_put(hb), took)
         return batch
 
     def __iter__(self):
@@ -342,6 +381,7 @@ class SubgraphPipeline:
         if self._end_step is not None and self._step >= self._end_step:
             raise StopIteration
         slot = self._step // self.recycle
+        self.last_fetch = dict(NO_FETCH)
         if slot != self._cur_slot:
             self._cur_batch = self._fetch_next_slot()
             self._cur_slot = slot

@@ -34,6 +34,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import tracing
+
 
 class EdgeList(NamedTuple):
     src: jax.Array   # (E,) int32 local source rows
@@ -130,32 +132,42 @@ class GNN:
 
     def _aggregate(self, aux: LayerAux, h: jax.Array, n: int) -> jax.Array:
         """Route aggregation: Pallas ELL kernel when the batch carries an
-        ELLGraph (train-step ``backend="ell"``), else the bound AggregateFn."""
-        if aux.ell is not None:
-            from repro.kernels import bucketed_spmm
-            return bucketed_spmm(aux.ell, h, stream=aux.stream)
-        return self.aggregate(aux.edges, h, n)
+        ELLGraph (train-step ``backend="ell"``), else the bound AggregateFn.
+        Runs under the ``lmc.agg`` scope."""
+        with jax.named_scope(tracing.AGG):
+            if aux.ell is not None:
+                from repro.kernels import bucketed_spmm
+                return bucketed_spmm(aux.ell, h, stream=aux.stream)
+            return self.aggregate(aux.edges, h, n)
 
     def layer_apply(self, lp: dict, l: int, h_in: jax.Array, aux: LayerAux) -> jax.Array:
-        """One message-passing layer over the local row set (batch + halo)."""
+        """One message-passing layer over the local row set (batch + halo):
+        the aggregation under the ``lmc.agg`` scope, the rest under
+        ``lmc.dense``."""
         n = h_in.shape[0]
-        if self.arch == "gcn":
-            agg = self._aggregate(aux, h_in, n) + aux.self_w[:, None] * h_in
-            return jax.nn.relu(agg @ lp["w"] + lp["b"])
-        if self.arch == "gcnii":
-            agg = self._aggregate(aux, h_in, n) + aux.self_w[:, None] * h_in
-            beta_l = float(np.log(self.lam / (l + 1) + 1.0))
-            sup = (1 - self.alpha) * agg + self.alpha * aux.h0
-            out = (1 - beta_l) * sup + beta_l * (sup @ lp["w"])
-            return jax.nn.relu(out)
+        agg = self._aggregate(aux, h_in, n)
         if self.arch == "sage":
-            deg = jax.ops.segment_sum(aux.edges.w, aux.edges.dst, num_segments=n)
-            agg = self._aggregate(aux, h_in, n) / jnp.maximum(deg, 1e-9)[:, None]
-            return jax.nn.relu(h_in @ lp["w_self"] + agg @ lp["w_nbr"] + lp["b"])
-        if self.arch == "gin":
-            agg = self._aggregate(aux, h_in, n) + (1.0 + lp["eps"]) * h_in
-            hid = jax.nn.relu(agg @ lp["w1"] + lp["b1"])
-            return jax.nn.relu(hid @ lp["w2"] + lp["b2"])
+            with jax.named_scope(tracing.AGG):
+                deg = jax.ops.segment_sum(aux.edges.w, aux.edges.dst,
+                                          num_segments=n)
+        with jax.named_scope(tracing.DENSE):
+            if self.arch == "gcn":
+                agg = agg + aux.self_w[:, None] * h_in
+                return jax.nn.relu(agg @ lp["w"] + lp["b"])
+            if self.arch == "gcnii":
+                agg = agg + aux.self_w[:, None] * h_in
+                beta_l = float(np.log(self.lam / (l + 1) + 1.0))
+                sup = (1 - self.alpha) * agg + self.alpha * aux.h0
+                out = (1 - beta_l) * sup + beta_l * (sup @ lp["w"])
+                return jax.nn.relu(out)
+            if self.arch == "sage":
+                agg = agg / jnp.maximum(deg, 1e-9)[:, None]
+                return jax.nn.relu(h_in @ lp["w_self"] + agg @ lp["w_nbr"]
+                                   + lp["b"])
+            if self.arch == "gin":
+                agg = agg + (1.0 + lp["eps"]) * h_in
+                hid = jax.nn.relu(agg @ lp["w1"] + lp["b1"])
+                return jax.nn.relu(hid @ lp["w2"] + lp["b2"])
         raise ValueError(self.arch)
 
     def head_apply(self, head: dict, h: jax.Array) -> jax.Array:

@@ -53,11 +53,12 @@ import numpy as np
 
 from repro.checkpoint import CheckpointError, CheckpointManager
 from repro.core import (HistoricalState, MBMethod, from_graph, accuracy,
-                        init_history, make_train_step, to_device_batch)
-from repro.data.prefetch import SubgraphPipeline
+                        host_batch, init_history, make_train_step)
+from repro.data.prefetch import NO_FETCH, SubgraphPipeline
 from repro.graph import ClusterSampler
 from repro.models.gnn import GNN
 from repro.optim.optimizers import Optimizer
+from repro.tracing import span
 from repro.train.health import (FailureInjector, FaultPlan, HealthConfig,
                                 HealthGuard, PipelineFault,
                                 SimulatedPreemption, TrainingDivergedError)
@@ -292,7 +293,9 @@ class GNNTrainer:
         target = self.step_num + num_steps
         while self.step_num < target:
             try:
-                self._one_step()
+                with jax.profiler.StepTraceAnnotation("train",
+                                                      step_num=self.step_num):
+                    self._one_step()
                 self._retries_left = self.max_retries  # healthy step: reset
             except SimulatedPreemption:
                 # crash recovery: restore last checkpoint and continue; a
@@ -318,7 +321,8 @@ class GNNTrainer:
                 continue
             if self.ckpt and self.step_num % self.ckpt_every == 0:
                 try:
-                    self.save()
+                    with span("train.ckpt"):
+                        self.save()
                 except OSError as e:   # includes injected CheckpointWriteFault
                     self.history.append({"step": self.step_num,
                                          "event": "ckpt-write-failed",
@@ -360,22 +364,36 @@ class GNNTrainer:
                              "policy": policy})
 
     def _one_step(self) -> None:
-        t0 = time.time()
-        if self._use_pipeline:
-            batch = next(self._batch_pipeline())   # may raise PipelineFault
-        else:
-            sg = self.sampler.sample()
-            batch = to_device_batch(sg, backend=self.backend)
+        """One step; its record splits ``time_s`` into the seconds of the
+        host spans (``fetch_s`` holding ``wait_s`` and ``h2d_s``,
+        ``dispatch_s``, ``sync_s``) and tells whether the batch was
+        ``staged`` on the device ahead of time and what it took to build
+        (``build_s``), as ``SubgraphPipeline.last_fetch`` defines them."""
+        t0 = time.perf_counter()
+        parts = dict(NO_FETCH)
+        with span("train.fetch", parts):
+            if self._use_pipeline:
+                batch = next(self._batch_pipeline())  # may raise PipelineFault
+                parts.update(self._pipeline.last_fetch)
+            else:
+                with span("pipeline.build", parts):
+                    hb = host_batch(self.sampler.sample(),
+                                    backend=self.backend)
+                with span("pipeline.h2d", parts):
+                    batch = jax.device_put(hb)
         if self.failure_injector is not None:
             self.failure_injector.maybe_fail(self.step_num)
             if isinstance(self.failure_injector, FaultPlan):
                 batch = self.failure_injector.corrupt_batch(self.step_num,
                                                             batch)
-        loss, grads, new_store, metrics = self._step(
-            self.params, self.store, batch, self.data.x, self.data.self_w)
-        new_params, new_opt, gnorm = self._update(
-            grads, self.opt_state, self.params, jnp.float32(self.lr))
-        lossf, gnormf = float(loss), float(gnorm)
+        with span("train.dispatch", parts):
+            loss, grads, new_store, metrics = self._step(
+                self.params, self.store, batch, self.data.x, self.data.self_w)
+            new_params, new_opt, gnorm = self._update(
+                grads, self.opt_state, self.params, jnp.float32(self.lr))
+        with span("train.sync", parts):
+            lossf, gnormf = float(loss), float(gnorm)
+            accf = float(metrics["train_acc"])
 
         # ---- health gate: nothing below is applied if this step diverged
         if self.guard is not None:
@@ -389,7 +407,7 @@ class GNNTrainer:
                 raise _Divergence(reason)
 
         self.params, self.opt_state = new_params, new_opt
-        dt = time.time() - t0
+        dt = time.perf_counter() - t0
         # straggler mitigation: drop the (stale-tolerant) store update when
         # this step blew its deadline, so the next step isn't gated on it
         med = float(np.median(self._step_times)) if self._step_times else dt
@@ -400,9 +418,8 @@ class GNNTrainer:
         if store_updated:
             self.store = new_store
         rec = {"step": self.step_num + 1, "loss": lossf,
-               "train_acc": float(metrics["train_acc"]),
-               "grad_norm": gnormf, "time_s": dt,
-               "straggler": bool(is_straggler)}
+               "train_acc": accf, "grad_norm": gnormf, "time_s": dt,
+               "straggler": bool(is_straggler), **parts}
         if self.guard is not None:
             self.guard.observe(lossf)
             # one fused device->host transfer for the staleness bookkeeping
