@@ -21,7 +21,7 @@ The pipeline's host work runs under the spans of ``repro.tracing``:
 ``pipeline.build`` on the building thread, ``pipeline.wait`` where the
 consumer takes its next batch (blocking on the queue unless one is staged),
 ``pipeline.h2d`` around every ``jax.device_put``. :attr:`SubgraphPipeline.last_fetch` holds their seconds
-for the step last yielded.
+for the step last yielded, and the yielded batch's edge fill.
 """
 from __future__ import annotations
 
@@ -219,9 +219,11 @@ class SubgraphPipeline:
     Counters: after each ``next``, :attr:`last_fetch` holds ``wait_s``
     (taking the batch: blocked on the queue, or ~0 when staged), ``h2d_s`` (in ``jax.device_put``, the staged
     next batch's included), ``staged`` (the batch came from the device-side
-    double buffer) and ``build_s`` (the seconds that built the yielded
-    slot, on its worker). A recycled step fetches nothing: its seconds are
-    0 and ``staged`` is False.
+    double buffer), ``build_s`` (the seconds that built the yielded
+    slot, on its worker) and ``edge_fill`` (the yielded batch's real edges
+    over its padded edge count). A recycled step fetches nothing: its
+    seconds are 0, ``staged`` is False, and ``edge_fill`` is the recycled
+    batch's.
     """
 
     def __init__(self, sampler, *, backend: str = "segment", depth: int = 2,
@@ -277,7 +279,8 @@ class SubgraphPipeline:
         self._end_step = None if num_steps is None else self._step + int(num_steps)
         self._cur_slot = -1
         self._cur_batch = None
-        self._staged = None          # (device batch, build_s), next slot
+        self._cur_fill = 0.0
+        self._staged = None          # (device batch, build record), next slot
         self.last_fetch = dict(NO_FETCH)
         self._closed = False
         self._pool: Optional[ThreadPoolExecutor] = None
@@ -294,26 +297,27 @@ class SubgraphPipeline:
 
     # ------------------------------------------------------------- producer
     def _build_host(self, slot: int):
-        """Worker-side: schedule slot -> host (numpy) Batch. Pure numpy."""
+        """Worker-side: schedule slot -> (host (numpy) Batch, its edge
+        fill). Pure numpy."""
         from repro.core.lmc import host_batch
         if self.build_hook is not None:
             self.build_hook(slot)
         cids = self.sampler.clusters_at(slot, mode=self.mode)
         sg = self.sampler.build_batch(cids)
         return host_batch(sg, backend=self.backend,
-                          ell_buckets=self.ell_buckets)
+                          ell_buckets=self.ell_buckets), sg.edge_fill
 
     def _timed_build(self, slot: int):
         """``_build_host`` under the ``pipeline.build`` span; returns
-        (host Batch, seconds it took)."""
+        (host Batch, {"build_s": seconds it took, "edge_fill": its fill})."""
         took = {}
         with span("pipeline.build", took):
-            hb = self._build_host(slot)
-        return hb, took["build_s"]
+            hb, took["edge_fill"] = self._build_host(slot)
+        return hb, took
 
     def _built_stream(self, first_slot: int, end_slot: Optional[int]):
         """Generator the Prefetcher drives: in-order (host batch, build
-        seconds) pairs.
+        record) pairs, as ``_timed_build`` returns them.
 
         Keeps up to ``workers`` build futures in flight; ``.result()``
         re-raises worker exceptions in slot order so the Prefetcher's
@@ -350,7 +354,8 @@ class SubgraphPipeline:
         import jax
         rec = self.last_fetch
         if self._pf is None:
-            hb, rec["build_s"] = self._timed_build(self._step // self.recycle)
+            hb, built = self._timed_build(self._step // self.recycle)
+            rec.update(built)
             with span("pipeline.h2d", rec):
                 return jax.device_put(hb)
         rec["staged"] = self._staged is not None
@@ -358,16 +363,17 @@ class SubgraphPipeline:
         # reads ~0 s), so a trace tells "never waited" from "no such span"
         with span("pipeline.wait", rec):
             # may raise StopIteration
-            batch, rec["build_s"] = self._staged or next(self._pf)
+            batch, built = self._staged or next(self._pf)
+        rec.update(built)
         self._staged = None
         if not rec["staged"]:
             with span("pipeline.h2d", rec):
                 batch = jax.device_put(batch)
         nxt = self._pf.poll()
         if nxt is not None:
-            hb, took = nxt
+            hb, built = nxt
             with span("pipeline.h2d", rec):
-                self._staged = (jax.device_put(hb), took)
+                self._staged = (jax.device_put(hb), built)
         return batch
 
     def __iter__(self):
@@ -385,6 +391,8 @@ class SubgraphPipeline:
         if slot != self._cur_slot:
             self._cur_batch = self._fetch_next_slot()
             self._cur_slot = slot
+            self._cur_fill = self.last_fetch["edge_fill"]
+        self.last_fetch["edge_fill"] = self._cur_fill
         self._step += 1
         return self._cur_batch
 

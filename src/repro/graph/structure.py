@@ -155,6 +155,11 @@ class PaddedSubgraph:
         """Extended-set row count NB + NH (the local id space)."""
         return self.n_batch + self.n_halo
 
+    @property
+    def edge_fill(self) -> float:
+        """Share of the padded edge list that holds real edges."""
+        return self.n_edges_real / max(int(self.edge_src.shape[0]), 1)
+
 
 def beta_score(local_deg: np.ndarray, global_deg: np.ndarray,
                score: str = "2x-x2", alpha: float = 1.0) -> np.ndarray:
@@ -336,6 +341,14 @@ def padded_sizes_for(graph: Graph, parts: np.ndarray, num_parts: int, c: int,
     Conservative: sums the c largest per-cluster stats, rounded up to friendly
     multiples so one jit shape covers every epoch. Per-cluster halo sizes and
     halo volumes are computed exactly (cheap: one CSR sweep per cluster).
+
+    ``pad_edges`` is also capped at ``graph.num_edges`` (rounded up to 256),
+    as ``serve.gateway.request_pads`` caps a request's: ``build_subgraph``
+    takes the edges into batch rows from the batch nodes' CSR rows and the
+    edges into halo rows from the halo nodes' CSR rows. The two row sets are
+    disjoint, so every edge of a batch is a distinct CSR entry. The top-c
+    sums overcount once halos overlap each other and the batch (c a large
+    share of the parts), and there the cap is what binds.
     """
     degrees = graph.degrees()
     src = np.repeat(np.arange(graph.num_nodes), np.diff(graph.indptr))
@@ -362,6 +375,9 @@ def padded_sizes_for(graph: Graph, parts: np.ndarray, num_parts: int, c: int,
 
     pad_batch = _round_up(top_sizes, 64)
     pad_halo = _round_up(max(top_halo, 1), 64) if include_halo else 64
-    # edges into batch rows ≤ batch volume; edges into halo rows ≤ halo volume
-    pad_edges = _round_up(top_vol + top_halo_vol + 64, 256)
+    # edges into batch rows ≤ batch volume; edges into halo rows ≤ halo
+    # volume; both together ≤ the graph's directed edges (256 keeps the flat
+    # four-chip batch of core/distributed.py divisible)
+    pad_edges = min(_round_up(top_vol + top_halo_vol + 64, 256),
+                    _round_up(max(graph.num_edges, 1), 256))
     return int(pad_batch), int(pad_halo), int(pad_edges)

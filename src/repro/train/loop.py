@@ -367,8 +367,9 @@ class GNNTrainer:
         """One step; its record splits ``time_s`` into the seconds of the
         host spans (``fetch_s`` holding ``wait_s`` and ``h2d_s``,
         ``dispatch_s``, ``sync_s``) and tells whether the batch was
-        ``staged`` on the device ahead of time and what it took to build
-        (``build_s``), as ``SubgraphPipeline.last_fetch`` defines them."""
+        ``staged`` on the device ahead of time, what it took to build
+        (``build_s``) and how full its padded edge list is (``edge_fill``),
+        as ``SubgraphPipeline.last_fetch`` defines them."""
         t0 = time.perf_counter()
         parts = dict(NO_FETCH)
         with span("train.fetch", parts):
@@ -377,8 +378,9 @@ class GNNTrainer:
                 parts.update(self._pipeline.last_fetch)
             else:
                 with span("pipeline.build", parts):
-                    hb = host_batch(self.sampler.sample(),
-                                    backend=self.backend)
+                    sg = self.sampler.sample()
+                    hb = host_batch(sg, backend=self.backend)
+                parts["edge_fill"] = sg.edge_fill
                 with span("pipeline.h2d", parts):
                     batch = jax.device_put(hb)
         if self.failure_injector is not None:

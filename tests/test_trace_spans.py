@@ -9,7 +9,7 @@ from repro.data.prefetch import SubgraphPipeline
 from repro.graph import ClusterSampler
 
 STEP_FIELDS = ("fetch_s", "wait_s", "h2d_s", "dispatch_s", "sync_s",
-               "staged", "build_s")
+               "staged", "build_s", "edge_fill")
 
 
 def _sampler(graph, parts):
@@ -74,8 +74,10 @@ def test_recycled_step_fetches_nothing(small_graph, small_parts):
         next(pipe)
         second = dict(pipe.last_fetch)
     assert first["build_s"] > 0.0 and first["h2d_s"] > 0.0
+    # the recycled step consumes the same batch, so its fill is the same
+    assert 0.0 < first["edge_fill"] <= 1.0
     assert second == {"wait_s": 0.0, "h2d_s": 0.0, "staged": False,
-                      "build_s": 0.0}
+                      "build_s": 0.0, "edge_fill": first["edge_fill"]}
 
 
 @pytest.mark.parametrize("prefetch", [None, 0, 2],
@@ -101,3 +103,27 @@ def test_record_parts_sum_within_the_step(small_graph, small_parts,
             assert 0.0 < rec["build_s"] <= rec["fetch_s"]
             assert rec["h2d_s"] > 0.0
             assert rec["staged"] is False and rec["wait_s"] == 0.0
+
+
+@pytest.mark.parametrize("prefetch", [None, 0, 2],
+                         ids=["legacy", "sync", "prefetch"])
+def test_record_carries_the_consumed_batch_edge_fill(small_graph, small_parts,
+                                                     prefetch):
+    tr = _trainer(small_graph, small_parts, prefetch=prefetch)
+    try:
+        tr.run(3)
+    finally:
+        tr.close()
+    # the batches the trainer consumed, rebuilt from a twin of its sampler
+    twin = _sampler(small_graph, small_parts)
+    if prefetch is None:
+        consumed = [twin.sample() for _ in range(3)]
+    else:
+        consumed = [twin.build_batch(twin.clusters_at(i,
+                                                      mode=tr.pipeline_mode))
+                    for i in range(3)]
+    recs = [h for h in tr.history if "loss" in h]
+    assert len(recs) == 3
+    for rec, sg in zip(recs, consumed):
+        assert 0.0 < rec["edge_fill"] <= 1.0
+        assert rec["edge_fill"] == sg.n_edges_real / twin.pad_edges
