@@ -9,7 +9,7 @@
 from repro.core.history import HistoricalState, init_history
 from repro.core.methods import (MBMethod, METHODS, LMC, GAS, CLUSTER, CF_ONLY,
                                 CB_ONLY, TI, RHO_BUDGET_DEFAULT)
-from repro.core.lmc import (Batch, host_batch, make_infer_step,
+from repro.core.lmc import (Batch, batch_fills, host_batch, make_infer_step,
                             make_train_step, to_device_batch)
 from repro.core.exact import (FullGraphData, from_graph, full_loss, full_grads,
                               accuracy, exact_layer_values, backward_sgd_grads)
@@ -17,7 +17,7 @@ from repro.core.exact import (FullGraphData, from_graph, full_loss, full_grads,
 __all__ = [
     "HistoricalState", "init_history", "MBMethod", "METHODS",
     "LMC", "GAS", "CLUSTER", "CF_ONLY", "CB_ONLY", "TI", "RHO_BUDGET_DEFAULT",
-    "Batch", "host_batch", "make_infer_step", "make_train_step",
+    "Batch", "batch_fills", "host_batch", "make_infer_step", "make_train_step",
     "to_device_batch",
     "FullGraphData", "from_graph", "full_loss", "full_grads", "accuracy",
     "exact_layer_values", "backward_sgd_grads",

@@ -61,7 +61,8 @@ class Batch(NamedTuple):
 
 
 def host_batch(sg: PaddedSubgraph, *, backend: str = "segment",
-               ell_buckets=(8, 32, 128)) -> Batch:
+               ell_buckets=(8, 32, 128),
+               record: Optional[dict] = None) -> Batch:
     """Build a Batch of *host* (numpy) arrays, including the re-bucketed ELL
     adjacency for ``backend="ell"|"ti"`` — everything except the device
     transfer.
@@ -72,13 +73,16 @@ def host_batch(sg: PaddedSubgraph, *, backend: str = "segment",
     (DESIGN.md §9). ``to_device_batch`` composes the two for the synchronous
     path. ``backend="ti"`` additionally rides the subgraph's α scales along
     (the halo-compensation transform — no store state needed at step time).
+    The ELL build runs under the host span ``pipeline.ell``, whose seconds
+    go to ``record["ell_s"]`` when a record is given.
     """
     assert backend in AGG_BACKENDS, backend
     ell = None
     ti_scale = None
     if backend in ("ell", "ti"):
-        ell = ell_from_coo(sg.edge_src, sg.edge_dst, sg.edge_w, sg.n_ext,
-                           buckets=ell_buckets, as_jax=False)
+        with tracing.span("pipeline.ell", record):
+            ell = ell_from_coo(sg.edge_src, sg.edge_dst, sg.edge_w, sg.n_ext,
+                               buckets=ell_buckets, as_jax=False)
     if backend == "ti":
         if sg.ti_scale is None:
             raise ValueError(
@@ -94,6 +98,16 @@ def host_batch(sg: PaddedSubgraph, *, backend: str = "segment",
         labeled_mask=np.asarray(sg.labeled_mask), beta=np.asarray(sg.beta),
         loss_scale=np.asarray(sg.loss_scale), grad_scale=np.asarray(sg.grad_scale),
         ell=ell, ti_scale=ti_scale)
+
+
+def batch_fills(sg: PaddedSubgraph, batch: Batch) -> dict:
+    """How full a batch's padded layouts are: ``edge_fill``, the real edges
+    over the padded edge count, and ``ell_fill``, the real (row, neighbour)
+    pairs over the slots of its forward ELL (0.0 without an ELL)."""
+    slots = (0 if batch.ell is None else
+             sum(idx.size for idx in batch.ell.bucket_idx))
+    return {"edge_fill": sg.edge_fill,
+            "ell_fill": sg.n_edges_real / slots if slots else 0.0}
 
 
 def to_device_batch(sg: PaddedSubgraph, *, backend: str = "segment",

@@ -20,8 +20,10 @@ Two layers live here (DESIGN.md §9):
 The pipeline's host work runs under the spans of ``repro.tracing``:
 ``pipeline.build`` on the building thread, ``pipeline.wait`` where the
 consumer takes its next batch (blocking on the queue unless one is staged),
-``pipeline.h2d`` around every ``jax.device_put``. :attr:`SubgraphPipeline.last_fetch` holds their seconds
-for the step last yielded, and the yielded batch's edge fill.
+``pipeline.h2d`` around every ``jax.device_put``, and ``pipeline.ell``
+around the ELL build inside ``pipeline.build``.
+:attr:`SubgraphPipeline.last_fetch` holds their seconds for the step last
+yielded, and the yielded batch's edge and ELL fills.
 """
 from __future__ import annotations
 
@@ -35,7 +37,10 @@ from typing import Callable, Iterator, Optional
 from repro.tracing import span
 
 # SubgraphPipeline.last_fetch of a step that fetched nothing
-NO_FETCH = {"wait_s": 0.0, "h2d_s": 0.0, "staged": False, "build_s": 0.0}
+NO_FETCH = {"wait_s": 0.0, "h2d_s": 0.0, "staged": False, "build_s": 0.0,
+            "ell_s": 0.0}
+# the fields of last_fetch that describe the yielded batch (core.batch_fills)
+FILLS = ("edge_fill", "ell_fill")
 
 
 class _Done:
@@ -220,10 +225,12 @@ class SubgraphPipeline:
     (taking the batch: blocked on the queue, or ~0 when staged), ``h2d_s`` (in ``jax.device_put``, the staged
     next batch's included), ``staged`` (the batch came from the device-side
     double buffer), ``build_s`` (the seconds that built the yielded
-    slot, on its worker) and ``edge_fill`` (the yielded batch's real edges
-    over its padded edge count). A recycled step fetches nothing: its
-    seconds are 0, ``staged`` is False, and ``edge_fill`` is the recycled
-    batch's.
+    slot, on its worker), ``ell_s`` (the part of ``build_s`` that built its
+    ELL; 0 without one), ``edge_fill`` (the yielded batch's real edges over
+    its padded edge count) and ``ell_fill`` (its real (row, neighbour)
+    pairs over its ELL slots; 0 without an ELL). A recycled step fetches
+    nothing: its seconds are 0, ``staged`` is False, and the fills are the
+    recycled batch's.
     """
 
     def __init__(self, sampler, *, backend: str = "segment", depth: int = 2,
@@ -279,7 +286,7 @@ class SubgraphPipeline:
         self._end_step = None if num_steps is None else self._step + int(num_steps)
         self._cur_slot = -1
         self._cur_batch = None
-        self._cur_fill = 0.0
+        self._cur_fill = dict.fromkeys(FILLS, 0.0)
         self._staged = None          # (device batch, build record), next slot
         self.last_fetch = dict(NO_FETCH)
         self._closed = False
@@ -296,23 +303,25 @@ class SubgraphPipeline:
                                   depth=self.depth)
 
     # ------------------------------------------------------------- producer
-    def _build_host(self, slot: int):
-        """Worker-side: schedule slot -> (host (numpy) Batch, its edge
-        fill). Pure numpy."""
-        from repro.core.lmc import host_batch
+    def _build_host(self, slot: int, record: dict):
+        """Worker-side: schedule slot -> host (numpy) Batch; adds the ELL
+        build's seconds and the batch's fills to ``record``. Pure numpy."""
+        from repro.core.lmc import batch_fills, host_batch
         if self.build_hook is not None:
             self.build_hook(slot)
         cids = self.sampler.clusters_at(slot, mode=self.mode)
         sg = self.sampler.build_batch(cids)
-        return host_batch(sg, backend=self.backend,
-                          ell_buckets=self.ell_buckets), sg.edge_fill
+        hb = host_batch(sg, backend=self.backend,
+                        ell_buckets=self.ell_buckets, record=record)
+        record.update(batch_fills(sg, hb))
+        return hb
 
     def _timed_build(self, slot: int):
         """``_build_host`` under the ``pipeline.build`` span; returns
-        (host Batch, {"build_s": seconds it took, "edge_fill": its fill})."""
+        (host Batch, {"build_s", "ell_s": seconds, and its fills})."""
         took = {}
         with span("pipeline.build", took):
-            hb, took["edge_fill"] = self._build_host(slot)
+            hb = self._build_host(slot, took)
         return hb, took
 
     def _built_stream(self, first_slot: int, end_slot: Optional[int]):
@@ -391,8 +400,8 @@ class SubgraphPipeline:
         if slot != self._cur_slot:
             self._cur_batch = self._fetch_next_slot()
             self._cur_slot = slot
-            self._cur_fill = self.last_fetch["edge_fill"]
-        self.last_fetch["edge_fill"] = self._cur_fill
+            self._cur_fill = {k: self.last_fetch[k] for k in FILLS}
+        self.last_fetch.update(self._cur_fill)
         self._step += 1
         return self._cur_batch
 

@@ -11,8 +11,12 @@ as one broadcast multiply-add over the whole tile (β and mask arrive as
 (N, 1) lane-broadcast columns). The gather itself has two strategies:
 
   * ``stream=True`` (default): the store stays in **HBM** (``pl.ANY``) and
-    each grid step's (block_rows, block_d) gather arrives via per-row
-    HBM→VMEM ``pltpu.make_async_copy`` into a 2-slot VMEM scratch. The
+    each grid step's (block_rows, 128) gather arrives via per-row
+    HBM→VMEM ``pltpu.make_async_copy`` into a 2-slot VMEM scratch. A copy
+    reads one row's 128 lanes through ``ell_spmm.hbm_tiles``, the store's
+    own HBM tile layout, which Mosaic accepts at every D that is a multiple
+    of 128 (a (1, 128) window of a wider (M, D) row it refuses), so a
+    D-wide store takes D/128 lane blocks of the grid. The
     pipeline runs across grid steps: step t's compute overlaps step t+1's
     DMA (slot t % 2 computes while slot (t+1) % 2 fills). The store is
     *full-graph* on the LMC train path, so this is the path that makes
@@ -36,7 +40,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.ell_spmm import default_interpret, default_stream
+from repro.kernels.ell_spmm import (LANES, default_interpret, default_stream,
+                                    hbm_tiles, tile_row)
 
 # XLA tiles a 1-D int32 array in 1024-word chunks, and Mosaic accepts a 1-D
 # block only if it matches: an SMEM id block is a whole number of chunks
@@ -87,16 +92,16 @@ def _comp_resident_kernel(gid_ref, beta_ref, mask_ref, fresh_ref, store_ref,
 
 def _comp_stream_kernel(gid_ref, gid_next_ref, beta_ref, mask_ref, fresh_ref,
                         store_ref, o_ref, gath_ref, sem_ref, *,
-                        block_rows: int, block_d: int, grid_j: int,
-                        gid_block: int):
+                        block_rows: int, grid_j: int, gid_block: int):
     """Streaming body, pipelined across row/feature tiles.
 
-    store_ref lives in HBM (``pl.ANY``); gath_ref is a (2, bn, bd) VMEM
-    double buffer; sem_ref a (2,) DMA-semaphore array. Grid steps run
-    sequentially on a TPU core and scratch persists across them, so tile
-    t = i·J + j computes out of slot t % 2 while tile t+1's row copies fill
-    slot (t+1) % 2 — the gather DMA for the next tile overlaps this tile's
-    lerp. Tile 0 pays the only un-overlapped gather (warm-up). gid_ref holds
+    store_ref is the store's HBM tile view (``hbm_tiles``, ``pl.ANY``), so
+    each row copy is one 128-lane window of one tile at any D; gath_ref is
+    a (2, bn, 128) VMEM double buffer; sem_ref a (2,) DMA-semaphore array.
+    Grid steps run sequentially on a TPU core and scratch persists across
+    them, so tile t = i·J + j computes out of slot t % 2 while tile t+1's
+    row copies fill slot (t+1) % 2 — the gather DMA for the next tile
+    overlaps this tile's lerp. Tile 0 pays the only un-overlapped gather (warm-up). gid_ref holds
     row tile i's ids in SMEM and gid_next_ref row tile i+1's, which tile t+1
     reads when it starts a new row tile (``_smem_id_blocks``).
     """
@@ -108,12 +113,9 @@ def _comp_stream_kernel(gid_ref, gid_next_ref, beta_ref, mask_ref, fresh_ref,
 
     def tile(col_tile, slot, op, ids_ref, ids_base):
         """start()/wait() the bn row-copies of one grid tile into slot."""
-        col0 = col_tile * block_d
-
         def row(r, _):
-            g = ids_ref[ids_base + r]
             op(pltpu.make_async_copy(
-                store_ref.at[pl.ds(g, 1), pl.ds(col0, block_d)],
+                tile_row(store_ref, ids_ref[ids_base + r], col_tile),
                 gath_ref.at[slot, pl.ds(r, 1), :],
                 sem_ref.at[slot]))
             return 0
@@ -152,7 +154,8 @@ def lmc_compensate_kernel(store: jax.Array, gids: jax.Array, beta: jax.Array,
     """store (M, D); gids/beta/mask (N,); fresh (N, D) -> (N, D).
 
     Requires N % block_rows == 0 and D % block_d == 0 (``ops.lmc_compensate``
-    pads and adds the custom VJP). ``stream=None`` autodetects to the
+    pads and adds the custom VJP); streamed, block_d is 128 and D any
+    multiple of it. ``stream=None`` autodetects to the
     HBM→VMEM DMA gather — no VMEM bound on the store row count M;
     ``stream=False`` forces the legacy resident store block (small M only).
     """
@@ -174,12 +177,13 @@ def lmc_compensate_kernel(store: jax.Array, gids: jax.Array, beta: jax.Array,
         pl.BlockSpec((block_rows, block_d), lambda i, j: (i, j)),
     ]
     if stream:
+        # one row copy is one 128-lane window of one HBM tile
+        assert block_d == LANES, block_d
         kernel = functools.partial(_comp_stream_kernel, block_rows=block_rows,
-                                   block_d=block_d, grid_j=grid[1],
-                                   gid_block=gid_block)
+                                   grid_j=grid[1], gid_block=gid_block)
         in_specs = [gid_spec(), gid_spec(1), *tile_specs,
                     pl.BlockSpec(memory_space=pl.ANY)]  # store stays in HBM
-        operands = (gids, gids, beta2, mask2, fresh, store)
+        operands = (gids, gids, beta2, mask2, fresh, hbm_tiles(store))
         # DMA is byte-exact: the double buffer must carry the store dtype
         scratch = [pltpu.VMEM((2, block_rows, block_d), store.dtype),
                    pltpu.SemaphoreType.DMA((2,))]
@@ -187,8 +191,9 @@ def lmc_compensate_kernel(store: jax.Array, gids: jax.Array, beta: jax.Array,
         kernel = functools.partial(_comp_resident_kernel,
                                    block_rows=block_rows, gid_block=gid_block)
         # lint: ok(R003) legacy resident path: stream=True is the default and
-        # Mosaic rejects >12 MiB blocks at compile time; kept for small
-        # stores + streamed-vs-resident benchmarking (module docstring)
+        # Mosaic rejects >12 MiB blocks at compile time; the block is one
+        # (M, block_d) lane block at any D, kept for small stores +
+        # streamed-vs-resident benchmarking (module docstring)
         store_spec = pl.BlockSpec((m, block_d), lambda i, j: (0, j))
         in_specs = [gid_spec(), *tile_specs, store_spec]
         operands = (gids, beta2, mask2, fresh, store)

@@ -14,12 +14,17 @@ accumulator, full VPU lanes):
   * ``stream=True`` (default): the feature operand stays in **HBM**
     (``pl.ANY`` memory space) and the kernel body issues per-row
     HBM→VMEM ``pltpu.make_async_copy`` gathers, driven by the row tile's
-    SMEM indices, into a 2-slot ``(block_rows, block_d)`` VMEM scratch.
+    SMEM indices, into a 2-slot ``(block_rows, 128)`` VMEM scratch.
     Neighbor plane k+1's copies start before plane k's wait, so the DMA for
     k+1 overlaps the multiply-add for k (double buffering). No VMEM bound on
     the gather source M — this is what lets the compiled path gather from
     full-graph stores (the old resident block capped M at ~24k f32
-    rows/device).
+    rows/device). Each copy moves one row's 128 lanes of the grid step's
+    lane block, read through ``hbm_tiles``, the source's own HBM tile
+    layout seen as (M/8, D/128, 8, 128): Mosaic refuses a (1, 128) window
+    of a wider (M, D) row, and accepts the (1, 128) slice of one tile at
+    every D that is a multiple of 128. So ``block_d`` is 128 here, and a
+    D-wide layer takes D/128 copies per (row, neighbor) pair.
   * ``stream=False``: the legacy resident block — the whole ``(M, block_d)``
     feature slab rides in as one VMEM block and rows are copied out of it with
     dynamic slices. Cheaper for small sources revisited by many rows (one
@@ -31,15 +36,17 @@ accumulator, full VPU lanes):
 interpreter fallback elsewhere (CPU containers cannot lower Mosaic kernels);
 streaming everywhere (the interpreter emulates the DMA/semaphore protocol
 exactly, so CPU CI verifies the streamed path — including at M well past the
-old cap). All tile dims are multiples of (8, 128) for VREG/MXU layout.
+old cap). Every VMEM block and scratch is (block_rows, 128) or
+(block_rows, K) at any D: a D-wide operand is D/128 lane blocks of the grid,
+never a wider tile.
 
-Memory per grid step (defaults, streamed): 2-slot gather scratch
+Memory per grid step (defaults, streamed), at any D: 2-slot gather scratch
 (2, 256, 128) f32 = 256 KiB, w tile (256, K≤128) = 128 KiB, out tile + f32
 accumulator (256, 128) ×2 = 256 KiB of VMEM, and the row tile's
 (block_rows, K) indices in SMEM, whose minor dim pads to 128 words: 128 KiB
-per buffer. None of it grows with N or M: the index array reaches SMEM one
-row tile at a time, where a whole (N, K) operand would overflow v5e's 1 MiB
-of SMEM near N = 2k rows.
+per buffer. None of it grows with N, M or D: the index array reaches SMEM
+one row tile at a time (the D/128 lane blocks of a row tile reuse it), where
+a whole (N, K) operand would overflow v5e's 1 MiB of SMEM near N = 2k rows.
 
 Mosaic cannot slice a lane at a dynamic offset, so weight column k of the
 (block_rows, K) tile is read as a masked lane reduction (``_weight_column``)
@@ -54,6 +61,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+
+LANES = 128   # a vreg's lanes: the width of one streamed row copy
 
 
 def default_interpret() -> bool:
@@ -72,6 +82,32 @@ def default_stream() -> bool:
     benchmarking.
     """
     return True
+
+
+def hbm_tiles(h: jax.Array) -> jax.Array:
+    """View a streamed gather source (M, D) as its own HBM tiles.
+
+    XLA lays a 2-D TPU array out in (t, 128) tiles, t = 8 rows for 32-bit
+    dtypes, row-major over tiles. Mosaic copies a (1, 128) row window out of
+    an (M, D) operand only while D == 128; at D = 256 it refuses the DMA
+    ("Slice shape along dimension 0 must be aligned to tiling (8)"), though
+    the window is one contiguous run of a tile. The view (M/t, D/128, t,
+    128) names the same bytes so that every row window is the (1, 128) slice
+    of a 128-lane tile, which Mosaic accepts at any D; XLA lowers it to a
+    bitcast. Only an M that is not a multiple of t pays a copy (the row pad).
+    """
+    m, d = h.shape
+    t = 8 * 4 // h.dtype.itemsize
+    if m % t:
+        h = jnp.pad(h, ((0, t - m % t), (0, 0)))
+    return h.reshape(-1, t, d // LANES, LANES).transpose(0, 2, 1, 3)
+
+
+def tile_row(tiles_ref, j, lane):
+    """The (1, 128) window of row j, lane block ``lane``, of an
+    ``hbm_tiles`` view: the source of one row copy."""
+    t = tiles_ref.shape[2]
+    return tiles_ref.at[j // t, lane, pl.ds(j % t, 1), :]
 
 
 def _weight_column(w_ref, k) -> jax.Array:
@@ -108,24 +144,23 @@ def _spmm_resident_kernel(idx_ref, w_ref, h_ref, o_ref, gath_ref, acc_ref, *,
 
 
 def _spmm_stream_kernel(idx_ref, w_ref, h_ref, o_ref, gath_ref, acc_ref,
-                        sem_ref, *, K: int, block_rows: int, block_d: int):
+                        sem_ref, *, K: int, block_rows: int):
     """Streaming body: per-row HBM→VMEM DMA gathers, double-buffered over k.
 
-    h_ref lives in HBM (``pl.ANY``); gath_ref is a (2, bn, bd) VMEM
-    double buffer; sem_ref a (2,) DMA-semaphore array, one per slot. Neighbor
-    plane k lands in slot k % 2: its copies are started one plane ahead
-    (while plane k-1's multiply-add runs) and waited right before use. Every
-    started copy is waited in the same grid step, so no DMA crosses grid-step
-    boundaries.
+    h_ref is the feature operand's HBM tile view (``hbm_tiles``, memory
+    space ``pl.ANY``); gath_ref is a (2, bn, 128) VMEM double buffer;
+    sem_ref a (2,) DMA-semaphore array, one per slot. Neighbor plane k lands
+    in slot k % 2: its copies are started one plane ahead (while plane k-1's
+    multiply-add runs) and waited right before use. Every started copy is
+    waited in the same grid step, so no DMA crosses grid-step boundaries.
     """
-    col0 = pl.program_id(1) * block_d
+    lane = pl.program_id(1)
 
     def plane(k, slot, op):
         """start()/wait() the bn row-copies of neighbor plane k into slot."""
         def row(r, _):
-            j = idx_ref[r, k]
             op(pltpu.make_async_copy(
-                h_ref.at[pl.ds(j, 1), pl.ds(col0, block_d)],
+                tile_row(h_ref, idx_ref[r, k], lane),
                 gath_ref.at[slot, pl.ds(r, 1), :],
                 sem_ref.at[slot]))
             return 0
@@ -160,7 +195,8 @@ def ell_spmm(nbr_idx: jax.Array, nbr_w: jax.Array, h: jax.Array, *,
     """out[i] = Σ_k w[i,k] · h[idx[i,k]]  via pl.pallas_call.
 
     nbr_idx/nbr_w: (N, K); h: (M, D). N must divide by block_rows and D by
-    block_d (the ops.py wrapper pads). ``interpret=None`` autodetects:
+    block_d (the ops.py wrapper pads); streamed, block_d is 128 and D any
+    multiple of it. ``interpret=None`` autodetects:
     compiled on TPU, interpreted elsewhere. ``stream=None`` autodetects to
     the HBM→VMEM DMA gather (no VMEM bound on M); ``stream=False`` forces the
     legacy resident ``(M, block_d)`` VMEM block (small sources only).
@@ -174,9 +210,12 @@ def ell_spmm(nbr_idx: jax.Array, nbr_w: jax.Array, h: jax.Array, *,
     assert n % block_rows == 0 and d % block_d == 0, (n, d)
     grid = (n // block_rows, d // block_d)
     if stream:
+        # one row copy is one 128-lane window of one HBM tile
+        assert block_d == LANES, block_d
         kernel = functools.partial(_spmm_stream_kernel, K=k,
-                                   block_rows=block_rows, block_d=block_d)
+                                   block_rows=block_rows)
         h_spec = pl.BlockSpec(memory_space=pl.ANY)  # stays in HBM
+        h = hbm_tiles(h)
         scratch = [pltpu.VMEM((2, block_rows, block_d), h.dtype),
                    pltpu.VMEM((block_rows, block_d), jnp.float32),
                    pltpu.SemaphoreType.DMA((2,))]
@@ -184,8 +223,9 @@ def ell_spmm(nbr_idx: jax.Array, nbr_w: jax.Array, h: jax.Array, *,
         kernel = functools.partial(_spmm_resident_kernel, K=k,
                                    block_rows=block_rows)
         # lint: ok(R003) legacy resident path: stream=True is the default and
-        # Mosaic rejects >12 MiB blocks at compile time; kept for small
-        # sources + streamed-vs-resident benchmarking (module docstring)
+        # Mosaic rejects >12 MiB blocks at compile time; the block is one
+        # (M, block_d) lane block at any D, kept for small sources +
+        # streamed-vs-resident benchmarking (module docstring)
         h_spec = pl.BlockSpec((m, block_d), lambda i, j: (0, j))
         scratch = [pltpu.VMEM((block_rows, block_d), h.dtype),
                    pltpu.VMEM((block_rows, block_d), jnp.float32)]
@@ -199,7 +239,7 @@ def ell_spmm(nbr_idx: jax.Array, nbr_w: jax.Array, h: jax.Array, *,
                          memory_space=pltpu.SMEM),
             # lint: ok(R003) K <= 128 by bucket construction: build_ell caps
             # bucket widths at powers of two <= 128, so this w tile is at
-            # most (256, 128) f32 = 128 KiB
+            # most (256, 128) f32 = 128 KiB, whatever D is
             pl.BlockSpec((block_rows, k), lambda i, j: (i, 0)),
             h_spec,
         ],

@@ -53,7 +53,8 @@ import numpy as np
 
 from repro.checkpoint import CheckpointError, CheckpointManager
 from repro.core import (HistoricalState, MBMethod, from_graph, accuracy,
-                        host_batch, init_history, make_train_step)
+                        batch_fills, host_batch, init_history,
+                        make_train_step)
 from repro.data.prefetch import NO_FETCH, SubgraphPipeline
 from repro.graph import ClusterSampler
 from repro.models.gnn import GNN
@@ -368,8 +369,9 @@ class GNNTrainer:
         host spans (``fetch_s`` holding ``wait_s`` and ``h2d_s``,
         ``dispatch_s``, ``sync_s``) and tells whether the batch was
         ``staged`` on the device ahead of time, what it took to build
-        (``build_s``) and how full its padded edge list is (``edge_fill``),
-        as ``SubgraphPipeline.last_fetch`` defines them."""
+        (``build_s``, its ELL part ``ell_s``) and how full its padded edge
+        list and ELL are (``edge_fill``, ``ell_fill``), as
+        ``SubgraphPipeline.last_fetch`` defines them."""
         t0 = time.perf_counter()
         parts = dict(NO_FETCH)
         with span("train.fetch", parts):
@@ -379,8 +381,8 @@ class GNNTrainer:
             else:
                 with span("pipeline.build", parts):
                     sg = self.sampler.sample()
-                    hb = host_batch(sg, backend=self.backend)
-                parts["edge_fill"] = sg.edge_fill
+                    hb = host_batch(sg, backend=self.backend, record=parts)
+                parts.update(batch_fills(sg, hb))
                 with span("pipeline.h2d", parts):
                     batch = jax.device_put(hb)
         if self.failure_injector is not None:

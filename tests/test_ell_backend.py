@@ -31,11 +31,13 @@ def tiny_parts(tiny_graph):
     return rng.integers(0, 4, tiny_graph.num_nodes).astype(np.int32)
 
 
+@pytest.mark.parametrize("hidden", [16, 256], ids=lambda d: f"hidden{d}")
 @pytest.mark.parametrize("method", [LMC, GAS], ids=lambda m: m.name)
-def test_ell_step_matches_segment(method, tiny_graph, tiny_parts):
+def test_ell_step_matches_segment(method, hidden, tiny_graph, tiny_parts):
+    """At hidden 256 every kernel runs over two 128-lane blocks."""
     g = tiny_graph
     data = from_graph(g)
-    gnn = make_gnn("gcn", g.feature_dim, 16, g.num_classes, 2)
+    gnn = make_gnn("gcn", g.feature_dim, hidden, g.num_classes, 2)
     params = gnn.init_params(jax.random.key(0))
     s = ClusterSampler(g, 4, 1, parts=tiny_parts, seed=0,
                        include_halo=method.include_halo,
@@ -43,7 +45,7 @@ def test_ell_step_matches_segment(method, tiny_graph, tiny_parts):
     step_seg = jax.jit(make_train_step(gnn, method, g.num_nodes))
     step_ell = jax.jit(make_train_step(gnn, method, g.num_nodes,
                                        backend="ell"))
-    st_seg = st_ell = init_history(2, g.num_nodes, 16)
+    st_seg = st_ell = init_history(2, g.num_nodes, hidden)
     for _ in range(2):   # chained steps: store updates feed the next batch
         sg = s.sample()
         l1, g1, st_seg, _ = step_seg(params, st_seg, to_device_batch(sg),

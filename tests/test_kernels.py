@@ -29,13 +29,19 @@ def _random_csr(seed, n_max=60, heavy=True):
     return indptr, indices, weights
 
 
+# the streamed kernels at one lane block and at the published widths: every
+# row copy reads a 128-lane window of the source's HBM tiles (hbm_tiles)
+WIDTHS = [128, 256, 384]
+
+
+@pytest.mark.parametrize("d", WIDTHS)
 @given(n_tiles=st.integers(1, 2), k=st.sampled_from([4, 8, 32]),
-       d_tiles=st.integers(1, 2), m=st.sampled_from([64, 300, 1000]),
+       m=st.sampled_from([64, 300, 1000]),
        dtype=st.sampled_from([np.float32]), seed=st.integers(0, 100))
 @settings(max_examples=16)
-def test_ell_spmm_matches_ref(n_tiles, k, d_tiles, m, dtype, seed):
+def test_ell_spmm_matches_ref(d, n_tiles, k, m, dtype, seed):
     rng = np.random.default_rng(seed)
-    n, d = 256 * n_tiles, 128 * d_tiles
+    n = 256 * n_tiles
     idx = rng.integers(0, m, (n, k)).astype(np.int32)
     w = (rng.random((n, k)) * (rng.random((n, k)) > 0.3)).astype(dtype)
     h = rng.normal(size=(m, d)).astype(dtype)
@@ -58,11 +64,12 @@ def test_ell_spmm_bf16():
                                rtol=5e-2, atol=5e-2)
 
 
+@pytest.mark.parametrize("d", WIDTHS)
 @given(seed=st.integers(0, 50), beta_max=st.floats(0.0, 1.0))
 @settings(max_examples=10)
-def test_lmc_compensate_matches_ref(seed, beta_max):
+def test_lmc_compensate_matches_ref(d, seed, beta_max):
     rng = np.random.default_rng(seed)
-    n, m, d = 256, 500, 128
+    n, m = 256, 500
     store = rng.normal(size=(m, d)).astype(np.float32)
     gids = rng.integers(0, m, n).astype(np.int32)
     beta = (rng.random(n) * beta_max).astype(np.float32)

@@ -1,7 +1,8 @@
 """The LMC step's device scopes, read from the compiled HLO's op_name paths.
 
 The step of a tiny GCNII is compiled on the ``segment`` and ``ell``
-(interpreted Pallas) backends. Every op carries at most one of the four
+(interpreted Pallas) backends, and a GCN at the published width 256 on
+``ell``. Every op carries at most one of the four
 scopes of ``repro.tracing``, all four are present, transposed aggregation is
 told by its path, every scatter and dot carries a scope, and the number of
 aggregations the compiled step holds is pinned: one forward and one
@@ -103,3 +104,36 @@ def test_aggregations_per_layer(compiled):
     transposed = [on for on in aggs if TRANSPOSED_AGG in on]
     assert len(aggs) - len(transposed) == LAYERS * per_call
     assert len(transposed) == LAYERS * per_call
+
+
+@pytest.fixture(scope="module")
+def compiled_gcn_256(small_graph, small_parts):
+    """The ``ell`` step of a GCN at 256 lanes (two lane blocks a kernel)."""
+    g = small_graph
+    sampler = ClusterSampler(g, 16, 2, parts=small_parts, seed=1)
+    gnn = make_gnn("gcn", g.feature_dim, 256, g.num_classes, LAYERS)
+    step = jax.jit(make_train_step(gnn, LMC, g.num_nodes, backend="ell"))
+    data = from_graph(g)
+    lowered = step.lower(gnn.init_params(jax.random.key(0)),
+                         init_history(LAYERS, g.num_nodes, 256),
+                         to_device_batch(sampler.sample(), backend="ell"),
+                         data.x, data.self_w)
+    return [on for comp, op, on in _instructions(lowered.compile().as_text())
+            if comp == "ENTRY" and op == "while" and on]
+
+
+def test_kernels_carry_their_scopes_at_hidden_256(compiled_gcn_256):
+    """Each interpreted kernel loop carries the scope its readers read: the
+    forward SpMM ``lmc.agg``, the custom VJP's transposed SpMM
+    ``transpose(jvp(lmc.agg``, the compensation kernel ``lmc.halo``. A GCN
+    needs no input adjoint at layer 0: one transposed SpMM a layer above."""
+    spmm = [on for on in compiled_gcn_256 if "ell_spmm" in on]
+    transposed = [on for on in spmm if "transpose(" in on]
+    forward = [on for on in spmm if "transpose(" not in on]
+    assert len(forward) == LAYERS * ELL_BUCKETS
+    assert all(tracing.AGG in on and TRANSPOSED_AGG not in on
+               for on in forward)
+    assert len(transposed) == (LAYERS - 1) * ELL_BUCKETS
+    assert all(TRANSPOSED_AGG in on for on in transposed)
+    compensate = [on for on in compiled_gcn_256 if "lmc_compensate" in on]
+    assert compensate and all(tracing.HALO in on for on in compensate)

@@ -9,7 +9,7 @@ from repro.data.prefetch import SubgraphPipeline
 from repro.graph import ClusterSampler
 
 STEP_FIELDS = ("fetch_s", "wait_s", "h2d_s", "dispatch_s", "sync_s",
-               "staged", "build_s", "edge_fill")
+               "staged", "build_s", "edge_fill", "ell_s", "ell_fill")
 
 
 def _sampler(graph, parts):
@@ -74,10 +74,13 @@ def test_recycled_step_fetches_nothing(small_graph, small_parts):
         next(pipe)
         second = dict(pipe.last_fetch)
     assert first["build_s"] > 0.0 and first["h2d_s"] > 0.0
-    # the recycled step consumes the same batch, so its fill is the same
+    # the recycled step consumes the same batch, so its fills are the same
     assert 0.0 < first["edge_fill"] <= 1.0
+    assert first["ell_s"] == 0.0 and first["ell_fill"] == 0.0  # no ELL
     assert second == {"wait_s": 0.0, "h2d_s": 0.0, "staged": False,
-                      "build_s": 0.0, "edge_fill": first["edge_fill"]}
+                      "build_s": 0.0, "ell_s": 0.0,
+                      "edge_fill": first["edge_fill"],
+                      "ell_fill": first["ell_fill"]}
 
 
 @pytest.mark.parametrize("prefetch", [None, 0, 2],
@@ -127,3 +130,33 @@ def test_record_carries_the_consumed_batch_edge_fill(small_graph, small_parts,
     for rec, sg in zip(recs, consumed):
         assert 0.0 < rec["edge_fill"] <= 1.0
         assert rec["edge_fill"] == sg.n_edges_real / twin.pad_edges
+
+
+@pytest.mark.parametrize("prefetch", [None, 0, 2],
+                         ids=["legacy", "sync", "prefetch"])
+def test_record_carries_the_consumed_batch_ell_fill(small_graph, small_parts,
+                                                    prefetch):
+    """On the ``ell`` backend the record holds the ELL build's seconds
+    (``pipeline.ell``, inside ``pipeline.build``) and the consumed batch's
+    real (row, neighbour) pairs over its ELL slots."""
+    from repro.core import host_batch
+    tr = _trainer(small_graph, small_parts, prefetch=prefetch, backend="ell")
+    try:
+        tr.run(2)
+    finally:
+        tr.close()
+    twin = _sampler(small_graph, small_parts)
+    if prefetch is None:
+        consumed = [twin.sample() for _ in range(2)]
+    else:
+        consumed = [twin.build_batch(twin.clusters_at(i,
+                                                      mode=tr.pipeline_mode))
+                    for i in range(2)]
+    recs = [h for h in tr.history if "loss" in h]
+    assert len(recs) == 2
+    for rec, sg in zip(recs, consumed):
+        slots = sum(idx.size for idx in
+                    host_batch(sg, backend="ell").ell.bucket_idx)
+        assert rec["ell_fill"] == sg.n_edges_real / slots
+        assert 0.0 < rec["ell_fill"] < rec["edge_fill"] <= 1.0
+        assert 0.0 < rec["ell_s"] <= rec["build_s"]
