@@ -75,14 +75,23 @@ def host_batch(sg: PaddedSubgraph, *, backend: str = "segment",
     (the halo-compensation transform — no store state needed at step time).
     The ELL build runs under the host span ``pipeline.ell``, whose seconds
     go to ``record["ell_s"]`` when a record is given.
+
+    The ELL holds the subgraph's real edges only (``build_subgraph`` puts
+    them first; the padded tail has w == 0 and adds nothing). Its bucket
+    shapes follow from the padded sizes and the graph's degree histograms
+    the subgraph carries (``kernels.ops.fixed_row_capacity``), so they are
+    fixed per sampler; a subgraph without histograms gets the worst case.
     """
     assert backend in AGG_BACKENDS, backend
     ell = None
     ti_scale = None
     if backend in ("ell", "ti"):
+        ne = sg.n_edges_real
         with tracing.span("pipeline.ell", record):
-            ell = ell_from_coo(sg.edge_src, sg.edge_dst, sg.edge_w, sg.n_ext,
-                               buckets=ell_buckets, as_jax=False)
+            ell = ell_from_coo(sg.edge_src[:ne], sg.edge_dst[:ne],
+                               sg.edge_w[:ne], sg.n_ext, buckets=ell_buckets,
+                               edge_capacity=sg.edge_src.shape[0],
+                               degree_hists=sg.degree_hists, as_jax=False)
     if backend == "ti":
         if sg.ti_scale is None:
             raise ValueError(

@@ -82,6 +82,7 @@ class ClusterSampler:
         self.rng = np.random.default_rng(seed)
         self.parts = partition_graph(graph, num_parts, seed=seed) if parts is None else parts
         self.degrees = graph.degrees()
+        self.degree_hists = graph.degree_hists()
         self._nodes_of_part = [np.where(self.parts == p)[0] for p in range(self.num_parts)]
         self.pad_batch, self.pad_halo, self.pad_edges = padded_sizes_for(
             graph, self.parts, self.num_parts, self.c, include_halo)
@@ -151,7 +152,7 @@ class ClusterSampler:
             pad_edges=self.pad_edges, num_parts=self.num_parts,
             clusters_in_batch=self.c, include_halo=self.include_halo,
             edge_weight_mode=self.edge_weight_mode, beta_spec=self.beta_spec,
-            degrees=self.degrees)
+            degrees=self.degrees, degree_hists=self.degree_hists)
 
     # -- state for checkpoint/restore ----------------------------------------
     def state_dict(self) -> dict:

@@ -73,6 +73,19 @@ class Graph:
         """Per-node (directed) degree, shape (n,) int64."""
         return np.diff(self.indptr).astype(np.int64)
 
+    def degree_hists(self) -> tuple:
+        """``(in, out)`` degree histograms: entry d counts the nodes with d
+        CSR entries in their row (the edges into them as a destination), or
+        with d entries naming them (the edges out of them as a source).
+
+        A sampled subgraph's edges are distinct CSR entries (see
+        ``padded_sizes_for``), so a local row's in- and out-degree never
+        exceed its node's: ``kernels.ops.fixed_row_capacity`` sizes the ELL
+        buckets from these.
+        """
+        out_deg = np.bincount(self.indices, minlength=self.num_nodes)
+        return np.bincount(self.degrees()), np.bincount(out_deg)
+
     def neighbors(self, v: int) -> np.ndarray:
         """Neighbor ids of node ``v`` (a CSR slice view)."""
         return self.indices[self.indptr[v]:self.indptr[v + 1]]
@@ -139,6 +152,9 @@ class PaddedSubgraph:
     n_batch_real: int = 0
     n_halo_real: int = 0
     n_edges_real: int = 0
+    # the graph's (in, out) degree histograms (Graph.degree_hists); host-side
+    # ELL builds size their buckets from them, worst-case shapes without
+    degree_hists: Optional[tuple] = None
 
     @property
     def n_batch(self) -> int:
@@ -193,16 +209,22 @@ def build_subgraph(
     edge_weight_mode: str = "global",
     beta_spec: tuple[str, float] = ("2x-x2", 1.0),
     degrees: Optional[np.ndarray] = None,
+    degree_hists: Optional[tuple] = None,
 ) -> PaddedSubgraph:
     """Construct the padded extended subgraph for a sampled mini-batch.
 
     ``include_halo=False`` gives the Cluster-GCN view (edges internal to the
     batch only); ``edge_weight_mode='local'`` renormalizes by subgraph degrees
     (Cluster-GCN), ``'global'`` keeps whole-graph GCN normalization (GAS/LMC).
+    ``degrees`` and ``degree_hists`` are the graph's (computed here if not
+    given; callers building many batches compute them once); the histograms
+    ride along on the subgraph for its ELL build.
     """
     n = graph.num_nodes
     if degrees is None:
         degrees = graph.degrees()
+    if degree_hists is None:
+        degree_hists = graph.degree_hists()
     batch_nodes = np.asarray(batch_nodes, dtype=np.int64)
     nb = batch_nodes.shape[0]
     if nb > pad_batch:
@@ -331,7 +353,8 @@ def build_subgraph(
         edge_src=es, edge_dst=ed, edge_w=ewp, labels=labels,
         labeled_mask=labeled, beta=beta, loss_scale=loss_scale,
         grad_scale=grad_scale, ti_scale=ti_scale,
-        n_batch_real=nb, n_halo_real=nh, n_edges_real=ne)
+        n_batch_real=nb, n_halo_real=nh, n_edges_real=ne,
+        degree_hists=degree_hists)
 
 
 def padded_sizes_for(graph: Graph, parts: np.ndarray, num_parts: int, c: int,

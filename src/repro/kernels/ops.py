@@ -15,8 +15,10 @@ cotangent and keeps β/mask/fresh gradients exact against the jnp oracle.
 `build_ell` / `ell_from_coo` are bulk-numpy preprocessors (degree bucketing
 via repeat/searchsorted, heavy-row splitting via chunk index arithmetic — no
 per-node Python loop); `ell_from_coo` additionally fixes per-bucket row
-capacities from the padded batch sizes so every mini-batch of a sampler
-traces to the same jit shapes.
+capacities (`fixed_row_capacity`) from the padded batch sizes and the
+graph's degree histograms, so every mini-batch of a sampler traces to the
+same jit shapes, each bucket no larger than the graph's rows can fill. It
+takes a batch's real edges only: padded edges would take slots and add 0.
 
 `ell_aggregate_fn` adapts the SpMM to the GNN `AggregateFn` interface so the
 paper's models can swap the jnp segment-sum oracle for the kernel with one
@@ -49,7 +51,8 @@ class ELLCapacityError(ValueError):
     ``row_capacity`` is given and a degree bucket would need more rows than
     the fixed shape allows — the alternative, silent truncation, would drop
     edges and corrupt aggregations. Catch it to rebuild with larger
-    capacities (or let ``fixed_capacity=True`` derive worst-case ones).
+    capacities (or let ``fixed_capacity=True`` derive them,
+    ``fixed_row_capacity``).
     """
 
 
@@ -234,29 +237,71 @@ def build_ell(indptr: np.ndarray, indices: np.ndarray, weights: np.ndarray,
     return ELLGraph(idx, w, rows, num_rows=n, num_cols=num_cols, transpose=t)
 
 
+def _profile_rows(hist: np.ndarray, buckets: Sequence[int]) -> list:
+    """Most rows each bucket can take from a graph with degree histogram
+    ``hist`` (entry d: the nodes of degree d), over any set of rows whose
+    degrees are at most their nodes': a node puts its one remainder chunk
+    in bucket b > 0 only where its degree exceeds K_{b-1}, and at most
+    ceil(deg / K_max) chunks in the last bucket. Bucket 0 is not bounded
+    here (deg-0 pad rows emit a row there)."""
+    deg = np.arange(hist.shape[0], dtype=np.int64)
+    hist = np.asarray(hist, np.int64)
+    kmax = int(buckets[-1])
+    rows = [None]
+    for b in range(1, len(buckets)):
+        over = deg > int(buckets[b - 1])
+        per_node = (-(-deg // kmax) if b == len(buckets) - 1
+                    else np.ones_like(deg))
+        rows.append(int((hist * per_node)[over].sum()))
+    return rows
+
+
 def fixed_row_capacity(num_rows: int, num_edges: int, buckets=(8, 32, 128),
-                       block_rows: int = 256) -> tuple:
-    """Worst-case per-bucket row counts for any graph with ≤ num_edges edges
-    over num_rows rows: each row emits ≤ 1 remainder chunk (any bucket) plus
-    full-kmax chunks (last bucket only, ≤ E/kmax in total)."""
+                       block_rows: int = 256, *,
+                       degree_hists: Optional[Sequence] = None) -> tuple:
+    """Per-bucket padded row counts, fixed for every batch of a sampler.
+
+    The worst case for any graph with ≤ num_edges edges over num_rows rows:
+    each row emits ≤ 1 remainder chunk (any bucket) plus full-kmax chunks
+    (last bucket only, ≤ E/kmax in total). With ``degree_hists`` (the
+    graph's degree histograms, ``Graph.degree_hists``; one per direction
+    of the ELL, as ``build_ell`` applies one capacity to A and Aᵀ) each
+    bucket past the first is capped by what rows of those degrees can put
+    there (``_profile_rows``, the most over the histograms), rounded up to
+    ``block_rows``: never above the worst case, and sound for any batch
+    whose rows' degrees are at most their nodes', which a sampled subgraph
+    of the graph's real edges is.
+    """
     caps = [max(_round_up(max(num_rows, 1), block_rows), block_rows)
             for _ in buckets]
     caps[-1] = max(_round_up(max(num_rows, 1) + num_edges // int(buckets[-1]),
                              block_rows), block_rows)
+    if degree_hists is not None:
+        bounds = [_profile_rows(h, buckets) for h in degree_hists]
+        for b in range(1, len(buckets)):
+            rows = max(bound[b] for bound in bounds)
+            caps[b] = min(caps[b], max(_round_up(rows, block_rows),
+                                       block_rows))
     return tuple(caps)
 
 
 def ell_from_coo(src: np.ndarray, dst: np.ndarray, w: np.ndarray,
                  num_rows: int, *, buckets=(8, 32, 128),
                  block_rows: int = 256, fixed_capacity: bool = True,
+                 edge_capacity: Optional[int] = None,
+                 degree_hists: Optional[Sequence] = None,
                  as_jax: bool = True) -> ELLGraph:
-    """Padded local COO (a PaddedSubgraph's edge list) -> square ELLGraph.
+    """Local COO (a PaddedSubgraph's real edges) -> square ELLGraph.
 
     Aggregation semantics match ``models.gnn.segment_spmm``: out[dst] +=
-    w·h[src]; padded edges (w == 0) contribute nothing. With
-    ``fixed_capacity`` the bucket shapes depend only on (num_rows, E), so all
-    batches of a sampler share one jit trace. ``as_jax=False`` leaves the
-    bucket arrays on the host (numpy) for deferred ``jax.device_put``.
+    w·h[src]. Pass only the real edges: a padded edge (w == 0, row 0)
+    adds nothing to a sum but takes ELL slots, and the profile capacities
+    leave no room for them. With ``fixed_capacity`` the bucket shapes
+    depend only on (num_rows, ``edge_capacity``, ``degree_hists``, buckets,
+    block_rows) (``fixed_row_capacity``), so all batches of a sampler share
+    one jit trace; ``edge_capacity`` is the padded edge count (default: the
+    edges given). ``as_jax=False`` leaves the bucket arrays on the host
+    (numpy) for deferred ``jax.device_put``.
     """
     src = np.asarray(src, np.int64)
     dst = np.asarray(dst, np.int64)
@@ -265,7 +310,10 @@ def ell_from_coo(src: np.ndarray, dst: np.ndarray, w: np.ndarray,
     counts = np.bincount(dst, minlength=num_rows)
     indptr = np.zeros(num_rows + 1, np.int64)
     indptr[1:] = np.cumsum(counts)
-    caps = (fixed_row_capacity(num_rows, src.shape[0], buckets, block_rows)
+    if edge_capacity is None:
+        edge_capacity = src.shape[0]
+    caps = (fixed_row_capacity(num_rows, int(edge_capacity), buckets,
+                               block_rows, degree_hists=degree_hists)
             if fixed_capacity else None)
     return build_ell(indptr, src[order], w[order], buckets, block_rows,
                      num_cols=num_rows, row_capacity=caps, as_jax=as_jax)

@@ -70,6 +70,7 @@ class StoreGateway:
         self.agg_backend = agg_backend
         self.ell_buckets = tuple(ell_buckets)
         self.degrees = graph.degrees()
+        self.degree_hists = graph.degree_hists()
         self.pads = {b: request_pads(graph, b, degrees=self.degrees)
                      for b in self.buckets}
 
@@ -95,7 +96,7 @@ class StoreGateway:
         sg = build_subgraph(
             self.graph, targets, pad_batch=bucket, pad_halo=pad_halo,
             pad_edges=pad_edges, num_parts=1, clusters_in_batch=1,
-            degrees=self.degrees)
+            degrees=self.degrees, degree_hists=self.degree_hists)
         # "ti" host batches are "ell" batches + the α scales; "segment"
         # batches get the scales attached directly — either way the ti
         # compensation path needs no rebuild

@@ -9,7 +9,7 @@ from _prop import given, settings, strategies as st
 from repro.kernels import (ELLCapacityError, ELLGraph, build_ell,
                            bucketed_spmm, default_interpret, ell_aggregate_fn,
                            ell_from_coo, ell_spmm, lmc_compensate)
-from repro.kernels.ops import _build_ell_loop
+from repro.kernels.ops import _build_ell_loop, fixed_row_capacity
 from repro.kernels.ref import (degree_bucket_spmm_ref, ell_spmm_ref,
                                lmc_compensate_ref)
 
@@ -239,6 +239,161 @@ def test_ell_from_coo_fixed_capacity_never_overflows(seed):
     ref_out = np.zeros((n, 8), np.float32)
     np.add.at(ref_out, dst, w[:, None] * h[src])
     np.testing.assert_allclose(out, ref_out, rtol=2e-4, atol=1e-5)
+
+
+def _hub_graph(seed, directed):
+    """Random graph with hub nodes of degree above the last bucket (128), so
+    their rows split into chunks. ``directed`` gives in-hubs (long CSR rows)
+    and out-hubs (named in many rows) on different nodes, so A and Aᵀ have
+    different degree profiles; otherwise the graph is symmetric."""
+    from repro.graph import Graph
+    r = np.random.default_rng(seed)
+    n = int(r.integers(800, 1200))
+    deg = r.integers(0, 21, n)
+    hubs = r.choice(n, 20, replace=False)
+    deg[hubs[:4]] = r.integers(400, 700, 4)          # in-hubs
+    src = np.repeat(np.arange(n), deg)
+    dst = np.where(r.random(src.shape[0]) < 0.45,    # out-hubs
+                   r.choice(hubs[4:], src.shape[0]),
+                   r.integers(0, n, src.shape[0]))
+    x = np.zeros((n, 4), np.float32)
+    y = np.zeros(n, np.int32)
+    mask = np.ones(n, bool)
+    if not directed:
+        return Graph.from_edges(n, src, dst, x, y, mask, mask, mask)
+    keep = src != dst
+    src, dst = src[keep], dst[keep]
+    indptr = np.zeros(n + 1, np.int64)
+    indptr[1:] = np.cumsum(np.bincount(src, minlength=n))
+    return Graph(indptr=indptr, indices=dst.astype(np.int32), x=x, y=y,
+                 train_mask=mask, val_mask=mask, test_mask=mask)
+
+
+@pytest.mark.parametrize("include_halo", [True, False],
+                         ids=["halo", "no-halo"])
+@pytest.mark.parametrize("directed", [False, True],
+                         ids=["symmetric", "directed"])
+@given(seed=st.integers(0, 10_000))
+@settings(max_examples=8)
+def test_profile_capacity_never_overflows_sampler_batches(seed, directed,
+                                                          include_halo):
+    """The degree-profile capacities hold every batch a ClusterSampler
+    builds, in both directions of the ELL, on graphs whose hubs split into
+    several max-bucket chunks, and never exceed the worst case. Built as
+    ``host_batch`` builds it, and again at 8-row blocks, where the rounding
+    leaves the bounds almost no room."""
+    from repro.core import host_batch
+    from repro.graph import ClusterSampler
+    g = _hub_graph(seed, directed)
+    assert g.degrees().max() > 128
+    parts = np.random.default_rng(seed).permutation(g.num_nodes) % 6
+    s = ClusterSampler(g, 6, 4, parts=parts, seed=seed,
+                       include_halo=include_halo)
+    rows = s.pad_batch + s.pad_halo
+    caps = fixed_row_capacity(rows, s.pad_edges, degree_hists=s.degree_hists)
+    worst = fixed_row_capacity(rows, s.pad_edges)
+    assert all(c <= w for c, w in zip(caps, worst))
+    split = 0
+    for _ in range(3):
+        for sg in s.epoch():
+            ell = host_batch(sg, backend="ell").ell   # raises on overflow
+            for g_dir in (ell, ell.transpose):
+                assert tuple(r.shape[0] for r in g_dir.bucket_rows) == caps
+            ne = sg.n_edges_real
+            tight = ell_from_coo(sg.edge_src[:ne], sg.edge_dst[:ne],
+                                 sg.edge_w[:ne], sg.n_ext, block_rows=8,
+                                 edge_capacity=s.pad_edges,
+                                 degree_hists=s.degree_hists, as_jax=False)
+            for g_dir in (tight, tight.transpose):
+                used = np.concatenate(g_dir.bucket_rows)
+                used = used[used < sg.n_ext]
+                split += used.size - np.unique(used).size
+    assert split > 0            # some hub row split into several chunks
+
+
+@pytest.mark.parametrize("backend", ["ell", "ti"])
+def test_profile_capacity_shapes_are_fixed_per_sampler(small_graph,
+                                                       small_parts, backend):
+    """Every batch of one sampler's epoch has identical ELL shapes (one jit
+    trace), at the profile capacities and at most the worst case."""
+    from repro.core import host_batch
+    from repro.graph import ClusterSampler
+    s = ClusterSampler(small_graph, 16, 4, parts=small_parts, seed=11)
+    rows = s.pad_batch + s.pad_halo
+    caps = fixed_row_capacity(rows, s.pad_edges, degree_hists=s.degree_hists)
+    worst = fixed_row_capacity(rows, s.pad_edges)
+    assert all(c <= w for c, w in zip(caps, worst)) and caps != worst
+    shapes = set()
+    for sg in s.epoch():
+        ell = host_batch(sg, backend=backend).ell
+        shapes.add(jax.tree.map(lambda a: a.shape, ell))
+        assert tuple(r.shape[0] for r in ell.bucket_rows) == caps
+    assert len(shapes) == 1
+
+
+def test_coo_without_profile_keeps_worst_case_shapes(small_graph,
+                                                     small_parts):
+    """Without degree histograms (a hand-built COO, or a subgraph that
+    carries none) the bucket shapes stay fixed_row_capacity's worst case."""
+    import dataclasses
+    from repro.core import host_batch
+    from repro.graph import ClusterSampler
+    r = np.random.default_rng(0)
+    n, e = 100, 400
+    g = ell_from_coo(r.integers(0, n, e), r.integers(0, n, e),
+                     r.random(e).astype(np.float32), n)
+    assert tuple(x.shape[0] for x in g.bucket_rows) == fixed_row_capacity(n, e)
+    s = ClusterSampler(small_graph, 16, 4, parts=small_parts, seed=12)
+    sg = dataclasses.replace(s.sample(), degree_hists=None)
+    ell = host_batch(sg, backend="ell").ell
+    worst = fixed_row_capacity(sg.n_ext, s.pad_edges)
+    for g_dir in (ell, ell.transpose):
+        assert tuple(x.shape[0] for x in g_dir.bucket_rows) == worst
+
+
+def test_real_edge_ell_is_bit_exact_against_padded_worst_case(small_graph,
+                                                              small_parts):
+    """The ELL of a batch's real edges at the profile capacities gives the
+    same SpMM and VJP, to the last bit, as the same batch built with every
+    padded edge at the worst-case capacities: each real row sums the same
+    products in the same slot order, and the padded edges only added
+    w = 0 products to local row 0 (kernel interpreted on the CPU).
+
+    Row 0 of Aᵀ led with the padded entries in the padded build (they sort
+    first, dst 0), so its real entries sat at slot offset P mod 128, P the
+    padded edge count. Bit equality there needs them in one 128-slot chunk
+    of that build, as they are in this batch (asserted); across a chunk
+    boundary that build split row 0's sum in two partial rows."""
+    from repro.core import host_batch
+    from repro.graph import ClusterSampler
+    s = ClusterSampler(small_graph, 16, 4, parts=small_parts, seed=7)
+    sg = s.sample()
+    ne, n = sg.n_edges_real, sg.n_ext
+    src, dst = sg.edge_src[:ne], sg.edge_dst[:ne]
+    pad = sg.edge_src.shape[0] - ne
+    assert pad > 0
+    assert pad % 128 + int((src == 0).sum()) <= 128
+    new = jax.device_put(host_batch(sg, backend="ell").ell)
+    old = ell_from_coo(sg.edge_src, sg.edge_dst, sg.edge_w, n)
+    assert (sum(x.size for x in new.bucket_idx)
+            < sum(x.size for x in old.bucket_idx))
+    # local row 0 holds only its real degree, in both directions
+    for g_dir, deg0 in ((new, int((dst == 0).sum())),
+                        (new.transpose, int((src == 0).sum()))):
+        held = sum(int((np.asarray(w)[np.asarray(rows) == 0] != 0).sum())
+                   for w, rows in zip(g_dir.bucket_w, g_dir.bucket_rows))
+        n_rows = sum(int((np.asarray(rows) == 0).sum())
+                     for rows in g_dir.bucket_rows)
+        assert deg0 > 0 and held == deg0 and n_rows == 1
+    rng = np.random.default_rng(0)
+    h = jnp.asarray(rng.normal(size=(n, 24)).astype(np.float32))
+    ct = jnp.asarray(rng.normal(size=(n, 24)).astype(np.float32))
+    outs = []
+    for g_ell in (new, old):
+        out, vjp = jax.vjp(lambda h_, g_=g_ell: bucketed_spmm(g_, h_), h)
+        outs.append((np.asarray(out), np.asarray(vjp(ct)[0])))
+    np.testing.assert_array_equal(outs[0][0], outs[1][0])
+    np.testing.assert_array_equal(outs[0][1], outs[1][1])
 
 
 # ------------------------------------------------------------- gradient paths

@@ -80,10 +80,15 @@ def test_kernel_backends_run_every_capped_batch_like_segment(
         store)
     steps = {b: jax.jit(make_train_step(gnn, method, g.num_nodes, backend=b))
              for b in ("segment", backend)}
-    caps = fixed_row_capacity(s.pad_batch + s.pad_halo, s.pad_edges)
+    rows_ext = s.pad_batch + s.pad_halo
+    caps = fixed_row_capacity(rows_ext, s.pad_edges,
+                              degree_hists=s.degree_hists)
+    worst = fixed_row_capacity(rows_ext, s.pad_edges)
+    assert all(c <= w for c, w in zip(caps, worst))
     n = 0
     for sg in s.epoch():
-        # built with the fixed bucket capacities of the capped edge count
+        # built with the fixed bucket capacities of the capped edge count and
+        # the graph's degree profile, never above the worst case
         # (ell_from_coo raises where a bucket would overflow them)
         batches = {b: to_device_batch(sg, backend=b) for b in steps}
         rows = tuple(r.shape[0] for r in batches[backend].ell.bucket_rows)

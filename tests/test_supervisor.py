@@ -24,10 +24,14 @@ from repro.train import (FaultPlan, GNNTrainer, HealthConfig, HealthGuard,
 
 
 def _trainer(g, parts, tmp, **kw):
+    # no straggler deadline: the matrix compares loss streams bit for bit,
+    # and a step that the host's load slows past the deadline would drop
+    # its store update in one of the two runs only (straggler handling is
+    # tested in test_fault_tolerance.py)
     gnn = make_gnn("gcn", g.feature_dim, 32, g.num_classes, 2)
     s = ClusterSampler(g, 16, 2, parts=parts, seed=1)
     return GNNTrainer(gnn, LMC, g, s, sgd(lr=0.3), ckpt_dir=tmp,
-                      ckpt_every=10, **kw)
+                      ckpt_every=10, straggler_deadline=float("inf"), **kw)
 
 
 def _losses(tr):
