@@ -17,7 +17,6 @@ Paper mapping (DESIGN.md §6):
   bench_spider                -> App F   (variance-reduced estimator)
   bench_spmm_kernel           -> kernel hot-spot micro-benchmark
   bench_compensate            -> Eq. 9/12 fused gather+lerp micro-benchmark
-                                 (streamed vs resident store gather)
   bench_pipeline              -> async sampling pipeline + minibatch
                                  recycling (DESIGN.md §9): sync-vs-prefetch
                                  step times, overlap fraction, ρ=4 parity
@@ -345,12 +344,8 @@ def bench_spmm_kernel(fast=False):
     # takes best-of-iters over the same number of steady-state iterations
     iters = 3 if fast else 5
     us_ref = _timer(lambda: jax.block_until_ready(ref(h)), iters=iters)
-    # streamed (HBM→VMEM DMA double buffer, the default) vs resident
-    # ((M, block_d) VMEM feature block, the pre-streaming path)
     us_str = _timer(lambda: jax.block_until_ready(
-        bucketed_spmm(ell, h, stream=True)), iters=iters)
-    us_res = _timer(lambda: jax.block_until_ready(
-        bucketed_spmm(ell, h, stream=False)), iters=iters)
+        bucketed_spmm(ell, h)), iters=iters)
     nnz = g.num_edges
     gflops = lambda us: 2 * nnz * 128 / us / 1e3
     mode = "interpret" if default_interpret() else "compiled"
@@ -359,8 +354,6 @@ def bench_spmm_kernel(fast=False):
         f"pallas_{mode}_streamed": {"us_per_call": us_str,
                                     "gflops": gflops(us_str),
                                     "default_path": True},
-        f"pallas_{mode}_resident": {"us_per_call": us_res,
-                                    "gflops": gflops(us_res)},
     }
     print(f"spmm/jnp_segment_sum,{us_ref:.0f},gflops={gflops(us_ref):.2f}",
           flush=True)
@@ -368,8 +361,6 @@ def bench_spmm_kernel(fast=False):
             if mode == "interpret" else "")
     print(f"spmm/pallas_{mode}_streamed,{us_str:.0f},"
           f"gflops={gflops(us_str):.2f}{note}", flush=True)
-    print(f"spmm/pallas_{mode}_resident,{us_res:.0f},"
-          f"gflops={gflops(us_res):.2f}{note}", flush=True)
 
     # ELL preprocessing: vectorized bulk-numpy builder vs the original
     # per-node Python loop, on a 50k-node synthetic CSR graph
@@ -403,11 +394,11 @@ def bench_spmm_kernel(fast=False):
 
 def bench_compensate(fast=False):
     """Fused LMC compensate (Eq. 9/12) micro-benchmark: jnp oracle vs the
-    Pallas kernel, streamed (HBM→VMEM DMA, the default) vs resident store
-    block — plus a streamed run at 4x the old ~24k-row cap, which the
-    resident path cannot compile at all. Same protocol as bench_spmm_kernel
-    (warmup + equal steady-state iters); derived metric is effective GB/s
-    over the gather+lerp traffic (store row reads + fresh reads + writes)."""
+    Pallas kernel (HBM→VMEM DMA store gather), plus a kernel run at 4x the
+    ~24k-row cap a VMEM-resident store block would have. Same protocol as
+    bench_spmm_kernel (warmup + equal steady-state iters); derived metric is
+    effective GB/s over the gather+lerp traffic (store row reads + fresh
+    reads + writes)."""
     import jax
     import jax.numpy as jnp
     from repro.kernels import default_interpret, lmc_compensate
@@ -419,28 +410,24 @@ def bench_compensate(fast=False):
     mode = "interpret" if default_interpret() else "compiled"
     rows = {}
 
-    def one(entry, m, **kw):
+    def one(entry, m, kernel):
         store = jnp.asarray(rng.normal(size=(m, d)).astype(np.float32))
         gids = jnp.asarray(rng.integers(0, m, n).astype(np.int32))
         beta = jnp.asarray(rng.random(n).astype(np.float32))
         mask = jnp.asarray((rng.random(n) > 0.2).astype(np.float32))
         fresh = jnp.asarray(rng.normal(size=(n, d)).astype(np.float32))
-        if kw:
-            fn = jax.jit(lambda *a: lmc_compensate(*a, **kw))
-        else:
-            fn = jax.jit(lambda *a: lmc_compensate_ref(*a))
+        fn = jax.jit(lmc_compensate if kernel else lmc_compensate_ref)
         us = _timer(lambda: jax.block_until_ready(
             fn(store, gids, beta, fresh, mask)), iters=iters)
         gbps = 3 * n * d * 4 / us / 1e3    # store-gather + fresh + out bytes
         rows[entry] = {"us_per_call": us, "gbps": gbps, "store_rows": m}
         print(f"compensate/{entry},{us:.0f},gbps={gbps:.2f};m={m}", flush=True)
 
-    m_small = 16384                        # fits the old resident-block cap
-    one("jnp_oracle", m_small)
-    one(f"pallas_{mode}_streamed", m_small, stream=True)
-    one(f"pallas_{mode}_resident", m_small, stream=False)
-    # full-graph-scale store: only the streamed path can compile this
-    one(f"pallas_{mode}_streamed_4xcap", 4 * 24576, stream=True)
+    m_small = 16384
+    one("jnp_oracle", m_small, kernel=False)
+    one(f"pallas_{mode}_streamed", m_small, kernel=True)
+    # full-graph-scale store, past what a VMEM-resident block could hold
+    one(f"pallas_{mode}_streamed_4xcap", 4 * 24576, kernel=True)
     rows[f"pallas_{mode}_streamed"]["default_path"] = True
     return rows
 

@@ -12,8 +12,6 @@ onto.
         # reads/writes on the hot path; DESIGN.md §11)
     PYTHONPATH=src python examples/train_gnn.py --prefetch 4 --recycle 4
         # async sampling pipeline + minibatch recycling (DESIGN.md §9)
-    PYTHONPATH=src python examples/train_gnn.py --no-prefetch
-        # legacy synchronous sampling (stateful sampler RNG)
     PYTHONPATH=src python examples/train_gnn.py --health --async-ckpt
         # numerical-health supervisor (NaN/spike guard with rollback) +
         # background checkpoint writes (DESIGN.md §10)
@@ -64,23 +62,12 @@ def main():
                          "ti = ELL aggregation + store-free message-"
                          "invariance compensation (zero historical-store "
                          "reads; DESIGN.md §11)")
-    ap.add_argument("--stream", default=None, action="store_true",
-                    help="force the HBM→VMEM double-buffered DMA gather in "
-                         "the ell-backend kernels (default: autodetect = "
-                         "streamed; required for full-graph stores on TPU)")
-    ap.add_argument("--no-stream", dest="stream", action="store_false",
-                    help="force the legacy resident VMEM gather blocks "
-                         "(small graphs only)")
     ap.add_argument("--prefetch", type=int, default=2, metavar="N",
                     help="async sampling pipeline queue depth: background "
                          "threads build + bucket the next N batches while "
                          "the device steps, with double-buffered "
                          "host->device transfer (DESIGN.md §9); 0 keeps the "
                          "schedule-indexed stream but builds synchronously")
-    ap.add_argument("--no-prefetch", dest="prefetch", action="store_const",
-                    const=None,
-                    help="fall back to the legacy fully synchronous sampling "
-                         "path (stateful sampler RNG, no pipeline)")
     ap.add_argument("--recycle", type=int, default=1, metavar="R",
                     help="minibatch recycling: reuse each sampled subgraph "
                          "for R consecutive steps before resampling "
@@ -111,9 +98,6 @@ def main():
                          "train step only pays the device->host snapshot; "
                          "files are byte-identical to synchronous saves)")
     args = ap.parse_args()
-    if args.prefetch is None and args.recycle > 1:
-        ap.error("--no-prefetch is incompatible with --recycle > 1 "
-                 "(recycling needs the schedule-indexed pipeline)")
 
     t0 = time.time()
     g = make_sbm_dataset(args.preset, seed=0)
@@ -135,7 +119,7 @@ def main():
               if args.health else None)
     tr = GNNTrainer(gnn, m, g, sampler, sgd(lr=0.2), seed=0,
                     ckpt_dir=args.ckpt_dir, ckpt_every=100,
-                    backend=args.backend, stream=args.stream,
+                    backend=args.backend,
                     prefetch=args.prefetch, recycle=args.recycle,
                     pipeline_workers=args.pipeline_workers,
                     health=health, max_retries=args.max_retries,

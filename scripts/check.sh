@@ -103,9 +103,8 @@ for name in ("BENCH_spmm.json", "BENCH_compensate.json"):
               f"{fresh.get('backend')!r}; skipping tripwire")
         continue
     for key, row in fresh["rows"].items():
-        # gate the production kernel path (default_path rows); the legacy
-        # resident-block comparison rows are informational and too jittery
-        # under the interpreter to gate on
+        # gate the kernel path at the size it was baselined at
+        # (default_path rows); the 4x-cap rows are informational
         if not key.startswith("pallas_") or not row.get("default_path"):
             continue
         old = base["rows"].get(key)

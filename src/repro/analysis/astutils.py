@@ -1,7 +1,8 @@
 """Shared AST machinery for the rule modules.
 
 Everything here is deliberately conservative: name resolution follows import
-aliases only (no cross-module inference), and the constant evaluator returns
+aliases only (the one cross-module step: an int literal a module imports by
+name from a module of its own source tree), and the constant evaluator returns
 ``None`` the moment an expression depends on a runtime value. Rules are
 written so that "could not resolve" maps to either "skip" (R005 arity on a
 computed return) or "flag" (R003 on a runtime-shaped VMEM block) depending on
@@ -10,6 +11,7 @@ which direction is safe for the invariant.
 from __future__ import annotations
 
 import ast
+from pathlib import Path
 from typing import Iterator, Optional
 
 
@@ -117,16 +119,37 @@ def param_default_env(func: ast.FunctionDef) -> dict[str, int]:
     return env
 
 
-def module_const_env(tree: ast.Module) -> dict[str, int]:
-    """Top-level `NAME = <int literal>` assignments."""
+def module_const_env(tree: ast.Module,
+                     path: Optional[str] = None) -> dict[str, int]:
+    """Top-level `NAME = <int literal>` assignments, and, given the module's
+    ``path``, those it imports by name from a module of its source tree
+    (`from repro.kernels.ell_spmm import LANES`)."""
     env: dict[str, int] = {}
     for node in tree.body:
+        if (path is not None and isinstance(node, ast.ImportFrom)
+                and node.module and not node.level):
+            src = _source_file(path, node.module)
+            if src is not None:
+                consts = module_const_env(ast.parse(src.read_text()))
+                for alias in node.names:
+                    if alias.name in consts:
+                        env[alias.asname or alias.name] = consts[alias.name]
         if isinstance(node, ast.Assign) and isinstance(node.value, ast.Constant):
             if isinstance(node.value.value, int):
                 for tgt in node.targets:
                     if isinstance(tgt, ast.Name):
                         env[tgt.id] = node.value.value
     return env
+
+
+def _source_file(path: str, module: str) -> Optional[Path]:
+    """The file of absolute import ``module`` in the nearest ancestor
+    directory of ``path`` that holds it (None: not in this tree)."""
+    rel = Path(*module.split(".")).with_suffix(".py")
+    for root in Path(path).resolve().parents:
+        if (root / rel).is_file():
+            return root / rel
+    return None
 
 
 FunctionLike = (ast.FunctionDef, ast.AsyncFunctionDef)

@@ -25,10 +25,11 @@ only: this CPU container's interpret mode happily runs any block size, which
 is exactly how the pre-streaming resident-block cap (~24k gather rows) went
 unnoticed until TPU lowering. The deleted trace-time guards are replaced
 statically: BlockSpec block shapes and VMEM scratch shapes are evaluated from
-literals + enclosing-function parameter defaults; a shape with a
-runtime-valued dim (an operand row count) is an unbounded resident block and
-must stream or carry a pragma, and resolvable shapes are summed per kernel
-entry point against the 12 MiB budget.
+literals, module int constants (own or imported from the source tree) and
+enclosing-function parameter defaults; a shape with a runtime-valued dim (an
+operand row count) is an unbounded resident block and must stream or carry
+a pragma, and resolvable shapes are summed per kernel entry point against
+the 12 MiB budget.
 """
 from __future__ import annotations
 
@@ -297,7 +298,7 @@ class VmemBudgetRule(Rule):
     def _vmem_blocks(self, mod: ModuleInfo):
         """Yield (node, [(dim_expr, val|None)], bytes|None, description) for
         every BlockSpec block shape and VMEM scratch shape in the module."""
-        mod_env = astutils.module_const_env(mod.tree)
+        mod_env = astutils.module_const_env(mod.tree, mod.path)
         out = []
         for node in ast.walk(mod.tree):
             qn = astutils.call_qualname(node, mod.aliases)

@@ -144,8 +144,8 @@ def _combine(mode: str, beta: jax.Array, hist: jax.Array, fresh: jax.Array,
 
 def _compensate(mode: str, backend: str, store: Optional[jax.Array], l: int,
                 halo_gids: jax.Array, beta1d: jax.Array, fresh: jax.Array,
-                mask1d: jax.Array, stream: Optional[bool] = None,
-                ti_scale: Optional[jax.Array] = None) -> jax.Array:
+                mask1d: jax.Array, ti_scale: Optional[jax.Array] = None
+                ) -> jax.Array:
     """Halo compensation ĥ/V̂ (Eq. 9/12): gather the historical rows of
     layer ``l`` of ``store`` and convex-combine with the incomplete fresh
     values, under the ``lmc.halo`` scope. ``store`` may be None where no
@@ -154,9 +154,8 @@ def _compensate(mode: str, backend: str, store: Optional[jax.Array], l: int,
     backend="segment": jnp gather + lerp. backend="ell": one fused Pallas
     ``lmc_compensate`` call — every mode is the same kernel with an effective
     β (lmc: β, historical: 0, fresh: 1); "none" skips the gather entirely.
-    ``stream`` (default: autodetect) selects the HBM→VMEM DMA store gather —
-    the store is *full-graph* here, so the streamed path is what lets the
-    compiled kernel run at paper scale (DESIGN.md §3).
+    The kernel gathers the *full-graph* store rows by HBM→VMEM DMA, so it
+    compiles at paper scale (DESIGN.md §3).
 
     backend="ti": the message-invariance estimator (DESIGN.md §11) — the
     historical row H̄_i is replaced by the message-invariant transform
@@ -179,7 +178,7 @@ def _compensate(mode: str, backend: str, store: Optional[jax.Array], l: int,
                         "historical": jnp.zeros_like(beta1d),
                         "fresh": jnp.ones_like(beta1d)}[mode]
             return lmc_compensate(store[l], halo_gids, beta_eff, fresh,
-                                  mask1d, stream=stream)
+                                  mask1d)
         hist = gather_rows(store[l], halo_gids)
         return _combine(mode, beta1d[:, None], hist, fresh, mask1d[:, None])
 
@@ -195,8 +194,7 @@ def _refresh(store: jax.Array, l: int, gids: jax.Array, mask: jax.Array,
 
 def make_infer_step(gnn: GNN, num_nodes: int, *, backend: str = "segment",
                     fwd_mode: str = "historical", compensation: str = "store",
-                    refresh: bool = True,
-                    stream: Optional[bool] = None) -> Callable:
+                    refresh: bool = True) -> Callable:
     """Build ``infer(params, store, batch, x_full, self_w_full)`` — the
     forward-only serving entry point over the historical store.
 
@@ -254,8 +252,7 @@ def make_infer_step(gnn: GNN, num_nodes: int, *, backend: str = "segment",
             bmask = batch.batch_mask[:, None]
         edges = EdgeList(batch.edge_src, batch.edge_dst, batch.edge_w)
         aux = LayerAux(edges=edges, x=x_ext, h0=h0_ext, self_w=self_w_ext,
-                       ell=batch.ell if backend == "ell" else None,
-                       stream=stream)
+                       ell=batch.ell if backend == "ell" else None)
         comp_backend = "ti" if compensation == "ti" else backend
 
         h_in = h0_ext
@@ -268,7 +265,7 @@ def make_infer_step(gnn: GNN, num_nodes: int, *, backend: str = "segment",
                 fwd_mode, comp_backend,
                 None if compensation == "ti" else new_h, l,
                 batch.halo_gids, batch.beta, h_out[nb:], batch.halo_mask,
-                stream, batch.ti_scale)
+                batch.ti_scale)
             if refresh:
                 new_h = _refresh(new_h, l, batch.batch_gids,
                                  batch.batch_mask, h_bar_batch, num_nodes)
@@ -283,8 +280,7 @@ def make_infer_step(gnn: GNN, num_nodes: int, *, backend: str = "segment",
 
 
 def make_train_step(gnn: GNN, method: MBMethod, num_nodes: int, *,
-                    backend: str = "segment",
-                    stream: Optional[bool] = None) -> Callable:
+                    backend: str = "segment") -> Callable:
     """Build ``step(params, store, batch, x_full, self_w_full)``.
 
     Returns ``(loss, grads, new_store, metrics)``. Pure; jit/pjit at call site
@@ -296,11 +292,6 @@ def make_train_step(gnn: GNN, method: MBMethod, num_nodes: int, *,
     ``jax.vjp`` cotangent applications of Eqs. 11-13) and halo compensation
     through the fused ``lmc_compensate`` kernel. The batch must then carry the
     bucketed adjacency (``to_device_batch(sg, backend="ell")``).
-
-    ``stream`` (ell/ti backends; default autodetect = streamed) selects the
-    HBM→VMEM double-buffered DMA gather in both kernels — required for
-    full-graph historical stores on the compiled path; ``stream=False``
-    forces the legacy resident VMEM gather blocks.
 
     ``backend="ti"`` aggregates through the same Pallas SpMM but compensates
     with the message-invariance estimator instead of historical rows
@@ -338,8 +329,7 @@ def make_train_step(gnn: GNN, method: MBMethod, num_nodes: int, *,
             hmask = batch.halo_mask[:, None]
         edges = EdgeList(batch.edge_src, batch.edge_dst, batch.edge_w)
         aux = LayerAux(edges=edges, x=x_ext, h0=h0_ext, self_w=self_w_ext,
-                       ell=batch.ell if backend in ("ell", "ti") else None,
-                       stream=stream)
+                       ell=batch.ell if backend in ("ell", "ti") else None)
 
         # ---------------- forward (Eqs. 8-10) --------------------------------
         h_in = h0_ext
@@ -355,7 +345,7 @@ def make_train_step(gnn: GNN, method: MBMethod, num_nodes: int, *,
             h_hat_halo = _compensate(method.fwd_mode, backend,
                                      None if backend == "ti" else new_h, l,
                                      batch.halo_gids, batch.beta, h_out[nb:],
-                                     batch.halo_mask, stream, batch.ti_scale)
+                                     batch.halo_mask, batch.ti_scale)
             if method.store_writes:
                 new_h = _refresh(new_h, l, batch.batch_gids, batch.batch_mask,
                                  h_bar_batch, num_nodes)
@@ -417,7 +407,7 @@ def make_train_step(gnn: GNN, method: MBMethod, num_nodes: int, *,
                 V_hat = _compensate(method.bwd_mode, backend,
                                     None if backend == "ti" else new_v, l - 1,
                                     batch.halo_gids, batch.beta, hgrad[nb:],
-                                    batch.halo_mask, stream, batch.ti_scale)
+                                    batch.halo_mask, batch.ti_scale)
                 if method.store_writes:
                     new_v = _refresh(new_v, l - 1, batch.batch_gids,
                                      batch.batch_mask, V_bar_next, num_nodes)

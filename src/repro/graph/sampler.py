@@ -8,13 +8,13 @@ step compiles once.
 Two sampling APIs coexist:
 
 * the *stateful* API (:meth:`ClusterSampler.sample` / ``epoch``) advances the
-  sampler's own RNG — the legacy synchronous-trainer path, whose bit-generator
-  state rides along in checkpoints;
+  sampler's own RNG — for tests and offline scripts;
 * the *schedule* API (:meth:`ClusterSampler.clusters_at`) is a pure function
-  of ``(seed, index)`` with no mutable state. The async prefetch pipeline
-  (``repro.data.prefetch.SubgraphPipeline``) is built on it: batches can be
-  constructed by a thread pool in any arrival order and the stream is still
-  bit-identical to a synchronous walk of the same indices (DESIGN.md §9).
+  of ``(seed, index)`` with no mutable state. The batch pipeline
+  (``repro.data.prefetch.SubgraphPipeline``), the trainer's one batch
+  source, is built on it: batches can be constructed by a thread pool in
+  any arrival order and the stream is still bit-identical to a synchronous
+  walk of the same indices (DESIGN.md §9).
 """
 from __future__ import annotations
 
@@ -39,7 +39,7 @@ class ClusterSampler:
     Thread-safety: :meth:`build_batch` and :meth:`clusters_at` are read-only
     with respect to sampler state and safe to call concurrently from worker
     threads. :meth:`sample` / :meth:`epoch` mutate ``self.rng`` and must stay
-    on a single thread (the synchronous trainer path).
+    on a single thread.
     """
 
     def __init__(
@@ -153,12 +153,3 @@ class ClusterSampler:
             clusters_in_batch=self.c, include_halo=self.include_halo,
             edge_weight_mode=self.edge_weight_mode, beta_spec=self.beta_spec,
             degrees=self.degrees, degree_hists=self.degree_hists)
-
-    # -- state for checkpoint/restore ----------------------------------------
-    def state_dict(self) -> dict:
-        """Checkpointable state: the stateful RNG's bit-generator state."""
-        return {"bit_generator": self.rng.bit_generator.state}
-
-    def load_state_dict(self, state: dict) -> None:
-        """Restore the stateful RNG (deterministic resume of :meth:`sample`)."""
-        self.rng.bit_generator.state = state["bit_generator"]

@@ -19,11 +19,8 @@ capacities (`fixed_row_capacity`) from the padded batch sizes and the
 graph's degree histograms, so every mini-batch of a sampler traces to the
 same jit shapes, each bucket no larger than the graph's rows can fill. It
 takes a batch's real edges only: padded edges would take slots and add 0.
-
-`ell_aggregate_fn` adapts the SpMM to the GNN `AggregateFn` interface so the
-paper's models can swap the jnp segment-sum oracle for the kernel with one
-argument; the train step selects it with ``make_train_step(...,
-backend="ell")``.
+The train step puts the SpMM on its hot path with ``make_train_step(...,
+backend="ell")``, which hands each layer the batch's ``ELLGraph``.
 """
 from __future__ import annotations
 
@@ -36,7 +33,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels.compensate import lmc_compensate_kernel
-from repro.kernels.ell_spmm import default_interpret, default_stream, ell_spmm
+from repro.kernels.ell_spmm import default_interpret, ell_spmm
 from repro.kernels import ref
 
 
@@ -320,8 +317,8 @@ def ell_from_coo(src: np.ndarray, dst: np.ndarray, w: np.ndarray,
 
 
 # ------------------------------------------------------------ kernel wrappers
-def _bucketed_spmm_impl(g: ELLGraph, h: jax.Array, interpret: bool,
-                        stream: bool) -> jax.Array:
+def _bucketed_spmm_impl(g: ELLGraph, h: jax.Array,
+                        interpret: bool) -> jax.Array:
     """out[i] = Σ_{j in N(i)} w_ij h[j] over all degree buckets."""
     n = g.num_rows
     d = h.shape[1]
@@ -330,7 +327,7 @@ def _bucketed_spmm_impl(g: ELLGraph, h: jax.Array, interpret: bool,
     out = jnp.zeros((n + 1, d_pad), h.dtype)   # row n catches padding rows
     for idx, w, rows in zip(g.bucket_idx, g.bucket_w, g.bucket_rows):
         part = ell_spmm(idx, w, hp, block_rows=_pick_block_rows(idx.shape[0]),
-                        interpret=interpret, stream=stream)
+                        interpret=interpret)
         out = out.at[rows].add(part.astype(h.dtype), mode="drop")
     return out[:n, :d]
 
@@ -345,26 +342,24 @@ def _zeros_cotangent(tree):
     return jax.tree.map(z, tree)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
-def _bucketed_spmm_vjp(interpret: bool, stream: bool, g: ELLGraph,
-                       h: jax.Array):
-    return _bucketed_spmm_impl(g, h, interpret, stream)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _bucketed_spmm_vjp(interpret: bool, g: ELLGraph, h: jax.Array):
+    return _bucketed_spmm_impl(g, h, interpret)
 
 
-def _bucketed_spmm_fwd(interpret, stream, g, h):
-    return _bucketed_spmm_impl(g, h, interpret, stream), (g, h)
+def _bucketed_spmm_fwd(interpret, g, h):
+    return _bucketed_spmm_impl(g, h, interpret), (g, h)
 
 
-def _bucketed_spmm_bwd(interpret, stream, res, ct):
+def _bucketed_spmm_bwd(interpret, res, ct):
     g, h = res
     if g.transpose is None:
         raise ValueError(
             "bucketed_spmm: gradient requested but the ELLGraph was built "
             "with with_transpose=False; the SpMM VJP needs the bucketed Aᵀ")
-    # the backward SpMM streams (or not) exactly like the forward: the Aᵀ
-    # kernel's gather source is the cotangent, which is full-graph-sized
-    # whenever the forward output was — the cap must not move to the bwd pass
-    dh = _bucketed_spmm_impl(g.transpose, ct, interpret, stream)
+    # the backward SpMM is the same kernel over Aᵀ: its gather source is the
+    # cotangent, which is full-graph-sized whenever the forward output was
+    dh = _bucketed_spmm_impl(g.transpose, ct, interpret)
     # weight cotangent dw[i,k] = ⟨ct[rows[i]], h[idx[i,k]]⟩ (jnp gather; XLA
     # DCEs it under jit when the caller only differentiates w.r.t. h, the
     # LMC train-step case). Row `num_rows` of the padded ct zeroes the
@@ -382,28 +377,21 @@ _bucketed_spmm_vjp.defvjp(_bucketed_spmm_fwd, _bucketed_spmm_bwd)
 
 
 def bucketed_spmm(g: ELLGraph, h: jax.Array, *,
-                  interpret: bool | None = None,
-                  stream: bool | None = None) -> jax.Array:
+                  interpret: bool | None = None) -> jax.Array:
     """Differentiable bucketed ELL SpMM: out = A h.
 
-    VJP: dh = Aᵀ(dout) through the transposed-bucket kernel (streamed like
-    the forward, so a full-graph-sized cotangent never needs a resident VMEM
-    block); d(bucket_w) via jnp gathers (padding slots get the would-be-edge
+    VJP: dh = Aᵀ(dout) through the transposed-bucket kernel (the same HBM
+    gather as the forward, so nothing bounds a full-graph-sized cotangent);
+    d(bucket_w) via jnp gathers (padding slots get the would-be-edge
     gradient ct·h[0], which is meaningless but never read back — ELL weights
     map to CSR entries only where the builder placed real edges).
-
-    ``stream=None`` autodetects to the HBM→VMEM DMA gather (no VMEM bound on
-    h's row count); ``stream=False`` forces the legacy resident feature block
-    (small sources / benchmarking).
     """
     if interpret is None:
         interpret = default_interpret()
-    if stream is None:
-        stream = default_stream()
-    return _bucketed_spmm_vjp(bool(interpret), bool(stream), g, h)
+    return _bucketed_spmm_vjp(bool(interpret), g, h)
 
 
-def _compensate_impl(store, gids, beta, fresh, mask, interpret, stream):
+def _compensate_impl(store, gids, beta, fresh, mask, interpret):
     n, d = fresh.shape
     d_pad = _round_up(d, 128)
     block = 256 if n >= 256 else _round_up(max(n, 8), 8)
@@ -417,26 +405,23 @@ def _compensate_impl(store, gids, beta, fresh, mask, interpret, stream):
     bp = jnp.pad(beta, pad1) if n_pad != n else beta
     mp = jnp.pad(mask, pad1) if n_pad != n else mask
     out = lmc_compensate_kernel(sp, gp, bp, fp, mp, block_rows=block,
-                                interpret=interpret, stream=stream)
+                                interpret=interpret)
     return out[:n, :d]
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
-def _lmc_compensate_vjp(interpret, stream, store, gids, beta, fresh, mask):
-    return _compensate_impl(store, gids, beta, fresh, mask, interpret, stream)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _lmc_compensate_vjp(interpret, store, gids, beta, fresh, mask):
+    return _compensate_impl(store, gids, beta, fresh, mask, interpret)
 
 
-def _compensate_fwd(interpret, stream, store, gids, beta, fresh, mask):
-    out = _compensate_impl(store, gids, beta, fresh, mask, interpret, stream)
+def _compensate_fwd(interpret, store, gids, beta, fresh, mask):
+    out = _compensate_impl(store, gids, beta, fresh, mask, interpret)
     return out, (store, gids, beta, fresh, mask)
 
 
-def _compensate_bwd(interpret, stream, res, ct):
-    # The adjoint is VMEM-cap-free by construction at any store size: the
-    # hist gather and the d_store scatter-add lower to XLA gather/scatter
-    # over HBM-resident operands (no (M, block_d) VMEM residency anywhere),
-    # so streaming the forward never shifts a resident-block cap here.
-    del stream
+def _compensate_bwd(interpret, res, ct):
+    # The adjoint is VMEM-cap-free at any store size: the hist gather and
+    # the d_store scatter-add lower to XLA gather/scatter over HBM operands
     store, gids, beta, fresh, mask = res
     hist = jnp.take(store, gids, axis=0, mode="clip")
     d_store = jnp.zeros_like(store).at[gids].add(
@@ -455,8 +440,7 @@ _lmc_compensate_vjp.defvjp(_compensate_fwd, _compensate_bwd)
 
 def lmc_compensate(store: jax.Array, gids: jax.Array, beta: jax.Array,
                    fresh: jax.Array, mask: jax.Array, *,
-                   interpret: bool | None = None,
-                   stream: bool | None = None) -> jax.Array:
+                   interpret: bool | None = None) -> jax.Array:
     """ĥ = mask · [(1-β)·store[gid] + β·fresh]  (Eq. 9/12), differentiable.
 
     store (M, D); gids/beta/mask (N,); fresh (N, D) -> (N, D). Arbitrary N/D
@@ -464,11 +448,9 @@ def lmc_compensate(store: jax.Array, gids: jax.Array, beta: jax.Array,
     including the scatter-add store cotangent (an XLA HBM scatter — no
     resident VMEM block, so the backward pass is cap-free at any M).
 
-    ``stream=None`` autodetects to the HBM→VMEM DMA store gather: the
-    *full-graph* historical store stays in HBM and only the gathered rows
-    cross into VMEM, so the compiled path has no bound on the store row
-    count. ``stream=False`` forces the legacy resident store block (small
-    stores / benchmarking only).
+    The *full-graph* historical store stays in HBM and only the gathered
+    rows cross into VMEM, so the compiled path has no bound on the store
+    row count.
 
     Perf note: when D is not a multiple of 128 the *whole store* is padded to
     the tile width on every call — keep hidden dims 128-aligned in production
@@ -476,24 +458,10 @@ def lmc_compensate(store: jax.Array, gids: jax.Array, beta: jax.Array,
     """
     if interpret is None:
         interpret = default_interpret()
-    if stream is None:
-        stream = default_stream()
-    return _lmc_compensate_vjp(bool(interpret), bool(stream), store, gids,
-                               beta, fresh, mask)
-
-
-def ell_aggregate_fn(g: ELLGraph, *, interpret: bool | None = None,
-                     stream: bool | None = None):
-    """AggregateFn adapter for repro.models.gnn (ignores the COO edge list —
-    the ELL graph already encodes the same adjacency)."""
-    def aggregate(edges, h, num_rows):
-        del edges
-        out = bucketed_spmm(g, h, interpret=interpret, stream=stream)
-        assert out.shape[0] == num_rows
-        return out
-    return aggregate
+    return _lmc_compensate_vjp(bool(interpret), store, gids, beta, fresh,
+                               mask)
 
 
 __all__ = ["ELLGraph", "build_ell", "ell_from_coo", "fixed_row_capacity",
-           "bucketed_spmm", "ell_spmm", "lmc_compensate", "ell_aggregate_fn",
-           "default_interpret", "default_stream", "ref"]
+           "bucketed_spmm", "ell_spmm", "lmc_compensate", "default_interpret",
+           "ref"]

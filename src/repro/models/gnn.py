@@ -10,16 +10,14 @@ message-passing backward pass (Eq. 11-13) with per-layer ``jax.vjp``:
 
 ``aux`` carries the edge list (local COO: src, dst, weight), raw features and
 H^0 (for GCNII's initial-residual term). Aggregation is a weighted
-segment-sum — the jnp oracle of the Pallas SpMM kernel (kernels/ref.py). Two
-ways to put the kernel on the hot path: bind ``aggregate=ell_aggregate_fn(g)``
-at construction (full-graph use), or populate ``aux.ell`` with the batch's
-``ELLGraph`` — when present, layers aggregate through the differentiable
-``kernels.bucketed_spmm`` (its custom VJP runs the transposed-adjacency SpMM,
-so the LMC per-layer ``jax.vjp`` calls stay on the kernel; DESIGN.md §3).
-``make_train_step(..., backend="ell")`` selects the latter, and
-``backend="ti"`` reuses the identical ELL aggregation path — the backends
-differ only in how core/lmc.py compensates halo rows afterwards (store gather
-vs. message-invariant rescale), which this module never sees.
+segment-sum — the jnp oracle of the Pallas SpMM kernel (kernels/ref.py) —
+unless ``aux.ell`` carries the rows' ``ELLGraph``: layers then aggregate
+through the differentiable ``kernels.bucketed_spmm`` (its custom VJP runs
+the transposed-adjacency SpMM, so the LMC per-layer ``jax.vjp`` calls stay
+on the kernel; DESIGN.md §3). ``make_train_step(..., backend="ell")`` sets
+it, and ``backend="ti"`` reuses the identical ELL aggregation path — the
+backends differ only in how core/lmc.py compensates halo rows afterwards
+(store gather vs. message-invariant rescale), which this module never sees.
 
 Supported: GCN (Kipf & Welling 2017), GCNII (Chen et al. 2020), GraphSAGE
 (Hamilton et al. 2017), GIN (Xu et al. 2019) — the families used by the paper
@@ -28,7 +26,7 @@ and its baselines.
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Callable, NamedTuple, Optional
+from typing import Any, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -49,16 +47,12 @@ class LayerAux(NamedTuple):
     h0: jax.Array         # (N, d) initial embedding (GCNII); zeros otherwise
     self_w: jax.Array     # (N,) self-loop weight 1/(deg+1) for GCN-normalized agg
     ell: Optional[Any] = None  # kernels.ELLGraph: aggregate via bucketed_spmm
-    stream: Optional[bool] = None  # HBM→VMEM DMA gather knob (None: autodetect)
 
 
 def segment_spmm(edges: EdgeList, h: jax.Array, num_rows: int) -> jax.Array:
     """out[i] = Σ_{(j->i)} w_ji * h[j] — the reference aggregation."""
     msgs = h[edges.src] * edges.w[:, None]
     return jax.ops.segment_sum(msgs, edges.dst, num_segments=num_rows)
-
-
-AggregateFn = Callable[[EdgeList, jax.Array, int], jax.Array]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -72,7 +66,6 @@ class GNN:
     num_layers: int
     alpha: float = 0.1         # GCNII initial-residual strength
     lam: float = 0.5           # GCNII identity-map strength (beta_l = log(lam/l+1))
-    aggregate: AggregateFn = staticmethod(segment_spmm)
 
     # ------------------------------------------------------------------ params
     def init_params(self, rng: jax.Array) -> dict:
@@ -132,13 +125,13 @@ class GNN:
 
     def _aggregate(self, aux: LayerAux, h: jax.Array, n: int) -> jax.Array:
         """Route aggregation: Pallas ELL kernel when the batch carries an
-        ELLGraph (train-step ``backend="ell"``), else the bound AggregateFn.
-        Runs under the ``lmc.agg`` scope."""
+        ELLGraph (train-step ``backend="ell"``), else the segment sum over
+        the COO edges. Runs under the ``lmc.agg`` scope."""
         with jax.named_scope(tracing.AGG):
             if aux.ell is not None:
                 from repro.kernels import bucketed_spmm
-                return bucketed_spmm(aux.ell, h, stream=aux.stream)
-            return self.aggregate(aux.edges, h, n)
+                return bucketed_spmm(aux.ell, h)
+            return segment_spmm(aux.edges, h, n)
 
     def layer_apply(self, lp: dict, l: int, h_in: jax.Array, aux: LayerAux) -> jax.Array:
         """One message-passing layer over the local row set (batch + halo):
@@ -189,11 +182,9 @@ class GNN:
 
 
 def make_gnn(arch: str, feature_dim: int, hidden_dim: int, num_classes: int,
-             num_layers: int, aggregate: Optional[AggregateFn] = None,
-             **kw: Any) -> GNN:
-    agg = aggregate if aggregate is not None else segment_spmm
+             num_layers: int, **kw: Any) -> GNN:
     return GNN(arch=arch, feature_dim=feature_dim, hidden_dim=hidden_dim,
-               num_classes=num_classes, num_layers=num_layers, aggregate=agg, **kw)
+               num_classes=num_classes, num_layers=num_layers, **kw)
 
 
 def full_edge_list(indptr: np.ndarray, indices: np.ndarray,

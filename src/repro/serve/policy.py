@@ -66,7 +66,6 @@ class ServeConfig:
             "ell" — the bucketed Pallas SpMM); degradation swaps the
             *compensation*, never the aggregation, so both modes share the
             compiled trace.
-        stream: ell-backend streamed-DMA store gather (None = autodetect).
         ti_fwd_mode: Eq.-9 mode of the degraded path ("lmc" blends the α
             estimate with β·fresh — the PR 9 estimator; "historical" serves
             the raw α ⊙ fresh invariance transform).
@@ -91,7 +90,6 @@ class ServeConfig:
     breaker_heal_after: int = 3
     breaker_cooldown: int = 2
     backend: str = "segment"
-    stream: Optional[bool] = None
     ti_fwd_mode: str = "lmc"
     force_mode: Optional[str] = None
     return_logits: bool = False

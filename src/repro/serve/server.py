@@ -129,13 +129,13 @@ class GNNServer:
         self._steps = {
             MODE_EXACT: jax.jit(make_infer_step(
                 gnn, n, backend=cfg.backend, fwd_mode="historical",
-                compensation="store", refresh=True, stream=cfg.stream)),
+                compensation="store", refresh=True)),
             MODE_TI: jax.jit(make_infer_step(
                 gnn, n, backend=cfg.backend, fwd_mode=cfg.ti_fwd_mode,
-                compensation="ti", refresh=False, stream=cfg.stream)),
+                compensation="ti", refresh=False)),
             "repair": jax.jit(make_infer_step(
                 gnn, n, backend=cfg.backend, fwd_mode=cfg.ti_fwd_mode,
-                compensation="ti", refresh=True, stream=cfg.stream)),
+                compensation="ti", refresh=True)),
         }
 
         if cfg.warmup:

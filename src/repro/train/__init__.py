@@ -1,11 +1,9 @@
 from repro.train.elastic import rescale_lmc_state
-from repro.train.health import (FailureInjector, FaultPlan, HealthConfig,
-                                HealthGuard, PipelineFault,
-                                SimulatedPreemption, StalenessBudgetError,
-                                TrainingDivergedError)
+from repro.train.health import (FaultPlan, HealthConfig, HealthGuard,
+                                PipelineFault, SimulatedPreemption,
+                                StalenessBudgetError, TrainingDivergedError)
 from repro.train.loop import GNNTrainer
 
-__all__ = ["GNNTrainer", "FailureInjector", "FaultPlan", "HealthConfig",
-           "HealthGuard", "PipelineFault", "SimulatedPreemption",
-           "StalenessBudgetError", "TrainingDivergedError",
-           "rescale_lmc_state"]
+__all__ = ["GNNTrainer", "FaultPlan", "HealthConfig", "HealthGuard",
+           "PipelineFault", "SimulatedPreemption", "StalenessBudgetError",
+           "TrainingDivergedError", "rescale_lmc_state"]

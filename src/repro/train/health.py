@@ -12,10 +12,10 @@ theorem's geometric bias term assumes. Two pieces live here:
   retries and optional lr-backoff, or skip-batch) is executed by
   ``GNNTrainer.run``, which is where the checkpoint and the pipeline live.
 
-* :class:`FaultPlan` — the layered fault-injection framework generalizing
-  the old single-class ``FailureInjector``. One plan schedules any mix of
-  fault classes, each firing exactly once (so a recovered retry of the same
-  step/slot is clean, keeping the post-recovery stream deterministic):
+* :class:`FaultPlan` — the layered fault-injection framework. One plan
+  schedules any mix of fault classes, each firing exactly once (so a
+  recovered retry of the same step/slot is clean, keeping the
+  post-recovery stream deterministic):
 
     preemption   — raises :class:`SimulatedPreemption` at step start
                    (crash/SIGTERM; recovery = restore latest checkpoint);
@@ -54,14 +54,14 @@ from repro.core.methods import RHO_BUDGET_DEFAULT
 __all__ = [
     "RHO_BUDGET_DEFAULT", "SimulatedPreemption", "PipelineFault",
     "CheckpointWriteFault", "TrainingDivergedError", "StalenessBudgetError",
-    "ServeWorkerFault", "FaultPlan", "FailureInjector", "HealthConfig",
+    "ServeWorkerFault", "FaultPlan", "HealthConfig",
     "HealthGuard",
 ]
 
 
 # ----------------------------------------------------------------- fault types
 class SimulatedPreemption(RuntimeError):
-    """Injected crash/preemption (the old FailureInjector's fault class)."""
+    """Injected crash/preemption (``FaultPlan(preempt_at=...)``)."""
 
 
 class PipelineFault(RuntimeError):
@@ -209,14 +209,6 @@ class FaultPlan:
         overflow with typed Overloaded responses."""
         return self.serve_burst_n if self._fire("serve-burst", request_idx) \
             else 0
-
-
-class FailureInjector(FaultPlan):
-    """Back-compat shim: the original preemption-only injector."""
-
-    def __init__(self, fail_at_steps: tuple = ()):
-        """Schedule preemptions at the given global step indices."""
-        super().__init__(preempt_at=fail_at_steps)
 
 
 # ---------------------------------------------------------------- HealthGuard

@@ -3,8 +3,8 @@
 Production behaviors implemented (tests: test_fault_tolerance.py,
 test_supervisor.py):
   * periodic atomic checkpoints of (params, opt state, historical stores,
-    sampler RNG state, lr, step counter) — synchronous or, with
-    ``async_ckpt=True``, written on a background thread off the hot path;
+    lr, step counter) — synchronous or, with ``async_ckpt=True``, written
+    on a background thread off the hot path;
   * crash/preemption recovery: on failure the loop restores the newest
     *verifiable* checkpoint and continues (a corrupt/truncated latest
     checkpoint falls back to the previous one — checkpoint.CheckpointError);
@@ -22,7 +22,8 @@ test_supervisor.py):
     a straggler step's *store updates* can be dropped without violating LMC's
     convergence assumptions (staleness is bounded by Thm 2's ρ-term — see
     DESIGN.md §4), which is what `straggler_policy="skip-store"` does;
-  * deterministic resume: the sampler's bit-generator state rides along.
+  * deterministic resume: the batch stream is a pure function of (sampler
+    seed, step index), so a restore rebuilds the pipeline at its step.
 
 ``backend="ell"`` switches the jit'd step onto the Pallas bucketed-ELL
 SpMM/compensate kernels (compiled on TPU, interpreter fallback on CPU);
@@ -32,14 +33,11 @@ aggregation but compensates halo rows with the store-free message-invariance
 estimator (DESIGN.md §11) — pair it with ``method=repro.core.TI`` so the
 (unread) store refresh is skipped too.
 
-``prefetch``/``recycle`` route batch construction through the async
-``SubgraphPipeline`` (repro.data.prefetch, DESIGN.md §9): sampling + ELL
-bucketing move to background threads, host→device transfers double-buffer
-behind the step, and each subgraph can be recycled for ρ consecutive steps.
-The pipeline stream is a pure function of (sampler seed, step index), so
-checkpoint resume stays deterministic — the pipeline is simply rebuilt at
-the restored step. The default (``prefetch=None, recycle=1``) keeps the
-legacy synchronous, stateful-RNG path byte-for-byte.
+Every batch comes from the ``SubgraphPipeline`` (repro.data.prefetch,
+DESIGN.md §9). With ``prefetch >= 1`` sampling + ELL bucketing move to
+background threads and host→device transfers double-buffer behind the
+step; the default ``prefetch=0`` builds each batch inline, on the same
+schedule. ``recycle=ρ`` reuses each subgraph for ρ consecutive steps.
 """
 from __future__ import annotations
 
@@ -53,16 +51,15 @@ import numpy as np
 
 from repro.checkpoint import CheckpointError, CheckpointManager
 from repro.core import (HistoricalState, MBMethod, from_graph, accuracy,
-                        batch_fills, host_batch, init_history,
-                        make_train_step)
+                        init_history, make_train_step)
 from repro.data.prefetch import NO_FETCH, SubgraphPipeline
 from repro.graph import ClusterSampler
 from repro.models.gnn import GNN
 from repro.optim.optimizers import Optimizer
 from repro.tracing import span
-from repro.train.health import (FailureInjector, FaultPlan, HealthConfig,
-                                HealthGuard, PipelineFault,
-                                SimulatedPreemption, TrainingDivergedError)
+from repro.train.health import (FaultPlan, HealthConfig, HealthGuard,
+                                PipelineFault, SimulatedPreemption,
+                                TrainingDivergedError)
 
 # running-median straggler baseline: bounded so the median scan stays O(1)
 # in run length (satellite of DESIGN.md §10; was an unbounded list)
@@ -93,8 +90,7 @@ class GNNTrainer:
                  straggler_deadline: float = 4.0,
                  straggler_policy: str = "skip-store",
                  backend: str = "segment",
-                 stream: Optional[bool] = None,
-                 prefetch: Optional[int] = None,
+                 prefetch: int = 0,
                  recycle: int = 1,
                  pipeline_workers: int = 2,
                  pipeline_mode: str = "uniform"):
@@ -108,8 +104,7 @@ class GNNTrainer:
             seed: parameter-init PRNG seed.
             failure_injector: a ``train.health.FaultPlan`` scheduling any
                 mix of injected faults (preemptions, pipeline-worker
-                crashes, mid-save checkpoint failures, NaN batches); the
-                legacy ``FailureInjector`` is a preemption-only FaultPlan.
+                crashes, mid-save checkpoint failures, NaN batches).
             health: enable the numerical-health guard with this config
                 (``HealthConfig()`` for defaults); None disables all
                 health checks (the pre-supervisor hot path).
@@ -125,15 +120,12 @@ class GNNTrainer:
                 drops a straggler step's store update (Thm 2-safe).
             backend: aggregation/compensation hot path, ``"segment"`` |
                 ``"ell"`` | ``"ti"`` (store-free message invariance).
-            stream: HBM→VMEM DMA gather knob for the ell kernels
-                (None = autodetect).
-            prefetch: queue depth of the async batch pipeline. ``None``
-                (default) keeps the legacy synchronous stateful-RNG path;
-                ``0`` uses the pipeline's schedule-indexed stream but builds
-                synchronously (debugging / equality tests); ``>= 1`` builds
-                ahead on background threads with double-buffered transfers.
+            prefetch: queue depth of the batch pipeline. ``0`` (default)
+                builds each batch inline, in the step's fetch; ``>= 1``
+                builds ahead on background threads with double-buffered
+                transfers. Both draw the same schedule-indexed stream.
             recycle: reuse each sampled subgraph for this many consecutive
-                steps (ρ; implies the pipeline path when > 1).
+                steps (ρ).
             pipeline_workers: builder threads when prefetching.
             pipeline_mode: schedule of the pipeline path — ``"uniform"``
                 (iid cluster draws, Alg. 1 line 4) or ``"epoch"`` (shuffled
@@ -142,6 +134,8 @@ class GNNTrainer:
         self.gnn = gnn
         self.method = method
         self.graph = graph
+        # built lazily so it always starts at the current step (resume-safe)
+        self._pipeline: Optional[SubgraphPipeline] = None
         self.sampler = sampler
         self.opt = optimizer
         self.data = from_graph(graph)
@@ -149,19 +143,14 @@ class GNNTrainer:
         self.straggler_deadline = straggler_deadline
         self.straggler_policy = straggler_policy
         self.backend = backend  # hot path: "segment" | "ell" | "ti"
-        self.stream = stream    # HBM→VMEM DMA gather knob (None: autodetect)
         if recycle < 1:
             raise ValueError(f"recycle must be >= 1, got {recycle}")
         if max_retries < 1:
             raise ValueError(f"max_retries must be >= 1, got {max_retries}")
-        self.prefetch = prefetch
+        self.prefetch = int(prefetch)
         self.recycle = int(recycle)
         self.pipeline_workers = int(pipeline_workers)
         self.pipeline_mode = pipeline_mode
-        # pipeline path whenever asked for (prefetch set) or needed (ρ > 1);
-        # built lazily so it always starts at the current step (resume-safe)
-        self._use_pipeline = prefetch is not None or self.recycle > 1
-        self._pipeline: Optional[SubgraphPipeline] = None
 
         self.params = gnn.init_params(jax.random.key(seed))
         self.opt_state = optimizer.init(self.params, _as_pspec_tree(self.params))
@@ -172,12 +161,12 @@ class GNNTrainer:
         # no buffer donation: the straggler skip-store policy, health
         # rollback and elastic rescale all need the pre-step state alive
         self._step = jax.jit(make_train_step(gnn, method, graph.num_nodes,
-                                             backend=backend, stream=stream))
+                                             backend=backend))
         # lr rides as a traced array argument so backoff never retraces
         self._update = jax.jit(
             lambda g, s, p, lr: optimizer.update(g, s, p, lr))
         fault_hook = (failure_injector.ckpt_hook
-                      if isinstance(failure_injector, FaultPlan) else None)
+                      if failure_injector is not None else None)
         self.ckpt = (CheckpointManager(ckpt_dir, fault_hook=fault_hook)
                      if ckpt_dir else None)
         self.ckpt_every = ckpt_every
@@ -191,12 +180,24 @@ class GNNTrainer:
         self.history: list[dict] = []
 
     # ----------------------------------------------------------------- state
+    @property
+    def sampler(self) -> ClusterSampler:
+        """The cluster sampler the batch pipeline draws from. Assigning a
+        new one (``rescale_lmc_state``'s) rebuilds the pipeline at the
+        current step."""
+        return self._sampler
+
+    @sampler.setter
+    def sampler(self, sampler: ClusterSampler) -> None:
+        self._reset_pipeline()
+        self._sampler = sampler
+
     def _state_tree(self):
         return {"params": self.params, "opt": self.opt_state,
                 "store": tuple(self.store)}
 
     def save(self) -> None:
-        """Write an atomic checkpoint (params/opt/stores/sampler RNG/lr/step).
+        """Write an atomic checkpoint (params/opt/stores/lr/step).
 
         With ``async_ckpt`` the write happens on the manager's background
         thread; this call only pays the device→host snapshot. A failed
@@ -206,8 +207,7 @@ class GNNTrainer:
         """
         if self.ckpt is None:
             return
-        extras = {"step": self.step_num, "lr": self.lr,
-                  "sampler": _jsonable(self.sampler.state_dict())}
+        extras = {"step": self.step_num, "lr": self.lr}
         self.ckpt.save(self.step_num, self._state_tree(), extras,
                        background=self.async_ckpt)
 
@@ -215,7 +215,8 @@ class GNNTrainer:
         """Restore the newest verifiable checkpoint; False when none exists.
 
         Corrupt/truncated checkpoints are skipped (checkpoint.manager walks
-        newest-first with per-leaf checksum verification). Also discards any
+        newest-first with per-leaf checksum verification); a ``"sampler"``
+        entry in an older checkpoint's extras is ignored. Also discards any
         in-flight batch pipeline: the stream is a pure function of the step
         index, so rebuilding it at the restored step replays exactly the
         batches the uninterrupted run would have seen.
@@ -234,7 +235,6 @@ class GNNTrainer:
         self.store = HistoricalState(*tree["store"])
         self.step_num = extras["step"]
         self.lr = float(extras.get("lr", self.lr))
-        self.sampler.load_state_dict(_from_jsonable(extras["sampler"]))
         if self.guard is not None:
             # counters don't ride the checkpoint: restart conservative (all
             # rows fresh-at-restore; true staleness is ≤ checkpoint interval)
@@ -247,10 +247,9 @@ class GNNTrainer:
         """The async batch source, (re)built lazily at the current step."""
         if self._pipeline is None:
             hook = (self.failure_injector.pipeline_hook
-                    if isinstance(self.failure_injector, FaultPlan) else None)
+                    if self.failure_injector is not None else None)
             self._pipeline = SubgraphPipeline(
-                self.sampler, backend=self.backend,
-                depth=self.prefetch if self.prefetch is not None else 0,
+                self.sampler, backend=self.backend, depth=self.prefetch,
                 workers=self.pipeline_workers, recycle=self.recycle,
                 mode=self.pipeline_mode, start_step=self.step_num,
                 build_hook=hook)
@@ -355,7 +354,7 @@ class GNNTrainer:
                 return
             # nothing verifiable to roll back to: degrade to skip-batch
         # skip-batch: the poisoned update was never applied; advance past
-        # the consumed batch (legacy path: the sampler RNG already moved)
+        # the consumed batch (the pipeline already moved to the next slot)
         self.step_num += 1
         if self.guard is not None:
             # the store kept its old rows — every row ages one step
@@ -375,21 +374,11 @@ class GNNTrainer:
         t0 = time.perf_counter()
         parts = dict(NO_FETCH)
         with span("train.fetch", parts):
-            if self._use_pipeline:
-                batch = next(self._batch_pipeline())  # may raise PipelineFault
-                parts.update(self._pipeline.last_fetch)
-            else:
-                with span("pipeline.build", parts):
-                    sg = self.sampler.sample()
-                    hb = host_batch(sg, backend=self.backend, record=parts)
-                parts.update(batch_fills(sg, hb))
-                with span("pipeline.h2d", parts):
-                    batch = jax.device_put(hb)
+            batch = next(self._batch_pipeline())  # may raise PipelineFault
+            parts.update(self._pipeline.last_fetch)
         if self.failure_injector is not None:
             self.failure_injector.maybe_fail(self.step_num)
-            if isinstance(self.failure_injector, FaultPlan):
-                batch = self.failure_injector.corrupt_batch(self.step_num,
-                                                            batch)
+            batch = self.failure_injector.corrupt_batch(self.step_num, batch)
         with span("train.dispatch", parts):
             loss, grads, new_store, metrics = self._step(
                 self.params, self.store, batch, self.data.x, self.data.self_w)
@@ -455,30 +444,3 @@ def _as_pspec_tree(params):
     return jax.tree.map(
         lambda p: PSpec(tuple(p.shape), (None,) * p.ndim, dtype=p.dtype),
         params)
-
-
-def _jsonable(state: dict):
-    import json
-    return json.loads(json.dumps(state, default=_np_default))
-
-
-def _np_default(o):
-    if isinstance(o, np.integer):
-        return int(o)
-    if isinstance(o, np.floating):
-        return float(o)
-    if isinstance(o, np.ndarray):
-        return {"__nd__": o.tolist(), "dtype": str(o.dtype)}
-    raise TypeError(type(o))
-
-
-def _from_jsonable(state):
-    def conv(x):
-        if isinstance(x, dict):
-            if "__nd__" in x:
-                return np.asarray(x["__nd__"], dtype=x["dtype"])
-            return {k: conv(v) for k, v in x.items()}
-        if isinstance(x, list):
-            return [conv(v) for v in x]
-        return x
-    return conv(state)

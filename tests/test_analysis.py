@@ -291,6 +291,29 @@ def test_r003_resolves_param_defaults_and_dtypes():
     assert "16.0 MiB" in f.message
 
 
+VMEM_IMPORTED_LANES = """
+    from jax.experimental import pallas as pl
+    from pkg.consts import LANES, WIDTH
+    def f():
+        return pl.BlockSpec((256, LANES), lambda i: (i, 0))
+"""
+
+
+def test_r003_resolves_imported_module_constants(tmp_path):
+    """An int literal imported by name from a module of the source tree
+    bounds a block; one that is not a literal there stays runtime-valued."""
+    (tmp_path / "pkg").mkdir()
+    (tmp_path / "pkg" / "consts.py").write_text(
+        "LANES = 128\nWIDTH = LANES * 2\n")
+    path = str(tmp_path / "pkg" / "kernel.py")
+    assert live(VMEM_IMPORTED_LANES, "R003", path=path) == []
+    (f,) = live(VMEM_IMPORTED_LANES.replace("(256, LANES)", "(256, WIDTH)"),
+                "R003", path=path)
+    assert "runtime-valued" in f.message
+    (f,) = live(VMEM_IMPORTED_LANES, "R003")   # no tree to look it up in
+    assert "runtime-valued" in f.message
+
+
 def test_r003_aggregate_budget():
     (f,) = live(VMEM_AGGREGATE, "R003")
     assert "sum to 16.0 MiB" in f.message

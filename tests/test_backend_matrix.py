@@ -4,8 +4,9 @@ The three aggregation/compensation backends of ``make_train_step`` must be
 interchangeable gradient estimators:
 
   * full-batch exactness — with the whole graph as one batch there is nothing
-    to compensate, so every (backend, fwd_mode, bwd_mode, stream) combination
-    must reduce to ``jax.grad`` exactly;
+    to compensate, so every (backend, fwd_mode, bwd_mode) combination must
+    reduce to ``jax.grad`` exactly, at one 128-lane block and (on the kernel
+    backends) at two;
   * Fig. 3 bias ordering — the store-free message-invariance estimator
     (backend="ti", DESIGN.md §11) must land in LMC's bias regime and beat
     Cluster-GCN's dropped-halo estimate against the exact backward-SGD oracle;
@@ -51,43 +52,53 @@ def tiny_graph():
 
 @pytest.fixture(scope="module")
 def tiny_setup(tiny_graph):
+    """The whole graph as one batch, and per hidden width a GCN, its params
+    and the ``full_grads`` reference (built on first use)."""
     g = tiny_graph
     data = from_graph(g)
-    gnn = make_gnn("gcn", g.feature_dim, 16, g.num_classes, 2)
-    params = gnn.init_params(jax.random.key(0))
-    loss_ref, grads_ref = full_grads(gnn, params, data)
     s = ClusterSampler(g, 1, 1, parts=np.zeros(g.num_nodes, np.int32))
     sg = s.sample()
     assert sg.n_halo_real == 0
     batches = {b: to_device_batch(sg, backend=b) for b in AGG_BACKENDS}
-    return g, data, gnn, params, float(loss_ref), grads_ref, batches
+    models = {}
+
+    def at_width(hidden):
+        if hidden not in models:
+            gnn = make_gnn("gcn", g.feature_dim, hidden, g.num_classes, 2)
+            params = gnn.init_params(jax.random.key(0))
+            loss_ref, grads_ref = full_grads(gnn, params, data)
+            models[hidden] = gnn, params, float(loss_ref), grads_ref
+        return models[hidden]
+
+    return g, data, at_width, batches
 
 
 # --------------------------------------------- (a) full-batch == jax.grad
-_STREAMS = {"segment": [None], "ell": [None, False], "ti": [None, False]}
-_MATRIX = [(bk, f, b, st)
+# 256 lanes: every kernel runs over two 128-lane blocks
+_WIDTHS = {"segment": [16], "ell": [16, 256], "ti": [16, 256]}
+_MATRIX = [(bk, f, b, d)
            for bk in AGG_BACKENDS
            for f in ("lmc", "historical", "fresh", "none")
            for b in ("lmc", "none", "fresh")
-           for st in _STREAMS[bk]]
+           for d in _WIDTHS[bk]]
 
 
 @pytest.mark.parametrize(
-    "backend,fwd_mode,bwd_mode,stream", _MATRIX,
-    ids=[f"{bk}-f_{f}-b_{b}-s_{st}" for bk, f, b, st in _MATRIX])
+    "backend,fwd_mode,bwd_mode,hidden", _MATRIX,
+    ids=[f"{bk}-f_{f}-b_{b}-d_{d}" for bk, f, b, d in _MATRIX])
 def test_full_batch_matrix_reduces_to_autodiff(backend, fwd_mode, bwd_mode,
-                                               stream, tiny_setup):
+                                               hidden, tiny_setup):
     """Whole graph in one batch => no halo => every combination is exact.
 
     Steps run unjitted: the 60-combination product would otherwise pay one
     XLA compilation each for identical numerics.
     """
-    g, data, gnn, params, loss_ref, grads_ref, batches = tiny_setup
+    g, data, at_width, batches = tiny_setup
+    gnn, params, loss_ref, grads_ref = at_width(hidden)
     m = MBMethod("matrix", fwd_mode=fwd_mode, bwd_mode=bwd_mode,
                  store_writes=(backend != "ti"))
-    step = make_train_step(gnn, m, g.num_nodes, backend=backend,
-                           stream=stream)
-    store = init_history(gnn.num_layers, g.num_nodes, 16)
+    step = make_train_step(gnn, m, g.num_nodes, backend=backend)
+    store = init_history(gnn.num_layers, g.num_nodes, hidden)
     loss, grads, _, _ = step(params, store, batches[backend], data.x,
                              data.self_w)
     assert abs(float(loss) - loss_ref) < 1e-5

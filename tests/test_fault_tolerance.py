@@ -7,7 +7,7 @@ from repro.data import TokenStream
 from repro.graph import ClusterSampler
 from repro.models import make_gnn
 from repro.optim import sgd
-from repro.train import FailureInjector, GNNTrainer, rescale_lmc_state
+from repro.train import FaultPlan, GNNTrainer, rescale_lmc_state
 
 
 def _trainer(g, parts, tmp, **kw):
@@ -29,7 +29,7 @@ def test_checkpoint_roundtrip(tmp_path):
 
 
 def test_preemption_recovery(small_graph, small_parts, tmp_path):
-    inj = FailureInjector(fail_at_steps=(25,))
+    inj = FaultPlan(preempt_at=(25,))
     tr = _trainer(small_graph, small_parts, str(tmp_path),
                   failure_injector=inj)
     hist = tr.run(50)
@@ -41,7 +41,7 @@ def test_preemption_recovery(small_graph, small_parts, tmp_path):
 
 
 def test_resume_is_deterministic(small_graph, small_parts, tmp_path):
-    """Restore + continue == uninterrupted run (same sampler state)."""
+    """Restore + continue == uninterrupted run (same batch schedule)."""
     t1 = _trainer(small_graph, small_parts, str(tmp_path / "a"))
     t1.run(20)
     t1.save()
@@ -70,6 +70,42 @@ def test_elastic_rescale(small_graph, small_parts, tmp_path):
     assert np.isfinite([h["loss"] for h in hist if "loss" in h][-1])
 
 
+def test_rescale_rebuilds_the_prefetch_pipeline(small_graph, small_parts,
+                                                tmp_path):
+    """A prefetching trainer whose sampler is replaced draws its next batch
+    from the new partition, not from batches built ahead from the old."""
+    tr = _trainer(small_graph, small_parts, str(tmp_path), prefetch=2)
+    try:
+        tr.run(3)
+        sampler2, tr.store = rescale_lmc_state(
+            small_graph, tr.store, old_num_parts=16, new_num_parts=8, seed=1)
+        tr.sampler = sampler2
+        want = sampler2.build_batch(
+            sampler2.clusters_at(tr.step_num, mode=tr.pipeline_mode))
+        batch = next(tr._batch_pipeline())
+        np.testing.assert_array_equal(np.asarray(batch.batch_gids),
+                                      want.batch_gids)
+        np.testing.assert_array_equal(np.asarray(batch.halo_gids),
+                                      want.halo_gids)
+    finally:
+        tr.close()
+
+
+def test_restore_ignores_a_saved_sampler_state(small_graph, small_parts,
+                                               tmp_path):
+    """Checkpoints once carried the sampler's RNG state under "sampler";
+    restore still reads them, and ignores the entry."""
+    t1 = _trainer(small_graph, small_parts, str(tmp_path))
+    t1.run(3)
+    t1.ckpt.save(t1.step_num, t1._state_tree(),
+                 {"step": t1.step_num, "lr": t1.lr,
+                  "sampler": {"bit_generator": {"state": {"state": 1}}}})
+    t2 = _trainer(small_graph, small_parts, str(tmp_path))
+    assert t2.restore() and t2.step_num == 3
+    t2.run(1)
+    assert np.isfinite(t2.history[-1]["loss"])
+
+
 def test_token_stream_resume():
     a = TokenStream(1000, 4, 32, seed=7)
     batches = [next(a) for _ in range(5)]
@@ -84,6 +120,8 @@ def test_straggler_skip_store(small_graph, small_parts, tmp_path):
                   straggler_deadline=0.0)  # every step after warmup is late
     hist = tr.run(15)
     assert any(h.get("straggler") for h in hist if "loss" in h)
-    # training still progresses (store updates skipped, not the params)
+    # training still progresses (store updates skipped, not the params);
+    # one batch's loss varies up to 2x with its clusters, so compare the
+    # means of the first and last five steps
     losses = [h["loss"] for h in hist if "loss" in h]
-    assert losses[-1] < losses[0]
+    assert np.mean(losses[-5:]) < np.mean(losses[:5])

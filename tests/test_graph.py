@@ -83,32 +83,6 @@ def test_epoch_schedule_covers_under_shuffled_queries(seed, qseed, small_graph,
                                       s.clusters_at(i, mode="epoch"))
 
 
-def test_sampler_state_roundtrip_mid_epoch(small_graph, small_parts):
-    """state_dict/load_state_dict restore the stateful RNG mid-epoch: a fresh
-    sampler loaded with the saved state replays the identical remainder of
-    the stream (both sample() draws and stochastic epoch() grouping)."""
-    a = ClusterSampler(small_graph, 16, 2, parts=small_parts, seed=7,
-                       stochastic=True)
-    for _ in range(3):                   # advance into the stream
-        a.sample()
-    it = a.epoch()
-    next(it)                             # consume part of an epoch
-    saved = a.state_dict()
-
-    b = ClusterSampler(small_graph, 16, 2, parts=small_parts, seed=0,
-                       stochastic=True)  # different seed: state must win
-    b.load_state_dict(saved)
-    for _ in range(4):
-        sa, sb = a.sample(), b.sample()
-        np.testing.assert_array_equal(sa.batch_gids, sb.batch_gids)
-        np.testing.assert_array_equal(sa.halo_gids, sb.halo_gids)
-        np.testing.assert_array_equal(sa.edge_src, sb.edge_src)
-    ea = [sg.batch_gids[sg.batch_mask > 0] for sg in a.epoch()]
-    eb = [sg.batch_gids[sg.batch_mask > 0] for sg in b.epoch()]
-    for xa, xb in zip(ea, eb):
-        np.testing.assert_array_equal(xa, xb)
-
-
 @given(score=st.sampled_from(["x2", "2x-x2", "x", "1", "sin"]),
        alpha=st.floats(0.0, 1.0))
 def test_beta_scores_in_unit_interval(score, alpha):

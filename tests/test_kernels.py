@@ -7,8 +7,8 @@ import pytest
 from _prop import given, settings, strategies as st
 
 from repro.kernels import (ELLCapacityError, ELLGraph, build_ell,
-                           bucketed_spmm, default_interpret, ell_aggregate_fn,
-                           ell_from_coo, ell_spmm, lmc_compensate)
+                           bucketed_spmm, default_interpret, ell_from_coo,
+                           ell_spmm, lmc_compensate)
 from repro.kernels.ops import _build_ell_loop, fixed_row_capacity
 from repro.kernels.ref import (degree_bucket_spmm_ref, ell_spmm_ref,
                                lmc_compensate_ref)
@@ -81,13 +81,14 @@ def test_lmc_compensate_matches_ref(d, seed, beta_max):
                                rtol=1e-6, atol=1e-6)
 
 
-@pytest.mark.parametrize("stream", [True, False], ids=["streamed", "resident"])
-def test_lmc_compensate_across_id_blocks_and_feature_tiles(stream):
-    """Six row tiles whose ids span two 1024-word SMEM blocks, times two
-    feature tiles: the streamed kernel's next-tile DMA crosses both a
-    feature-tile and an id-block boundary."""
+@pytest.mark.parametrize("d", [256, 128], ids=["streamed", "d128"])
+def test_lmc_compensate_across_id_blocks_and_feature_tiles(d):
+    """Six row tiles whose ids span two 1024-word SMEM blocks: at d = 256
+    (two feature tiles) the kernel's next-tile DMA crosses both a
+    feature-tile and an id-block boundary; at d = 128 (one lane block)
+    every next-tile DMA starts a new row tile."""
     rng = np.random.default_rng(0)
-    n, m, d = 1300, 700, 256
+    n, m = 1300, 700
     args = [jnp.asarray(a) for a in (
         rng.normal(size=(m, d)).astype(np.float32),
         rng.integers(0, m, n).astype(np.int32),
@@ -95,7 +96,7 @@ def test_lmc_compensate_across_id_blocks_and_feature_tiles(stream):
         rng.normal(size=(n, d)).astype(np.float32),
         (rng.random(n) > 0.2).astype(np.float32))]
     np.testing.assert_allclose(
-        np.asarray(lmc_compensate(*args, stream=stream)),
+        np.asarray(lmc_compensate(*args)),
         np.asarray(lmc_compensate_ref(*args)), rtol=1e-6, atol=1e-6)
 
 
@@ -512,20 +513,34 @@ def test_ell_spmm_wide_bucket_sweep():
 
 
 def test_gnn_forward_with_kernel_aggregate(small_graph):
-    """Swapping the jnp aggregation for the Pallas kernel is output-identical."""
+    """``aux.ell`` set to the whole graph's ELL routes every layer's
+    aggregation through the Pallas kernel, output-identical to the
+    segment sum over the COO edges (``aux.ell=None``), for GCN and GCNII."""
     from repro.core import from_graph
     from repro.models import make_gnn
+    from repro.models.gnn import LayerAux
     g = small_graph
     data = from_graph(g)
     row = np.repeat(np.arange(g.num_nodes), np.diff(g.indptr))
     ws = g.gcn_edge_weights(g.indices.astype(np.int64), row)
     ell = build_ell(g.indptr, g.indices, ws)
-
-    gnn_ref = make_gnn("gcn", g.feature_dim, 32, g.num_classes, 2)
-    gnn_krn = make_gnn("gcn", g.feature_dim, 32, g.num_classes, 2,
-                       aggregate=ell_aggregate_fn(ell))
-    params = gnn_ref.init_params(jax.random.key(0))
-    out_ref = gnn_ref.full_forward(params, data.x, data.edges, data.self_w)
-    out_krn = gnn_krn.full_forward(params, data.x, data.edges, data.self_w)
-    np.testing.assert_allclose(np.asarray(out_krn), np.asarray(out_ref),
-                               rtol=2e-3, atol=2e-3)
+    for arch in ("gcn", "gcnii"):
+        gnn = make_gnn(arch, g.feature_dim, 32, g.num_classes, 2)
+        params = gnn.init_params(jax.random.key(0))
+        h0 = gnn.embed_apply(params["embed"], data.x)
+        aux_seg = LayerAux(edges=data.edges, x=data.x, h0=h0,
+                           self_w=data.self_w)
+        aux_krn = aux_seg._replace(ell=ell)
+        lp0 = gnn.layer_params(params, 0)
+        for aux, kernel in ((aux_seg, False), (aux_krn, True)):
+            jaxpr = jax.make_jaxpr(
+                lambda h, a=aux: gnn.layer_apply(lp0, 0, h, a))(h0)
+            assert ("pallas_call" in str(jaxpr)) == kernel, arch
+        h_seg = h_krn = h0
+        for l in range(gnn.num_layers):
+            lp = gnn.layer_params(params, l)
+            h_seg = gnn.layer_apply(lp, l, h_seg, aux_seg)
+            h_krn = gnn.layer_apply(lp, l, h_krn, aux_krn)
+            np.testing.assert_allclose(np.asarray(h_krn), np.asarray(h_seg),
+                                       rtol=2e-3, atol=2e-3,
+                                       err_msg=f"{arch} layer {l}")

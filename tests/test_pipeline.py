@@ -180,12 +180,14 @@ def _make_trainer(graph, parts, **kw):
     return GNNTrainer(gnn, LMC, graph, s, sgd(lr=0.2), seed=0, **kw)
 
 
-def test_trainer_prefetch_matches_sync(small_graph, small_parts):
+@pytest.mark.parametrize("backend", ["segment", "ell", "ti"])
+def test_trainer_prefetch_matches_sync(small_graph, small_parts, backend):
     """GNNTrainer(prefetch=2) must produce the identical loss trajectory to
-    prefetch=0 (same schedule, synchronous builds)."""
-    ta = _make_trainer(small_graph, small_parts, prefetch=0)
+    prefetch=0 (same schedule, synchronous builds), on every backend."""
+    ta = _make_trainer(small_graph, small_parts, prefetch=0, backend=backend)
     ta.run(6)
-    tb = _make_trainer(small_graph, small_parts, prefetch=2)
+    ta.close()
+    tb = _make_trainer(small_graph, small_parts, prefetch=2, backend=backend)
     tb.run(6)
     tb.close()
     la = [h["loss"] for h in ta.history]

@@ -117,9 +117,10 @@ def test_matrix_nan_batch_rollback(small_graph, small_parts, tmp_path,
     ref = clean_runs["sync"]
     np.testing.assert_allclose([ref[s] for s in sorted(ref)],
                                [got[s] for s in sorted(got)], rtol=1e-6)
-    # and the run still converges
+    # and the run still converges: one batch's loss varies up to 2x with
+    # its clusters, so compare the means of the first and last ten steps
     losses = [h["loss"] for h in tr.history if "loss" in h]
-    assert losses[-1] < losses[0]
+    assert np.mean(losses[-10:]) < np.mean(losses[:10])
 
 
 # ------------------------------------------------------- health policies

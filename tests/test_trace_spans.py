@@ -12,6 +12,11 @@ STEP_FIELDS = ("fetch_s", "wait_s", "h2d_s", "dispatch_s", "sync_s",
                "staged", "build_s", "edge_fill", "ell_s", "ell_fill")
 
 
+# (prefetch, recycle): inline builds, builds ahead on threads, and builds
+# ahead with each batch consumed by two steps
+_FETCHES = [(0, 1), (2, 1), (2, 2)]
+
+
 def _sampler(graph, parts):
     return ClusterSampler(graph, 16, 2, parts=parts, seed=1)
 
@@ -83,11 +88,12 @@ def test_recycled_step_fetches_nothing(small_graph, small_parts):
                       "ell_fill": first["ell_fill"]}
 
 
-@pytest.mark.parametrize("prefetch", [None, 0, 2],
-                         ids=["legacy", "sync", "prefetch"])
+@pytest.mark.parametrize("prefetch,recycle", _FETCHES,
+                         ids=["sync", "prefetch", "recycle2"])
 def test_record_parts_sum_within_the_step(small_graph, small_parts,
-                                          prefetch):
-    tr = _trainer(small_graph, small_parts, prefetch=prefetch)
+                                          prefetch, recycle):
+    tr = _trainer(small_graph, small_parts, prefetch=prefetch,
+                  recycle=recycle)
     try:
         tr.run(4)
     finally:
@@ -100,31 +106,35 @@ def test_record_parts_sum_within_the_step(small_graph, small_parts,
         assert 0.0 < parts <= rec["time_s"]
         assert rec["wait_s"] + rec["h2d_s"] <= rec["fetch_s"]
         assert rec["dispatch_s"] > 0.0 and rec["sync_s"] > 0.0
-    if prefetch != 2:
+    if prefetch == 0:
         # built and copied in the step itself, so inside its fetch
         for rec in recs:
             assert 0.0 < rec["build_s"] <= rec["fetch_s"]
             assert rec["h2d_s"] > 0.0
             assert rec["staged"] is False and rec["wait_s"] == 0.0
+    if recycle > 1:
+        # a recycled slot's step reuses the batch: nothing waited for,
+        # built or copied
+        for rec in recs[1::recycle]:
+            assert rec["wait_s"] == rec["h2d_s"] == rec["build_s"] == 0.0
+            assert rec["staged"] is False
 
 
-@pytest.mark.parametrize("prefetch", [None, 0, 2],
-                         ids=["legacy", "sync", "prefetch"])
+@pytest.mark.parametrize("prefetch,recycle", _FETCHES,
+                         ids=["sync", "prefetch", "recycle2"])
 def test_record_carries_the_consumed_batch_edge_fill(small_graph, small_parts,
-                                                     prefetch):
-    tr = _trainer(small_graph, small_parts, prefetch=prefetch)
+                                                     prefetch, recycle):
+    tr = _trainer(small_graph, small_parts, prefetch=prefetch,
+                  recycle=recycle)
     try:
         tr.run(3)
     finally:
         tr.close()
     # the batches the trainer consumed, rebuilt from a twin of its sampler
     twin = _sampler(small_graph, small_parts)
-    if prefetch is None:
-        consumed = [twin.sample() for _ in range(3)]
-    else:
-        consumed = [twin.build_batch(twin.clusters_at(i,
-                                                      mode=tr.pipeline_mode))
-                    for i in range(3)]
+    consumed = [twin.build_batch(twin.clusters_at(i // recycle,
+                                                  mode=tr.pipeline_mode))
+                for i in range(3)]
     recs = [h for h in tr.history if "loss" in h]
     assert len(recs) == 3
     for rec, sg in zip(recs, consumed):
@@ -132,31 +142,32 @@ def test_record_carries_the_consumed_batch_edge_fill(small_graph, small_parts,
         assert rec["edge_fill"] == sg.n_edges_real / twin.pad_edges
 
 
-@pytest.mark.parametrize("prefetch", [None, 0, 2],
-                         ids=["legacy", "sync", "prefetch"])
+@pytest.mark.parametrize("prefetch,recycle", _FETCHES,
+                         ids=["sync", "prefetch", "recycle2"])
 def test_record_carries_the_consumed_batch_ell_fill(small_graph, small_parts,
-                                                    prefetch):
+                                                    prefetch, recycle):
     """On the ``ell`` backend the record holds the ELL build's seconds
     (``pipeline.ell``, inside ``pipeline.build``) and the consumed batch's
     real (row, neighbour) pairs over its ELL slots."""
     from repro.core import host_batch
-    tr = _trainer(small_graph, small_parts, prefetch=prefetch, backend="ell")
+    tr = _trainer(small_graph, small_parts, prefetch=prefetch,
+                  recycle=recycle, backend="ell")
     try:
         tr.run(2)
     finally:
         tr.close()
     twin = _sampler(small_graph, small_parts)
-    if prefetch is None:
-        consumed = [twin.sample() for _ in range(2)]
-    else:
-        consumed = [twin.build_batch(twin.clusters_at(i,
-                                                      mode=tr.pipeline_mode))
-                    for i in range(2)]
+    consumed = [twin.build_batch(twin.clusters_at(i // recycle,
+                                                  mode=tr.pipeline_mode))
+                for i in range(2)]
     recs = [h for h in tr.history if "loss" in h]
     assert len(recs) == 2
-    for rec, sg in zip(recs, consumed):
+    for i, (rec, sg) in enumerate(zip(recs, consumed)):
         slots = sum(idx.size for idx in
                     host_batch(sg, backend="ell").ell.bucket_idx)
         assert rec["ell_fill"] == sg.n_edges_real / slots
         assert 0.0 < rec["ell_fill"] < rec["edge_fill"] <= 1.0
-        assert 0.0 < rec["ell_s"] <= rec["build_s"]
+        if i % recycle:   # a recycled slot: its batch was built before
+            assert rec["ell_s"] == rec["build_s"] == 0.0
+        else:
+            assert 0.0 < rec["ell_s"] <= rec["build_s"]
