@@ -111,12 +111,17 @@ def host_batch(sg: PaddedSubgraph, *, backend: str = "segment",
 
 def batch_fills(sg: PaddedSubgraph, batch: Batch) -> dict:
     """How full a batch's padded layouts are: ``edge_fill``, the real edges
-    over the padded edge count, and ``ell_fill``, the real (row, neighbour)
-    pairs over the slots of its forward ELL (0.0 without an ELL)."""
-    slots = (0 if batch.ell is None else
-             sum(idx.size for idx in batch.ell.bucket_idx))
+    over the padded edge count; ``ell_fill``, the real (row, neighbour)
+    pairs over the slots of its forward ELL; and ``ell_issue_fill``, the
+    same pairs over the row copies the kernel issues per lane block there
+    (``ELLGraph.issued_copies``). Both ELL fills are 0.0 without an ELL."""
+    ell = batch.ell
+    slots = 0 if ell is None else sum(idx.size for idx in ell.bucket_idx)
+    issued = 0 if ell is None else ell.issued_copies()
+    pairs = sg.n_edges_real
     return {"edge_fill": sg.edge_fill,
-            "ell_fill": sg.n_edges_real / slots if slots else 0.0}
+            "ell_fill": pairs / slots if slots else 0.0,
+            "ell_issue_fill": pairs / issued if issued else 0.0}
 
 
 def to_device_batch(sg: PaddedSubgraph, *, backend: str = "segment",

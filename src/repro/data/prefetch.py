@@ -40,7 +40,7 @@ from repro.tracing import span
 NO_FETCH = {"wait_s": 0.0, "h2d_s": 0.0, "staged": False, "build_s": 0.0,
             "ell_s": 0.0}
 # the fields of last_fetch that describe the yielded batch (core.batch_fills)
-FILLS = ("edge_fill", "ell_fill")
+FILLS = ("edge_fill", "ell_fill", "ell_issue_fill")
 
 
 class _Done:
@@ -227,8 +227,10 @@ class SubgraphPipeline:
     double buffer), ``build_s`` (the seconds that built the yielded
     slot, on its worker), ``ell_s`` (the part of ``build_s`` that built its
     ELL; 0 without one), ``edge_fill`` (the yielded batch's real edges over
-    its padded edge count) and ``ell_fill`` (its real (row, neighbour)
-    pairs over its ELL slots; 0 without an ELL). A recycled step fetches
+    its padded edge count), ``ell_fill`` (its real (row, neighbour)
+    pairs over its ELL slots) and ``ell_issue_fill`` (the same pairs over
+    the row copies the kernel issues per lane block; both 0 without an
+    ELL). A recycled step fetches
     nothing: its seconds are 0, ``staged`` is False, and the fills are the
     recycled batch's.
     """

@@ -14,6 +14,12 @@ a 2-slot ``(block_rows, 128)`` VMEM scratch. Neighbor plane k+1's copies
 start before plane k's wait, so the DMA for k+1 overlaps the multiply-add
 for k (double buffering); the accumulation is one broadcast multiply-add
 over the whole tile into an f32 accumulator, never a per-row scalar loop.
+Each row tile reads only its first ``tile_k[i]`` neighbor planes, a count
+scalar-prefetched into SMEM: the host builder orders a bucket's rows
+longest first and gives each tile its longest real row, so a tile issues
+copies up to that row's length and none past it, and a tile of empty rows
+writes zeros without a copy. The planes a tile skips hold w == 0 in every
+row, so each row's sum is the same products in the same order.
 Nothing bounds the gather source M, so the compiled path gathers from
 full-graph stores. Each copy moves one row's 128 lanes of the grid step's
 lane block, read through ``hbm_tiles``, the source's own HBM tile layout
@@ -33,7 +39,8 @@ Memory per grid step (defaults), at any D: 2-slot gather scratch
 (2, 256, 128) f32 = 256 KiB, w tile (256, K≤128) = 128 KiB, out tile + f32
 accumulator (256, 128) ×2 = 256 KiB of VMEM, and the row tile's
 (block_rows, K) indices in SMEM, whose minor dim pads to 128 words: 128 KiB
-per buffer. None of it grows with N, M or D: the index array reaches SMEM
+per buffer, beside the whole (N / block_rows,) plane-count array (one word a
+tile). Nothing else grows with N, M or D: the index array reaches SMEM
 one row tile at a time (the D/128 lane blocks of a row tile reuse it), where
 a whole (N, K) operand would overflow v5e's 1 MiB of SMEM near N = 2k rows.
 
@@ -93,10 +100,13 @@ def _weight_column(w_ref, k) -> jax.Array:
     return jnp.sum(jnp.where(lane == k, w, 0.0), axis=1, keepdims=True)
 
 
-def _spmm_stream_kernel(idx_ref, w_ref, h_ref, o_ref, gath_ref, acc_ref,
-                        sem_ref, *, K: int, block_rows: int):
+def _spmm_stream_kernel(tile_k_ref, idx_ref, w_ref, h_ref, o_ref, gath_ref,
+                        acc_ref, sem_ref, *, K: int, block_rows: int):
     """Kernel body: per-row HBM→VMEM DMA gathers, double-buffered over k.
 
+    tile_k_ref is the scalar-prefetched (N / bn,) array of per-tile plane
+    counts: this row tile issues copies for planes k < min(tile_k[i], K)
+    only.
     h_ref is the feature operand's HBM tile view (``hbm_tiles``, memory
     space ``pl.ANY``); gath_ref is a (2, bn, 128) VMEM double buffer;
     sem_ref a (2,) DMA-semaphore array, one per slot. Neighbor plane k lands
@@ -105,6 +115,7 @@ def _spmm_stream_kernel(idx_ref, w_ref, h_ref, o_ref, gath_ref, acc_ref,
     waited in the same grid step, so no DMA crosses grid-step boundaries.
     """
     lane = pl.program_id(1)
+    kt = jnp.minimum(tile_k_ref[pl.program_id(0)], K)
 
     def plane(k, slot, op):
         """start()/wait() the bn row-copies of neighbor plane k into slot."""
@@ -118,18 +129,23 @@ def _spmm_stream_kernel(idx_ref, w_ref, h_ref, o_ref, gath_ref, acc_ref,
         jax.lax.fori_loop(0, block_rows, row, 0)
 
     acc_ref[:] = jnp.zeros_like(acc_ref)
-    plane(0, 0, lambda dma: dma.start())
+
+    @pl.when(kt > 0)
+    def _():
+        plane(0, 0, lambda dma: dma.start())
 
     def k_step(k, _):
-        slot = jax.lax.rem(k, 2)
+        @pl.when(k < kt)
+        def _():
+            slot = jax.lax.rem(k, 2)
 
-        @pl.when(k + 1 < K)
-        def _():  # overlap: plane k+1's DMA flies during plane k's compute
-            plane(k + 1, jax.lax.rem(k + 1, 2), lambda dma: dma.start())
+            @pl.when(k + 1 < kt)
+            def _():  # overlap: plane k+1's DMA flies during plane k's compute
+                plane(k + 1, jax.lax.rem(k + 1, 2), lambda dma: dma.start())
 
-        plane(k, slot, lambda dma: dma.wait())
-        acc_ref[:] += (_weight_column(w_ref, k)
-                       * gath_ref[slot].astype(jnp.float32))
+            plane(k, slot, lambda dma: dma.wait())
+            acc_ref[:] += (_weight_column(w_ref, k)
+                           * gath_ref[slot].astype(jnp.float32))
         return 0
 
     jax.lax.fori_loop(0, K, k_step, 0)
@@ -137,38 +153,49 @@ def _spmm_stream_kernel(idx_ref, w_ref, h_ref, o_ref, gath_ref, acc_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
-def ell_spmm(nbr_idx: jax.Array, nbr_w: jax.Array, h: jax.Array, *,
-             block_rows: int = 256,
+def ell_spmm(nbr_idx: jax.Array, nbr_w: jax.Array, h: jax.Array,
+             tile_k: jax.Array, *, block_rows: int = 256,
              interpret: bool | None = None) -> jax.Array:
     """out[i] = Σ_k w[i,k] · h[idx[i,k]]  via pl.pallas_call.
 
     nbr_idx/nbr_w: (N, K); h: (M, D). N must divide by block_rows and D by
-    128 (the ops.py wrapper pads). ``interpret=None`` autodetects: compiled
-    on TPU, interpreted elsewhere.
+    128 (the ops.py wrapper pads). ``tile_k`` (N / block_rows,) int32 gives
+    each row tile its plane count: tile i reads planes k < tile_k[i] only,
+    so every row of the tile must hold w == 0 in the planes past it (the
+    builder's per-tile longest real row, ``ops.build_ell``); a count of K
+    reads every plane. ``block_rows`` is static, so the height of every
+    VMEM tile is a compile-time constant (what lint rule R003 bounds).
+    ``interpret=None`` autodetects: compiled on TPU, interpreted elsewhere.
     """
     if interpret is None:
         interpret = default_interpret()
     n, k = nbr_idx.shape
     d = h.shape[1]
     assert n % block_rows == 0 and d % LANES == 0, (n, d)
+    assert tile_k.shape == (n // block_rows,), (tile_k.shape, n, block_rows)
     return pl.pallas_call(
         functools.partial(_spmm_stream_kernel, K=k, block_rows=block_rows),
-        grid=(n // block_rows, d // LANES),
-        in_specs=[
-            # SMEM: one (block_rows, K) index tile, its minor dim padded to
-            # 128 words, so (256, 128) int32 = 128 KiB per buffer
-            pl.BlockSpec((block_rows, k), lambda i, j: (i, 0),
-                         memory_space=pltpu.SMEM),
-            # lint: ok(R003) K <= 128 by bucket construction: build_ell caps
-            # bucket widths at powers of two <= 128, so this w tile is at
-            # most (256, 128) f32 = 128 KiB, whatever D is
-            pl.BlockSpec((block_rows, k), lambda i, j: (i, 0)),
-            pl.BlockSpec(memory_space=pl.ANY),  # stays in HBM
-        ],
-        out_specs=pl.BlockSpec((block_rows, LANES), lambda i, j: (i, j)),
-        scratch_shapes=[pltpu.VMEM((2, block_rows, LANES), h.dtype),
-                        pltpu.VMEM((block_rows, LANES), jnp.float32),
-                        pltpu.SemaphoreType.DMA((2,))],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            # SMEM: the whole (N / block_rows,) plane-count array, one word
+            # a row tile (1,001 words on a gas-arxiv batch's K = 8 bucket)
+            num_scalar_prefetch=1,
+            grid=(n // block_rows, d // LANES),
+            in_specs=[
+                # SMEM: one (block_rows, K) index tile, its minor dim padded
+                # to 128 words, so (256, 128) int32 = 128 KiB per buffer
+                pl.BlockSpec((block_rows, k), lambda i, j, tk: (i, 0),
+                             memory_space=pltpu.SMEM),
+                # lint: ok(R003) K <= 128 by bucket construction: build_ell
+                # caps bucket widths at powers of two <= 128, so this w tile
+                # is at most (256, 128) f32 = 128 KiB, whatever D is
+                pl.BlockSpec((block_rows, k), lambda i, j, tk: (i, 0)),
+                pl.BlockSpec(memory_space=pl.ANY),  # stays in HBM
+            ],
+            out_specs=pl.BlockSpec((block_rows, LANES),
+                                   lambda i, j, tk: (i, j)),
+            scratch_shapes=[pltpu.VMEM((2, block_rows, LANES), h.dtype),
+                            pltpu.VMEM((block_rows, LANES), jnp.float32),
+                            pltpu.SemaphoreType.DMA((2,))]),
         out_shape=jax.ShapeDtypeStruct((n, d), h.dtype),
         interpret=interpret,
-    )(nbr_idx, nbr_w, hbm_tiles(h))
+    )(tile_k.astype(jnp.int32), nbr_idx, nbr_w, hbm_tiles(h))

@@ -2,7 +2,10 @@
 
 `bucketed_spmm` is the deployable aggregation: rows are degree-bucketed host
 side (powers of two) so ELL padding waste stays < 2x, each bucket runs one
-`ell_spmm` pallas_call, and the results concatenate back in row order. It is a
+`ell_spmm` pallas_call, and the results scatter-add back in row order. Within
+a bucket the builder orders rows longest first and records each row tile's
+longest real row (``ELLGraph.bucket_tile_k``), so the kernel stops each tile
+at that plane and issues no copy for the slots past it. It is a
 `jax.custom_vjp`: the transpose of an ELL SpMM is an SpMM over the transposed
 adjacency, so `build_ell` also buckets Aᵀ and the backward pass runs through
 the same kernel (this is what lets `core/lmc.py`'s per-layer ``jax.vjp`` calls
@@ -53,53 +56,67 @@ class ELLCapacityError(ValueError):
     """
 
 
-def _pick_block_rows(rows: int) -> int:
-    """Largest power-of-two tile height ≤ 256 dividing the padded row count."""
-    for b in (256, 128, 64, 32, 16, 8):
-        if rows % b == 0:
-            return b
-    raise ValueError(f"ELL bucket rows {rows} not a multiple of 8")
-
-
 @jax.tree_util.register_pytree_node_class
 @dataclasses.dataclass(frozen=True)
 class ELLGraph:
     """Degree-bucketed padded-ELL adjacency (host-built, device arrays).
 
     Registered as a pytree so it can ride through ``jit`` (as part of a Batch)
-    and through ``jax.custom_vjp``: the index/weight/row arrays are children,
-    the row/col counts are static aux data, and ``transpose`` (the bucketed
-    Aᵀ, used by the SpMM VJP) is a nested child.
+    and through ``jax.custom_vjp``: the index/weight/row/tile arrays are
+    children, the row/col counts are static aux data, and ``transpose`` (the
+    bucketed Aᵀ, used by the SpMM VJP) is a nested child.
+
+    A bucket's rows come longest first. ``bucket_tile_k[b][i]`` is the
+    longest real row of the bucket's row tile i, the planes the kernel
+    reads there; the tile height is ``tile_rows(b)``.
     """
     bucket_idx: tuple      # per bucket: (rows_b, K_b) int32 neighbor ids
     bucket_w: tuple        # per bucket: (rows_b, K_b) f32 weights
     bucket_rows: tuple     # per bucket: (rows_b,) int32 destination rows
+    bucket_tile_k: tuple   # per bucket: (rows_b // tile,) int32 plane counts
     num_rows: int          # output rows (static)
     num_cols: int          # gather-source rows, == h.shape[0] (static)
     transpose: Optional["ELLGraph"] = None
 
     def tree_flatten(self):
         return ((self.bucket_idx, self.bucket_w, self.bucket_rows,
-                 self.transpose), (self.num_rows, self.num_cols))
+                 self.bucket_tile_k, self.transpose),
+                (self.num_rows, self.num_cols))
 
     @classmethod
     def tree_unflatten(cls, aux, children):
-        idx, w, rows, t = children
+        idx, w, rows, tile_k, t = children
         return cls(bucket_idx=idx, bucket_w=w, bucket_rows=rows,
-                   num_rows=aux[0], num_cols=aux[1], transpose=t)
+                   bucket_tile_k=tile_k, num_rows=aux[0], num_cols=aux[1],
+                   transpose=t)
+
+    def tile_rows(self, b: int) -> int:
+        """Bucket b's row-tile height: its rows over its plane counts."""
+        return self.bucket_idx[b].shape[0] // self.bucket_tile_k[b].shape[0]
+
+    def issued_copies(self) -> int:
+        """The row copies the kernel issues per lane block over this
+        direction's buckets: Σ ``bucket_tile_k`` × tile height (host
+        arrays)."""
+        return sum(int(np.sum(tk)) * self.tile_rows(b)
+                   for b, tk in enumerate(self.bucket_tile_k))
 
 
 # --------------------------------------------------------------- host builders
 def _ell_buckets(indptr: np.ndarray, indices: np.ndarray, weights: np.ndarray,
                  buckets: Sequence[int], block_rows: int,
                  row_capacity: Optional[Sequence[int]], as_jax: bool = True):
-    """CSR -> per-bucket (idx, w, rows) arrays, fully vectorized.
+    """CSR -> per-bucket (idx, w, rows, tile_k) arrays, fully vectorized.
 
-    Reproduces the row order of the original per-node loop exactly: rows are
-    emitted in (node, chunk) order; each chunk of ≤ kmax neighbors lands in
-    the smallest bucket that fits it; deg-0 nodes emit one empty bucket-0 row.
-    ``as_jax=False`` keeps the bucket arrays as host numpy (the prefetch
-    pipeline builds batches off-thread and lets the consumer ``device_put``).
+    Reproduces the rows of the per-node loop (``_build_ell_loop``) exactly:
+    each chunk of ≤ kmax neighbors lands in the smallest bucket that fits
+    it; deg-0 nodes emit one empty bucket-0 row. Within a bucket rows come
+    in non-increasing order of real length, ties in (node, chunk) order, so
+    empty and pad rows trail; ``tile_k`` holds each ``block_rows`` tile's
+    longest real row. A given ``row_capacity`` must be a multiple of
+    ``block_rows``. ``as_jax=False`` keeps the bucket arrays as host numpy
+    (the prefetch pipeline builds batches off-thread and lets the consumer
+    ``device_put``).
     """
     n = indptr.shape[0] - 1
     deg = np.diff(indptr).astype(np.int64)
@@ -115,15 +132,23 @@ def _ell_buckets(indptr: np.ndarray, indices: np.ndarray, weights: np.ndarray,
     chunk_len = np.clip(deg[row_node] - chunk_start, 0, kmax)
     bucket_of = np.searchsorted(np.asarray(buckets, np.int64), chunk_len)
 
-    b_idx, b_w, b_rows = [], [], []
+    b_idx, b_w, b_rows, b_tile_k = [], [], [], []
     for b, k in enumerate(buckets):
-        sel = np.flatnonzero(bucket_of == b)   # preserves (node, chunk) order
+        sel = np.flatnonzero(bucket_of == b)   # in (node, chunk) order
+        # longest first, ties in (node, chunk) order; on a narrow unsigned
+        # key numpy's stable sort is a radix sort, not a merge sort
+        key = (kmax - chunk_len[sel]).astype(np.min_scalar_type(kmax))
+        sel = sel[np.argsort(key, kind="stable")]
         rows = sel.shape[0]
         if row_capacity is not None:
             rows_pad = int(row_capacity[b])
             if rows > rows_pad:
                 raise ELLCapacityError(
                     f"bucket {b} (K={k}): {rows} rows exceed capacity {rows_pad}")
+            if rows_pad % block_rows:
+                raise ValueError(f"bucket {b} (K={k}): capacity {rows_pad} "
+                                 f"is not a multiple of block_rows "
+                                 f"{block_rows}")
         else:
             rows_pad = max(_round_up(rows, block_rows), block_rows)
         idx = np.zeros((rows_pad, k), np.int32)
@@ -139,16 +164,20 @@ def _ell_buckets(indptr: np.ndarray, indices: np.ndarray, weights: np.ndarray,
                 w[:rows] = np.where(valid, weights[pos], 0.0).astype(np.float32)
             # else: edgeless graph — every row is an all-padding deg-0 row
             rid[:rows] = row_node[sel].astype(np.int32)
+        length = np.zeros((rows_pad,), np.int32)
+        length[:rows] = chunk_len[sel]
         conv = jnp.asarray if as_jax else (lambda a: a)
         b_idx.append(conv(idx))
         b_w.append(conv(w))
         b_rows.append(conv(rid))
-    return tuple(b_idx), tuple(b_w), tuple(b_rows)
+        b_tile_k.append(conv(length.reshape(-1, block_rows).max(axis=1)))
+    return tuple(b_idx), tuple(b_w), tuple(b_rows), tuple(b_tile_k)
 
 
 def _build_ell_loop(indptr, indices, weights, buckets=(8, 32, 128),
                     block_rows: int = 256):
-    """Original per-node Python-loop builder.
+    """Original per-node Python-loop builder, rows ordered longest first
+    within each bucket (a stable sort of the (node, chunk) order).
 
     Kept only as the correctness reference for the vectorized `build_ell`
     (property-tested against it) and as the baseline of the preprocessing
@@ -156,10 +185,11 @@ def _build_ell_loop(indptr, indices, weights, buckets=(8, 32, 128),
     """
     n = indptr.shape[0] - 1
     kmax = buckets[-1]
-    b_idx, b_w, b_rows = [], [], []
+    b_idx, b_w, b_rows, b_tile_k = [], [], [], []
     row_ids = [[] for _ in buckets]
     row_idx = [[] for _ in buckets]
     row_ws = [[] for _ in buckets]
+    row_len = [[] for _ in buckets]
 
     for v in range(n):
         lo, hi = indptr[v], indptr[v + 1]
@@ -173,21 +203,28 @@ def _build_ell_loop(indptr, indices, weights, buckets=(8, 32, 128),
             row_ids[b].append(v)
             row_idx[b].append(np.pad(part_n.astype(np.int32), (0, pad)))
             row_ws[b].append(np.pad(part_w.astype(np.float32), (0, pad)))
+            row_len[b].append(len(part_n))
 
     for b, k in enumerate(buckets):
         rows = len(row_ids[b])
         rows_pad = max(_round_up(rows, block_rows), block_rows)
+        order = sorted(range(rows), key=lambda i: -row_len[b][i])
         idx = np.zeros((rows_pad, k), np.int32)
         w = np.zeros((rows_pad, k), np.float32)
         rid = np.full((rows_pad,), n, np.int32)
+        lens = [row_len[b][i] for i in order] + [0] * (rows_pad - rows)
         if rows:
-            idx[:rows] = np.stack(row_idx[b])
-            w[:rows] = np.stack(row_ws[b])
-            rid[:rows] = np.asarray(row_ids[b], np.int32)
+            idx[:rows] = np.stack([row_idx[b][i] for i in order])
+            w[:rows] = np.stack([row_ws[b][i] for i in order])
+            rid[:rows] = np.asarray([row_ids[b][i] for i in order], np.int32)
         b_idx.append(jnp.asarray(idx))
         b_w.append(jnp.asarray(w))
         b_rows.append(jnp.asarray(rid))
-    return ELLGraph(tuple(b_idx), tuple(b_w), tuple(b_rows),
+        b_tile_k.append(jnp.asarray(
+            [max(lens[t:t + block_rows]) for t in range(0, rows_pad,
+                                                         block_rows)],
+            jnp.int32))
+    return ELLGraph(tuple(b_idx), tuple(b_w), tuple(b_rows), tuple(b_tile_k),
                     num_rows=n, num_cols=n)
 
 
@@ -211,11 +248,16 @@ def build_ell(indptr: np.ndarray, indices: np.ndarray, weights: np.ndarray,
     """CSR -> degree-bucketed ELL (bulk numpy, no per-node Python loop).
 
     Rows with deg > max(buckets) are split into multiple partial rows (their
-    partial sums add via the final scatter-add, keeping K bounded). When
+    partial sums add via the final scatter-add, keeping K bounded). Each
+    bucket's rows come longest first, and ``bucket_tile_k`` gives each
+    ``block_rows`` tile its longest real row: the kernel's tile height and
+    the neighbour planes it reads there (the row order is free, since
+    ``bucket_rows`` maps every ELL row to its destination). When
     ``with_transpose`` the transposed adjacency is bucketed too, giving the
     SpMM its custom-VJP backward graph. ``row_capacity`` (per-bucket padded
-    row counts, applied to both directions) fixes the array shapes so every
-    batch of a sampler hits one jit trace.
+    row counts, applied to both directions, each a multiple of
+    ``block_rows``) fixes the array shapes so every batch of a sampler hits
+    one jit trace.
     """
     indptr = np.asarray(indptr, np.int64)
     indices = np.asarray(indices)
@@ -223,15 +265,15 @@ def build_ell(indptr: np.ndarray, indices: np.ndarray, weights: np.ndarray,
     n = indptr.shape[0] - 1
     num_cols = n if num_cols is None else int(num_cols)
 
-    idx, w, rows = _ell_buckets(indptr, indices, weights, buckets, block_rows,
-                                row_capacity, as_jax)
+    fwd = _ell_buckets(indptr, indices, weights, buckets, block_rows,
+                       row_capacity, as_jax)
     t = None
     if with_transpose:
         t_ptr, t_ind, t_w = _transpose_csr(indptr, indices, weights, num_cols)
-        ti, tw, tr = _ell_buckets(t_ptr, t_ind, t_w, buckets, block_rows,
-                                  row_capacity, as_jax)
-        t = ELLGraph(ti, tw, tr, num_rows=num_cols, num_cols=n)
-    return ELLGraph(idx, w, rows, num_rows=n, num_cols=num_cols, transpose=t)
+        t = ELLGraph(*_ell_buckets(t_ptr, t_ind, t_w, buckets, block_rows,
+                                   row_capacity, as_jax),
+                     num_rows=num_cols, num_cols=n)
+    return ELLGraph(*fwd, num_rows=n, num_cols=num_cols, transpose=t)
 
 
 def _profile_rows(hist: np.ndarray, buckets: Sequence[int]) -> list:
@@ -319,14 +361,16 @@ def ell_from_coo(src: np.ndarray, dst: np.ndarray, w: np.ndarray,
 # ------------------------------------------------------------ kernel wrappers
 def _bucketed_spmm_impl(g: ELLGraph, h: jax.Array,
                         interpret: bool) -> jax.Array:
-    """out[i] = Σ_{j in N(i)} w_ij h[j] over all degree buckets."""
+    """out[i] = Σ_{j in N(i)} w_ij h[j] over all degree buckets; each
+    bucket's kernel runs at the graph's tile height and plane counts."""
     n = g.num_rows
     d = h.shape[1]
     d_pad = _round_up(d, 128)
     hp = jnp.pad(h, ((0, 0), (0, d_pad - d))) if d_pad != d else h
     out = jnp.zeros((n + 1, d_pad), h.dtype)   # row n catches padding rows
-    for idx, w, rows in zip(g.bucket_idx, g.bucket_w, g.bucket_rows):
-        part = ell_spmm(idx, w, hp, block_rows=_pick_block_rows(idx.shape[0]),
+    for b, (idx, w, rows, tile_k) in enumerate(zip(
+            g.bucket_idx, g.bucket_w, g.bucket_rows, g.bucket_tile_k)):
+        part = ell_spmm(idx, w, hp, tile_k, block_rows=g.tile_rows(b),
                         interpret=interpret)
         out = out.at[rows].add(part.astype(h.dtype), mode="drop")
     return out[:n, :d]

@@ -369,8 +369,8 @@ class GNNTrainer:
         ``dispatch_s``, ``sync_s``) and tells whether the batch was
         ``staged`` on the device ahead of time, what it took to build
         (``build_s``, its ELL part ``ell_s``) and how full its padded edge
-        list and ELL are (``edge_fill``, ``ell_fill``), as
-        ``SubgraphPipeline.last_fetch`` defines them."""
+        list and ELL are (``edge_fill``, ``ell_fill``, ``ell_issue_fill``),
+        as ``SubgraphPipeline.last_fetch`` defines them."""
         t0 = time.perf_counter()
         parts = dict(NO_FETCH)
         with span("train.fetch", parts):

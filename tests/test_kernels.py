@@ -1,12 +1,14 @@
 """Pallas kernels vs pure-jnp oracles (interpret mode), shape/dtype sweeps,
 gradient paths through the custom VJPs, and the vectorized ELL builder."""
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 from _prop import given, settings, strategies as st
 
-from repro.kernels import (ELLCapacityError, ELLGraph, build_ell,
+from repro.kernels import (ELLCapacityError, build_ell,
                            bucketed_spmm, default_interpret, ell_from_coo,
                            ell_spmm, lmc_compensate)
 from repro.kernels.ops import _build_ell_loop, fixed_row_capacity
@@ -34,6 +36,12 @@ def _random_csr(seed, n_max=60, heavy=True):
 WIDTHS = [128, 256, 384]
 
 
+def _all_planes(idx, block_rows=256):
+    """Per-tile plane counts that read every plane of (N, K) ``idx``."""
+    n, k = idx.shape
+    return jnp.full((n // block_rows,), k, jnp.int32)
+
+
 @pytest.mark.parametrize("d", WIDTHS)
 @given(n_tiles=st.integers(1, 2), k=st.sampled_from([4, 8, 32]),
        m=st.sampled_from([64, 300, 1000]),
@@ -45,7 +53,8 @@ def test_ell_spmm_matches_ref(d, n_tiles, k, m, dtype, seed):
     idx = rng.integers(0, m, (n, k)).astype(np.int32)
     w = (rng.random((n, k)) * (rng.random((n, k)) > 0.3)).astype(dtype)
     h = rng.normal(size=(m, d)).astype(dtype)
-    out = ell_spmm(jnp.asarray(idx), jnp.asarray(w), jnp.asarray(h))
+    out = ell_spmm(jnp.asarray(idx), jnp.asarray(w), jnp.asarray(h),
+                   _all_planes(idx))
     ref = ell_spmm_ref(jnp.asarray(idx), jnp.asarray(w), jnp.asarray(h))
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
@@ -57,7 +66,7 @@ def test_ell_spmm_bf16():
     w = rng.random((256, 8)).astype(np.float32)
     h = rng.normal(size=(64, 128)).astype(jnp.bfloat16)
     out = ell_spmm(jnp.asarray(idx), jnp.asarray(w).astype(jnp.bfloat16),
-                   jnp.asarray(h))
+                   jnp.asarray(h), _all_planes(idx))
     ref = ell_spmm_ref(jnp.asarray(idx),
                        jnp.asarray(w).astype(jnp.bfloat16), jnp.asarray(h))
     np.testing.assert_allclose(np.float32(out), np.float32(ref),
@@ -115,17 +124,21 @@ def test_bucketed_spmm_on_real_graph(small_graph):
 
 
 # ------------------------------------------------------ vectorized build_ell
+def _bucket_arrays(g):
+    return g.bucket_idx + g.bucket_w + g.bucket_rows + g.bucket_tile_k
+
+
 @given(seed=st.integers(0, 200))
 @settings(max_examples=12)
 def test_build_ell_vectorized_matches_loop(seed):
-    """The bulk-numpy builder reproduces the original per-node loop exactly:
-    same bucketing, same heavy-row splitting, same row order, same padding."""
+    """The bulk-numpy builder reproduces the per-node loop exactly: same
+    bucketing, same heavy-row splitting, same row order (longest first
+    within a bucket), same padding, same per-tile plane counts."""
     indptr, indices, weights = _random_csr(seed)
     g_vec = build_ell(indptr, indices, weights, with_transpose=False)
     g_loop = _build_ell_loop(indptr, indices, weights)
     assert g_vec.num_rows == g_loop.num_rows
-    for a, b in zip(g_vec.bucket_idx + g_vec.bucket_w + g_vec.bucket_rows,
-                    g_loop.bucket_idx + g_loop.bucket_w + g_loop.bucket_rows):
+    for a, b in zip(_bucket_arrays(g_vec), _bucket_arrays(g_loop)):
         assert a.shape == b.shape
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
@@ -138,9 +151,9 @@ def test_build_ell_edgeless_graph():
     g_vec = build_ell(indptr, np.zeros(0, np.int32), np.zeros(0, np.float32))
     g_loop = _build_ell_loop(indptr, np.zeros(0, np.int32),
                              np.zeros(0, np.float32))
-    for a, b in zip(g_vec.bucket_idx + g_vec.bucket_w + g_vec.bucket_rows,
-                    g_loop.bucket_idx + g_loop.bucket_w + g_loop.bucket_rows):
+    for a, b in zip(_bucket_arrays(g_vec), _bucket_arrays(g_loop)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    assert all(int(np.max(tk)) == 0 for tk in g_vec.bucket_tile_k)
     out = bucketed_spmm(g_vec, jnp.ones((n, 8), jnp.float32))
     np.testing.assert_array_equal(np.asarray(out), np.zeros((n, 8)))
 
@@ -200,7 +213,8 @@ def test_build_ell_exactly_at_capacity():
     indptr[1:] = np.cumsum(deg)
     indices = r.integers(0, n, int(indptr[-1])).astype(np.int32)
     weights = r.random(int(indptr[-1])).astype(np.float32)
-    g = build_ell(indptr, indices, weights, row_capacity=(8, 8, 8))
+    g = build_ell(indptr, indices, weights, block_rows=8,
+                  row_capacity=(8, 8, 8))
     assert g.bucket_idx[0].shape[0] == 8          # exactly full, zero pad rows
     h = r.normal(size=(n, 8)).astype(np.float32)
     out = np.asarray(bucketed_spmm(g, jnp.asarray(h)))
@@ -336,7 +350,6 @@ def test_coo_without_profile_keeps_worst_case_shapes(small_graph,
                                                      small_parts):
     """Without degree histograms (a hand-built COO, or a subgraph that
     carries none) the bucket shapes stay fixed_row_capacity's worst case."""
-    import dataclasses
     from repro.core import host_batch
     from repro.graph import ClusterSampler
     r = np.random.default_rng(0)
@@ -397,6 +410,168 @@ def test_real_edge_ell_is_bit_exact_against_padded_worst_case(small_graph,
     np.testing.assert_array_equal(outs[0][1], outs[1][1])
 
 
+# ------------------------------------------------- per-tile plane counts
+# plane counts of four 64-row tiles: an empty tile, a full one, two between
+TILE_COUNTS = [(0, 8, 3, 1), (8, 0, 0, 5), (2, 7, 8, 0)]
+
+
+@pytest.mark.parametrize("d", [128, 256])
+@pytest.mark.parametrize("counts", TILE_COUNTS, ids=lambda c: "-".join(
+    map(str, c)))
+def test_ell_spmm_stops_each_tile_at_its_plane_count(counts, d):
+    """Tile i reads planes k < tile_k[i] only. Rows hold real lengths up to
+    their tile's count, one row at it, and w == 0 past their length, so the
+    first plane past the count is padding in every row; the planes past it
+    carry garbage indices and weights the kernel must not read. Small
+    integer values keep every sum exact, so the kernel equals the oracle on
+    the padded (idx, w) to the bit, and an all-empty tile gives exact 0."""
+    rng = np.random.default_rng(sum(counts) + d)
+    k, bn, m = 8, 64, 300
+    counts = np.asarray(counts, np.int32)
+    n = bn * counts.size
+    length = np.minimum(rng.integers(0, k + 1, n), np.repeat(counts, bn))
+    length[::bn] = counts                          # one row at the count
+    slot = np.arange(k)[None, :]
+    idx = rng.integers(0, m, (n, k)).astype(np.int32)
+    w = rng.integers(-4, 5, (n, k)).astype(np.float32)
+    h = rng.integers(-8, 9, (m, d)).astype(np.float32)
+    real = slot < length[:, None]
+    w_pad = np.where(real, w, 0.0).astype(np.float32)
+    idx_pad = np.where(real, idx, 0).astype(np.int32)
+    # past the tile's count the kernel reads nothing: garbage there is inert
+    past = slot >= np.repeat(counts, bn)[:, None]
+    w_run = np.where(past, w, w_pad).astype(np.float32)
+    out = np.asarray(ell_spmm(jnp.asarray(idx_pad), jnp.asarray(w_run),
+                              jnp.asarray(h), jnp.asarray(counts),
+                              block_rows=bn))
+    ref = np.asarray(ell_spmm_ref(jnp.asarray(idx_pad), jnp.asarray(w_pad),
+                                  jnp.asarray(h)))
+    np.testing.assert_array_equal(out, ref)
+    for i in np.flatnonzero(counts == 0):
+        np.testing.assert_array_equal(out[i * bn:(i + 1) * bn], 0.0)
+
+
+def _node_order_full_planes(g):
+    """The same ELL with each bucket's rows back in node order (pad rows
+    last) and every tile at K planes: the layout before rows were ordered
+    by length and tiles counted."""
+    def one(g_dir):
+        perm = [np.argsort(np.asarray(rows), kind="stable")
+                for rows in g_dir.bucket_rows]
+        return dataclasses.replace(
+            g_dir,
+            bucket_idx=tuple(jnp.asarray(np.asarray(a)[p])
+                             for a, p in zip(g_dir.bucket_idx, perm)),
+            bucket_w=tuple(jnp.asarray(np.asarray(a)[p])
+                           for a, p in zip(g_dir.bucket_w, perm)),
+            bucket_rows=tuple(jnp.asarray(np.asarray(a)[p])
+                              for a, p in zip(g_dir.bucket_rows, perm)),
+            bucket_tile_k=tuple(jnp.full(tk.shape, idx.shape[1], jnp.int32)
+                                for tk, idx in zip(g_dir.bucket_tile_k,
+                                                   g_dir.bucket_idx)),
+            transpose=None)
+    return dataclasses.replace(one(g), transpose=one(g.transpose))
+
+
+def test_length_ordered_counted_ell_is_bit_exact_against_node_order():
+    """``bucketed_spmm`` and its ``jax.grad`` over the length-ordered ELL
+    with per-tile plane counts equal, to the last bit, the same graph laid
+    out in node order with every tile at K planes: each row sums the same
+    products in the same order, and only w == 0 planes were dropped. The
+    graph has deg-0 rows and a bucket of mixed lengths, both ways."""
+    r = np.random.default_rng(5)
+    n = 300
+    deg = r.choice([0, 1, 3, 8, 9, 17, 32], size=n)
+    indptr = np.zeros(n + 1, np.int64)
+    indptr[1:] = np.cumsum(deg)
+    indices = r.integers(0, n, int(indptr[-1])).astype(np.int32)
+    weights = r.random(int(indptr[-1])).astype(np.float32)
+    g = build_ell(indptr, indices, weights, block_rows=32)
+    plain = _node_order_full_planes(g)
+    assert np.asarray(g.bucket_tile_k[0]).min() == 0    # empty tiles
+    for g_dir in (g, g.transpose):
+        counts = np.asarray(g_dir.bucket_tile_k[1])     # K = 32, mixed
+        assert 0 < counts.min() < counts.max() <= 32
+    h = jnp.asarray(r.normal(size=(n, 40)).astype(np.float32))
+    loss = lambda g_, h_: jnp.sum(jnp.sin(bucketed_spmm(g_, h_)))
+    for f in (lambda g_: bucketed_spmm(g_, h),
+              lambda g_: jax.grad(loss, argnums=1)(g_, h)):
+        np.testing.assert_array_equal(np.asarray(f(g)), np.asarray(f(plain)))
+
+
+def _check_length_order(g_dir, nnz):
+    """Rows of every bucket hold their real edges as a prefix, longest row
+    first, and each tile's plane count is its longest real row."""
+    held = 0
+    for w, tk in zip(g_dir.bucket_w, g_dir.bucket_tile_k):
+        w, tk = np.asarray(w), np.asarray(tk)
+        real = w != 0
+        length = real.sum(axis=1)
+        held += int(length.sum())
+        np.testing.assert_array_equal(
+            real, np.arange(w.shape[1])[None, :] < length[:, None])
+        assert np.all(np.diff(length) <= 0)
+        bn = w.shape[0] // tk.shape[0]
+        assert tk.shape[0] * bn == w.shape[0]
+        np.testing.assert_array_equal(tk, length.reshape(-1, bn).max(axis=1))
+    assert held == nnz
+
+
+@given(seed=st.integers(0, 200))
+@settings(max_examples=10)
+def test_build_ell_orders_rows_by_length_and_counts_tiles(seed):
+    """Both directions of ``build_ell`` on graphs with deg-0 and heavy
+    (split) rows: non-increasing row lengths in every bucket, and
+    ``bucket_tile_k`` equal to each tile's longest real row."""
+    indptr, indices, weights = _random_csr(seed)
+    weights = weights + 0.5                  # every real edge reads w != 0
+    g = build_ell(indptr, indices, weights, block_rows=16)
+    for g_dir in (g, g.transpose):
+        _check_length_order(g_dir, indices.shape[0])
+
+
+def test_ell_from_coo_profile_capacities_order_rows_by_length(small_graph,
+                                                              small_parts):
+    """The same holds for sampler batches built by ``ell_from_coo`` at the
+    degree-profile capacities, both directions, at two tile heights."""
+    from repro.core import host_batch
+    from repro.graph import ClusterSampler
+    s = ClusterSampler(small_graph, 16, 4, parts=small_parts, seed=13)
+    for sg in list(s.epoch())[:2]:
+        ne = sg.n_edges_real
+        assert np.all(sg.edge_w[:ne] != 0)
+        for ell in (host_batch(sg, backend="ell").ell,
+                    ell_from_coo(sg.edge_src[:ne], sg.edge_dst[:ne],
+                                 sg.edge_w[:ne], sg.n_ext, block_rows=8,
+                                 edge_capacity=s.pad_edges,
+                                 degree_hists=s.degree_hists, as_jax=False)):
+            for g_dir in (ell, ell.transpose):
+                _check_length_order(g_dir, ne)
+
+
+@pytest.mark.parametrize("seed", [3, 11])
+def test_issued_copies_lie_between_real_pairs_and_slots(seed):
+    """``ELLGraph.issued_copies`` covers every real pair and stops short of
+    the slots once tiles stop at their longest row; the same graph in node
+    order at K planes a tile issues a copy for every slot."""
+    indptr, indices, weights = _random_csr(seed)
+    g = build_ell(indptr, indices, weights, block_rows=16)
+    plain = _node_order_full_planes(g)
+    for g_dir, p_dir in ((g, plain), (g.transpose, plain.transpose)):
+        slots = sum(idx.size for idx in g_dir.bucket_idx)
+        assert indices.shape[0] <= g_dir.issued_copies() < slots
+        assert p_dir.issued_copies() == slots
+
+
+def test_build_ell_capacity_must_be_whole_tiles():
+    """A row capacity that is not a multiple of the tile height cannot be
+    cut into tiles, so it is refused before any row is laid out."""
+    indptr = np.arange(5, dtype=np.int64)
+    with pytest.raises(ValueError, match="not a multiple of block_rows 8"):
+        build_ell(indptr, np.zeros(4, np.int32), np.ones(4, np.float32),
+                  block_rows=8, row_capacity=(12, 8, 8))
+
+
 # ------------------------------------------------------------- gradient paths
 def test_grad_bucketed_spmm_matches_oracle():
     """jax.grad through the kernel (custom VJP = transposed-graph SpMM)
@@ -438,8 +613,7 @@ def test_vjp_bucketed_spmm_weight_cotangent():
         return out[:n]
 
     _, vjp_k = jax.vjp(lambda ws, h_: bucketed_spmm(
-        ELLGraph(g.bucket_idx, ws, g.bucket_rows, n, n, g.transpose), h_),
-        g.bucket_w, h)
+        dataclasses.replace(g, bucket_w=ws), h_), g.bucket_w, h)
     _, vjp_r = jax.vjp(oracle, g.bucket_w, h)
     (dw_k, dh_k), (dw_r, dh_r) = vjp_k(ct), vjp_r(ct)
     np.testing.assert_allclose(np.asarray(dh_k), np.asarray(dh_r),
@@ -491,7 +665,7 @@ def test_interpret_autodetect():
     idx = jnp.asarray(rng.integers(0, 16, (256, 8)).astype(np.int32))
     w = jnp.asarray(rng.random((256, 8)).astype(np.float32))
     h = jnp.asarray(rng.normal(size=(16, 128)).astype(np.float32))
-    out = ell_spmm(idx, w, h)
+    out = ell_spmm(idx, w, h, _all_planes(idx))
     np.testing.assert_allclose(np.asarray(out),
                                np.asarray(ell_spmm_ref(idx, w, h)),
                                rtol=2e-5, atol=2e-5)
@@ -506,7 +680,8 @@ def test_ell_spmm_wide_bucket_sweep():
         w = (rng.random((256, 128)) * (rng.random((256, 128)) > 0.5)
              ).astype(np.float32)
         h = rng.normal(size=(m, d)).astype(np.float32)
-        out = ell_spmm(jnp.asarray(idx), jnp.asarray(w), jnp.asarray(h))
+        out = ell_spmm(jnp.asarray(idx), jnp.asarray(w), jnp.asarray(h),
+                       _all_planes(idx))
         ref = ell_spmm_ref(jnp.asarray(idx), jnp.asarray(w), jnp.asarray(h))
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    rtol=2e-4, atol=2e-4)

@@ -13,6 +13,7 @@ only one process at a time may load the TPU library, and every test worker
 imports every test file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -32,6 +33,7 @@ BUCKETS = (8, 32, 128)
 # the bucket capacities a gas-arxiv batch has, sized from the graph's degree
 # profile (fixed_row_capacity(..., degree_hists=)), against the worst case
 ARXIV_PROFILE_CAPS = (256_256, 157_184, 256)
+TILE = 256       # the builder's row tile: one plane count per tile
 
 
 @pytest.fixture(scope="module")
@@ -59,7 +61,8 @@ def _shape(sharding, shape, dtype=jnp.float32):
 
 def _ell_shapes(sharding, rows: int, edges: int, caps=None) -> ELLGraph:
     """Shapes of the square bucketed ELL (and its transpose) for one batch,
-    at the worst-case bucket capacities unless ``caps`` are given."""
+    at the worst-case bucket capacities unless ``caps`` are given, with one
+    scalar-prefetched plane count per 256-row tile of each bucket."""
     if caps is None:
         caps = fixed_row_capacity(rows, edges, BUCKETS)
 
@@ -67,7 +70,8 @@ def _ell_shapes(sharding, rows: int, edges: int, caps=None) -> ELLGraph:
         return (tuple(_shape(sharding, (c, k), jnp.int32)
                       for c, k in zip(caps, BUCKETS)),
                 tuple(_shape(sharding, (c, k)) for c, k in zip(caps, BUCKETS)),
-                tuple(_shape(sharding, (c,), jnp.int32) for c in caps))
+                tuple(_shape(sharding, (c,), jnp.int32) for c in caps),
+                tuple(_shape(sharding, (c // TILE,), jnp.int32) for c in caps))
 
     return ELLGraph(*one(), rows, rows, transpose=ELLGraph(*one(), rows, rows))
 
@@ -76,12 +80,22 @@ def _kernel_calls(compiled) -> int:
     return compiled.as_text().count('custom_call_target="tpu_custom_call"')
 
 
+def _leading_operands(compiled) -> list:
+    """The first operand's layout of every kernel call: a scalar-prefetch
+    operand leads the list."""
+    return [m.group(1) for m in re.finditer(
+        r'custom_call_target="tpu_custom_call", '
+        r"operand_layout_constraints=\{([^,]*),", compiled.as_text())]
+
+
 @pytest.mark.parametrize("caps", [None, ARXIV_PROFILE_CAPS],
                          ids=["streamed", "profile"])
 def test_bucketed_spmm_fwd_bwd_compiles(one_chip, caps, hidden):
     """Forward and custom-VJP backward: one kernel per bucket each way
     (value_and_grad keeps the forward live; grad alone would drop it), at
-    the worst-case and at the degree-profile bucket capacities."""
+    the worst-case and at the degree-profile bucket capacities, each kernel
+    taking its bucket's per-tile plane counts as a scalar-prefetch
+    operand."""
     g = _ell_shapes(one_chip, ARXIV_ROWS, ARXIV_EDGES, caps)
     h = _shape(one_chip, (ARXIV_ROWS, hidden))
 
@@ -90,6 +104,10 @@ def test_bucketed_spmm_fwd_bwd_compiles(one_chip, caps, hidden):
 
     compiled = jax.jit(jax.value_and_grad(loss)).lower(h, g).compile()
     assert _kernel_calls(compiled) == 2 * len(BUCKETS)
+    if caps is None:
+        caps = fixed_row_capacity(ARXIV_ROWS, ARXIV_EDGES, BUCKETS)
+    assert sorted(_leading_operands(compiled)) == sorted(
+        f"s32[{c // TILE}]{{0}}" for c in caps * 2)
 
 
 @pytest.mark.parametrize("m", [ARXIV_NODES], ids=["streamed"])

@@ -9,7 +9,8 @@ from repro.data.prefetch import SubgraphPipeline
 from repro.graph import ClusterSampler
 
 STEP_FIELDS = ("fetch_s", "wait_s", "h2d_s", "dispatch_s", "sync_s",
-               "staged", "build_s", "edge_fill", "ell_s", "ell_fill")
+               "staged", "build_s", "edge_fill", "ell_s", "ell_fill",
+               "ell_issue_fill")
 
 
 # (prefetch, recycle): inline builds, builds ahead on threads, and builds
@@ -82,10 +83,12 @@ def test_recycled_step_fetches_nothing(small_graph, small_parts):
     # the recycled step consumes the same batch, so its fills are the same
     assert 0.0 < first["edge_fill"] <= 1.0
     assert first["ell_s"] == 0.0 and first["ell_fill"] == 0.0  # no ELL
+    assert first["ell_issue_fill"] == 0.0
     assert second == {"wait_s": 0.0, "h2d_s": 0.0, "staged": False,
                       "build_s": 0.0, "ell_s": 0.0,
                       "edge_fill": first["edge_fill"],
-                      "ell_fill": first["ell_fill"]}
+                      "ell_fill": first["ell_fill"],
+                      "ell_issue_fill": first["ell_issue_fill"]}
 
 
 @pytest.mark.parametrize("prefetch,recycle", _FETCHES,
@@ -148,7 +151,8 @@ def test_record_carries_the_consumed_batch_ell_fill(small_graph, small_parts,
                                                     prefetch, recycle):
     """On the ``ell`` backend the record holds the ELL build's seconds
     (``pipeline.ell``, inside ``pipeline.build``) and the consumed batch's
-    real (row, neighbour) pairs over its ELL slots."""
+    real (row, neighbour) pairs over its ELL slots, and over the row copies
+    its kernel issues (``ell_issue_fill``)."""
     from repro.core import host_batch
     tr = _trainer(small_graph, small_parts, prefetch=prefetch,
                   recycle=recycle, backend="ell")
@@ -167,6 +171,8 @@ def test_record_carries_the_consumed_batch_ell_fill(small_graph, small_parts,
                     host_batch(sg, backend="ell").ell.bucket_idx)
         assert rec["ell_fill"] == sg.n_edges_real / slots
         assert 0.0 < rec["ell_fill"] < rec["edge_fill"] <= 1.0
+        # its tiles stop at their longest row: fewer copies than slots
+        assert 0.0 < rec["ell_fill"] < rec["ell_issue_fill"] <= 1.0
         if i % recycle:   # a recycled slot: its batch was built before
             assert rec["ell_s"] == rec["build_s"] == 0.0
         else:
